@@ -75,6 +75,9 @@ def main(argv: list[str] | None = None) -> None:
         help="run only the named bench (repeatable; names as listed below)",
     )
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     selected = REGISTRY
     if args.only:
         known = {n for n, _, _ in REGISTRY}

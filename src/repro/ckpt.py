@@ -29,10 +29,7 @@ import numpy as np
 
 
 def _flatten_with_paths(tree: Any):
-    flatten_with_path = getattr(
-        jax.tree, "flatten_with_path", jax.tree_util.tree_flatten_with_path
-    )
-    flat, treedef = flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in kp) for kp, _ in flat]
     leaves = [v for _, v in flat]
     return paths, leaves, treedef

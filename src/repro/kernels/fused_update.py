@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 2048
 
@@ -38,7 +39,7 @@ def fused_update(
         _kernel,
         grid=(L // TILE,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # (lr, mu, wd) scalars
             pl.BlockSpec((TILE,), lambda i: (i,)),
             pl.BlockSpec((TILE,), lambda i: (i,)),
             pl.BlockSpec((TILE,), lambda i: (i,)),

@@ -18,8 +18,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-NODE_BLOCK = 256
+NODE_BLOCK = 64  # (NB, M, K) temporaries stay well inside the 16 MiB VMEM scope
+
+
+def _select(candn, idx):
+    """candn[n, idx[n], :] for every node: a one-hot masked sum over the
+    candidate axis (Mosaic lowers only 2D gathers).  Exactly one term of
+    each sum is nonzero, so the selection is exact."""
+    m = jax.lax.broadcasted_iota(jnp.int32, candn.shape[:2], 1)
+    onehot = (m == idx[:, None]).astype(jnp.float32)  # (NB, M)
+    return jnp.sum(candn * onehot[:, :, None], axis=1)
 
 
 def _kernel(alpha_ref, beta_ref, tau_ref, pi_ref, mask_ref, cand_ref, rsum_ref, out_ref):
@@ -39,16 +49,14 @@ def _kernel(alpha_ref, beta_ref, tau_ref, pi_ref, mask_ref, cand_ref, rsum_ref, 
     logdet = jnp.sum(
         jnp.where(maskf[:, None, :] > 0, jnp.log(jnp.maximum(candn, 1e-12)), 0.0), axis=-1
     )  # (NB, M)
-    rho_idx = jnp.argmin(logdet, axis=-1)  # (NB,)
-    rho = jnp.take_along_axis(candn, rho_idx[:, None, None], axis=1)[:, 0, :]
+    rho = _select(candn, jnp.argmin(logdet, axis=-1))
 
     # line 6: grad = rsum / (tau * pi)
     grad = rsum / (tau * jnp.maximum(pi, 1e-12)) * maskf  # (NB, K)
 
     # line 7: scores = candn . grad  -> argmax candidate
     scores = jnp.sum(candn * grad[:, None, :], axis=-1)  # (NB, M)
-    best_idx = jnp.argmax(scores, axis=-1)
-    pi_tilde = jnp.take_along_axis(candn, best_idx[:, None, None], axis=1)[:, 0, :]
+    pi_tilde = _select(candn, jnp.argmax(scores, axis=-1))
 
     # line 8: Frank-Wolfe + exploration mixture, renormalized on the mask
     pi_new = alpha * (pi + beta * (pi_tilde - pi)) + (1.0 - alpha) * rho
@@ -76,9 +84,9 @@ def policy_update(
         _kernel,
         grid=(N // NODE_BLOCK,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # alpha
-            pl.BlockSpec(memory_space=pl.ANY),  # beta
-            pl.BlockSpec(memory_space=pl.ANY),  # tau
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # alpha
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # beta
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # tau
             pl.BlockSpec((NODE_BLOCK, K), lambda i: (i, 0)),
             pl.BlockSpec((NODE_BLOCK, K), lambda i: (i, 0)),
             pl.BlockSpec((M, K), lambda i: (0, 0)),
@@ -87,4 +95,4 @@ def policy_update(
         out_specs=pl.BlockSpec((NODE_BLOCK, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, K), jnp.float32),
         interpret=interpret,
-    )(scal(alpha, jnp.float32), scal(beta, jnp.float32), scal(tau, jnp.float32), pi, mask.astype(jnp.float32) > 0, cand, reward_sums)
+    )(scal(alpha, jnp.float32), scal(beta, jnp.float32), scal(tau, jnp.float32), pi, mask.astype(jnp.float32), cand, reward_sums)

@@ -201,8 +201,6 @@ def lower_cell(cell: Cell, mesh):
         out_shardings=cell.out_shardings,
         donate_argnums=cell.donate_argnums,
     )
-    # jax < 0.6 has no jax.set_mesh; Mesh is itself the ambient-mesh context
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with ctx:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*cell.args)
         return lowered

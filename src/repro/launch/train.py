@@ -16,7 +16,12 @@ TPU deployments enable async collectives via
 
 Usage:
   python -m repro.launch.train --arch tinyllama-1.1b --steps 50 \
-      --reduced --ckpt-dir /tmp/ckpt [--resume] [--aggregation totoro_tree_q8]
+      --reduced --ckpt-dir /tmp/ckpt [--resume]
+
+``run(argv)`` is the same path as a function: it takes the command-line
+arguments as a list and returns the per-step losses and the set-up
+(init + compile) and run times, so other entry points drive the
+trainer exactly as the command line does.
 """
 from __future__ import annotations
 
@@ -24,7 +29,7 @@ import argparse
 import time
 
 
-def main():
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--steps", type=int, default=20)
@@ -43,7 +48,14 @@ def main():
                     help="simulated per-round client dropout probability")
     ap.add_argument("--non-iid", type=float, default=0.0)
     ap.add_argument("--log-every", type=int, default=5)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def run(argv: list[str] | None = None) -> dict:
+    """Train as the command line would; returns ``{"cfg", "losses",
+    "setup_s", "run_s"}`` (losses of the steps this call ran)."""
+    args = parse_args(argv)
+    t_setup = time.perf_counter()
 
     import jax
     import jax.numpy as jnp
@@ -74,15 +86,13 @@ def main():
         state = jax.device_put(state)  # elastic: re-shard onto current mesh
         print(f"resumed from step {start_step}")
 
-    train_step = jax.jit(steps_mod.build_train_step(cfg, plan), donate_argnums=(0,))
     sc = data.StreamConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq_len,
         batch_per_shard=args.global_batch, non_iid_alpha=args.non_iid,
     )
     rng = np.random.default_rng(0)
 
-    t0 = time.time()
-    for step in range(start_step, args.steps):
+    def make_batch(step):
         batch = data.learnable_lm_batch(sc, shard=0, step=step)
         if args.straggler_rate > 0:
             # deadline-style straggler mitigation: dropped clients' examples
@@ -94,18 +104,41 @@ def main():
             b = {"embeds": jnp.asarray(emb), "labels": jnp.asarray(batch["labels"])}
             if cfg.is_encoder_decoder:
                 b["tokens"] = jnp.asarray(batch["tokens"])
-        else:
-            b = {k: jnp.asarray(v) for k, v in batch.items()}
+            return b
+        return {k: jnp.asarray(v) for k, v in batch.items()}
+
+    # compile ahead of the loop so set-up (init + compile) and the steps
+    # are timed apart
+    batches = [make_batch(step) for step in range(start_step, args.steps)]
+    train_step = jax.jit(
+        steps_mod.build_train_step(cfg, plan), donate_argnums=(0,)
+    ).lower(state, batches[0] if batches else make_batch(start_step)).compile()
+    setup_s = time.perf_counter() - t_setup
+    print(f"set-up (init + compile): {setup_s:.2f} s")
+
+    losses = []
+    t0 = time.perf_counter()
+    for step, b in zip(range(start_step, args.steps), batches):
         state, metrics = train_step(state, b)
+        losses.append(float(metrics["loss"]))
         if step % args.log_every == 0 or step == args.steps - 1:
-            print(f"step {step}: loss={float(metrics['loss']):.4f} "
-                  f"({(time.time()-t0)/max(step-start_step+1,1)*1e3:.0f} ms/step)")
+            print(f"step {step}: loss={losses[-1]:.4f} "
+                  f"({(time.perf_counter()-t0)/len(losses)*1e3:.0f} ms/step)")
         if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
             ckpt.save(state, args.ckpt_dir, step=step + 1, replicas=args.replicas)
+    run_s = time.perf_counter() - t0
     if args.ckpt_dir:
         ckpt.save(state, args.ckpt_dir, step=args.steps, replicas=args.replicas)
         print(f"final checkpoint at step {args.steps} ({args.replicas} replicas)")
     print("done")
+    return {"cfg": cfg, "losses": losses, "setup_s": setup_s, "run_s": run_s}
+
+
+def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
+    run()
 
 
 if __name__ == "__main__":

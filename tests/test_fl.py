@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro import data as data_mod
 from repro.fl import aggregation, compression, dp
@@ -110,9 +109,6 @@ def test_full_fl_round_over_overlay_converges():
     assert accs[-1] > accs[0] - 0.05
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"), reason="needs jax>=0.6 explicit mesh axis types"
-)
 def test_q8_cross_pod_math_single_device():
     """q8_mean_over_pods == plain mean up to one quantization step."""
     from repro.fl.steps import q8_mean_over_pods

@@ -37,14 +37,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-def canned_run(*, m_apps: int, applies: int, workers: int, seed: int,
-               optimized: bool) -> dict:
-    """The canned workload: identical to a bench_hotpath trained run."""
+def canned_fixture(*, m_apps: int, workers: int, seed: int):
+    """The canned workload's system, apps and ``run_async`` arguments:
+    600 nodes in 4 zones, ``m_apps`` apps of ``workers`` workers each,
+    heterogeneous compute and >= 10% churn."""
     from benchmarks.bench_async import _make_apps
     from benchmarks.common import build_system
     from repro.core.sim import ChurnModel
-    from repro.fl import async_engine, engine
-    from repro.kernels import ops as kops
+    from repro.fl import async_engine
 
     base_ms, spread = 40.0, 6.0
     per_worker = async_engine.worker_compute_fn(base_ms, spread, seed=seed)
@@ -54,13 +54,26 @@ def canned_run(*, m_apps: int, applies: int, workers: int, seed: int,
         period_ms=6.0 * base_ms, downtime_ms=12.0 * base_ms,
         group_size=max(1, round(0.1 * workers)), seed=seed,
     )
+    run_kwargs = dict(
+        buffer_k=max(2, workers // 2), staleness_alpha=0.5, model_bytes=2e5,
+        compute_ms=per_worker, churn=churn,
+    )
+    return sys_a, apps_a, run_kwargs
+
+
+def canned_run(*, m_apps: int, applies: int, workers: int, seed: int,
+               optimized: bool) -> dict:
+    """The canned workload: identical to a bench_hotpath trained run."""
+    from repro.fl import async_engine, engine
+    from repro.kernels import ops as kops
+
+    sys_a, apps_a, run_kwargs = canned_fixture(m_apps=m_apps, workers=workers, seed=seed)
     prev_mode = kops.set_kernel_mode("auto" if optimized else "pallas")
     prev_bucketing = engine.set_bucketing(optimized)
     try:
         return async_engine.run_async(
-            sys_a, apps_a, applies=applies, buffer_k=max(2, workers // 2),
-            staleness_alpha=0.5, model_bytes=2e5, compute_ms=per_worker,
-            churn=churn, megabatch=optimized, incremental=optimized,
+            sys_a, apps_a, applies=applies, megabatch=optimized,
+            incremental=optimized, **run_kwargs,
         )
     finally:
         kops.set_kernel_mode(prev_mode)
@@ -86,6 +99,9 @@ def main() -> None:
 
     import jax
 
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     os.makedirs(args.out, exist_ok=True)
     trace_dir = os.path.join(args.out, "jax-trace")
     label = "baseline (pre-optimization)" if args.baseline else "optimized"
