@@ -1,0 +1,203 @@
+"""A plain reference of the buffered-async FL apply, for ``correct``.
+
+It imports nothing of the program and takes nothing the program made.
+From the benchmark it takes the initial weights and the data (both drawn
+by ``bench/lib/fixture.py`` from the seed) and the schedule the
+benchmark's own probes recorded: which worker committed, which model
+version it had downloaded (counted by the benchmark), and the commit's
+number within its app.  From these it recomputes, in straightforward
+``jax.numpy`` at the matmul precision the configuration states
+(``matmul_precision``, one bfloat16 pass in the deployment's file):
+
+1. local training: ``steps`` SGD steps at ``lr`` on the worker's whole
+   shard, cross-entropy mean over its samples, from the state the worker
+   downloaded; the commit is new minus start;
+2. commit quantization (``commit="qsgd-int8"``): the update's leaves in
+   wire order (sorted by name) are concatenated, zero-padded to rows of
+   256, and each row is rounded stochastically to ``levels`` steps of
+   ``max|row| / levels``; the rounding draws ``uniform(key, (rows,
+   256))`` with ``key = fold_in(fold_in(PRNGKey(seed), app), commit)``;
+3. buffered aggregation: commit weights ``shard / (1 + staleness) **
+   alpha`` with staleness the apply's version minus the commit's,
+   the weighted mean added to the global weights;
+4. the broadcast chain (``broadcast="delta-qsgd"``): the workers hold a
+   reconstruction ``R``; after each apply the delta ``P - R`` is rounded
+   as in 2 at ``broadcast_levels`` under ``fold_in(fold_in(fold_in(
+   PRNGKey(seed), 0x0D0C), app), version)`` and added to ``R``; a
+   worker downloading that version trains from ``R``.  Without a
+   compressed broadcast the workers train from ``P`` itself.
+
+``mode`` computes the same thing otherwise, for the control and the
+faults (``bench/control.py``): ``"fp8"`` rounds every matmul operand of
+local training to float8 (e4m3, one scale per tensor), the step below
+the stated bfloat16; ``"bf16"`` trains and aggregates in bfloat16, the
+step below the stated float32 storage; ``"int4"`` rounds commits to 7
+steps instead of 127;
+``"frozen"`` leaves the weights unchanged by each apply; ``"half"``
+trains each worker on the first half of its shard; ``"altered"``
+negates the first commit of each apply.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+MODES = ("sound", "fp8", "bf16", "int4", "frozen", "half", "altered")
+BROADCAST_LANE = 0x0D0C
+CHUNK = 256
+
+
+@dataclass
+class AppResult:
+    """One app after each followed apply: weights, mean local loss and
+    the state a worker downloading that version trains from."""
+
+    params: list    # per apply: {leaf: np.ndarray}
+    losses: list    # per apply: float
+    held: list      # per apply: {leaf: np.ndarray}
+
+
+def _fp8(a):
+    """``a`` rounded to float8 e4m3 under one scale for the tensor; the
+    gradient passes through unrounded."""
+    s = jnp.maximum(jnp.max(jnp.abs(a)), 1e-30) / 448.0
+    r = (a / s).astype(jnp.float8_e4m3fn).astype(a.dtype) * s
+    return a + jax.lax.stop_gradient(r - a)
+
+
+def _logits(p, x, fp8: bool = False):
+    mm = (lambda a, b: _fp8(a) @ _fp8(b)) if fp8 else (lambda a, b: a @ b)
+    h = jax.nn.relu(mm(x, p["w1"]) + p["b1"])
+    h = jax.nn.relu(mm(h, p["w2"]) + p["b2"])
+    return mm(h, p["w3"]) + p["b3"]
+
+
+@partial(jax.jit, static_argnames=("steps", "lr", "dtype", "fp8"))
+def _local_sgd(p0, x, y, *, steps: int, lr: float, dtype: str, fp8: bool = False):
+    """``steps`` SGD steps from ``p0`` on (x, y); returns the update in
+    float32 and the mean of the step losses."""
+    dt = jnp.dtype(dtype)
+    p = {k: v.astype(dt) for k, v in p0.items()}
+    x = x.astype(dt)
+
+    def loss(q):
+        lp = jax.nn.log_softmax(_logits(q, x, fp8))
+        return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=1))
+
+    losses = []
+    start = p
+    for _ in range(steps):
+        value, grad = jax.value_and_grad(loss)(p)
+        p = {k: p[k] - jnp.asarray(lr, dt) * grad[k] for k in p}
+        losses.append(value.astype(jnp.float32))
+    update = {k: (p[k] - start[k]).astype(jnp.float32) for k in p}
+    return update, jnp.mean(jnp.stack(losses))
+
+
+@partial(jax.jit, static_argnames=("levels",))
+def _round_rows(flat, key, *, levels: int):
+    """Stochastic rounding of ``flat`` on rows of 256 (see module doc);
+    returns the dequantized values, same length as ``flat``."""
+    n = flat.shape[0]
+    rows = max(1, math.ceil(n / CHUNK))
+    x = jnp.zeros((rows * CHUNK,), jnp.float32).at[:n].set(flat).reshape(rows, CHUNK)
+    u = jax.random.uniform(key, (rows, CHUNK), jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1, keepdims=True) / levels, 1e-12)
+    q = jnp.floor(x / scale + u)
+    return (q * scale).reshape(-1)[:n]
+
+
+def _keys(leaves) -> list[str]:
+    return sorted(leaves)  # wire order: a dict pytree flattens by sorted key
+
+
+def _flat(tree):
+    return jnp.concatenate([jnp.ravel(tree[k]).astype(jnp.float32) for k in _keys(tree)])
+
+
+def _unflat(vec, like):
+    out, off = {}, 0
+    for k in _keys(like):
+        size = int(np.prod(like[k].shape))
+        out[k] = vec[off:off + size].reshape(like[k].shape)
+        off += size
+    return out
+
+
+def commit_key(seed: int, app: int, commit: int):
+    return jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(seed), app), commit)
+
+
+def broadcast_key(seed: int, app: int, version: int):
+    lane = jax.random.fold_in(jax.random.PRNGKey(seed), BROADCAST_LANE)
+    return jax.random.fold_in(jax.random.fold_in(lane, app), version)
+
+
+def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
+           traffic: dict, policy_seed: int, mode: str = "sound") -> AppResult:
+    """Replay one app's followed applies; see the module docstring."""
+    if mode not in MODES:
+        raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    comp = traffic["compression"]
+    commit_kind, broadcast = comp["commit"], comp["broadcast"]
+    levels = 7 if mode == "int4" else int(comp.get("levels", 127))
+    b_levels = int(comp.get("broadcast_levels", 7))
+    dtype = "bfloat16" if mode == "bf16" else "float32"
+    steps, lr = int(config["local_steps"]), float(config["lr"])
+    alpha = float(config["staleness_alpha"])
+
+    with jax.default_matmul_precision(str(config["matmul_precision"])):
+        p = {k: jnp.asarray(v, jnp.float32) for k, v in params0.items()}
+        recon = p
+        held = {0: p}
+        out = AppResult([], [], [])
+        for v, commits in enumerate(schedule):
+            updates, weights, stale, losses = [], [], [], []
+            for i, (worker, base, seq) in enumerate(sorted(commits, key=lambda c: c[1])):
+                x, y = data[worker]
+                if mode == "half":
+                    x, y = x[: len(y) // 2], y[: len(y) // 2]
+                upd, loss = _local_sgd(held[base], jnp.asarray(x), jnp.asarray(y),
+                                       steps=steps, lr=lr, dtype=dtype, fp8=mode == "fp8")
+                u = _flat(upd)
+                if commit_kind == "qsgd-int8":
+                    u = _round_rows(u, commit_key(policy_seed, app, seq), levels=levels)
+                elif mode == "bf16":
+                    u = u.astype(jnp.bfloat16).astype(jnp.float32)
+                if mode == "altered" and i == 0:
+                    u = -u
+                updates.append(u)
+                weights.append(float(len(data[worker][1])))
+                stale.append(v - base)
+                losses.append(float(loss))
+            w = jnp.asarray(weights, jnp.float32) * (
+                1.0 + jnp.asarray(stale, jnp.float32)) ** (-alpha)
+            if mode == "bf16":
+                stack = jnp.stack(updates).astype(jnp.bfloat16)
+                agg = (jnp.sum(w.astype(jnp.bfloat16)[:, None] * stack, axis=0)
+                       / jnp.sum(w).astype(jnp.bfloat16)).astype(jnp.float32)
+            else:
+                agg = jnp.sum(w[:, None] * jnp.stack(updates), axis=0) / jnp.sum(w)
+            if mode != "frozen":
+                p = {k: p[k] + d for k, d in _unflat(agg, p).items()}
+                if mode == "bf16":
+                    p = {k: a.astype(jnp.bfloat16).astype(jnp.float32) for k, a in p.items()}
+            if broadcast == "delta-qsgd":
+                delta = _flat(p) - _flat(recon)
+                step = _round_rows(delta, broadcast_key(policy_seed, app, v + 1),
+                                   levels=b_levels)
+                recon = {k: recon[k] + d for k, d in _unflat(step, recon).items()}
+                held[v + 1] = recon
+            elif broadcast == "none":
+                held[v + 1] = p
+            else:
+                raise ValueError(f"broadcast {broadcast!r} has no reference")
+            out.params.append(jax.tree.map(np.asarray, p))
+            out.losses.append(float(np.average(losses, weights=weights)))
+            out.held.append(jax.tree.map(np.asarray, held[v + 1]))
+    return out

@@ -1,0 +1,150 @@
+"""From a profiler trace to device busy time, idle gaps and kernel time.
+
+``read_xplane`` keeps two kinds of events of a ``jax.profiler`` trace:
+
+- device operations: every event on the ``XLA Ops`` line of each
+  ``/device:...`` plane (one plane per chip), named by the program it
+  ran in and its HLO instruction (``jit_qsgd_quantize/qsgd_quantize.1``);
+- host spans: the benchmark's ``TraceAnnotation``s (names starting
+  ``bench.``) on the host plane, among them ``bench.window`` around the
+  whole measured window.
+
+Both are on the profiler's one clock, in nanoseconds.  ``reduce`` then
+works on that plain list, which is also what ``bench/testdata`` keeps:
+
+- busy: the union of a chip's operation intervals inside the window,
+  averaged over the chips;
+- idle gaps: the complement, each labelled with the innermost host span
+  covering its middle (``outside apply`` when none does, that is while
+  the event core and the scheduler run);
+- kernel time: the summed durations of the operations whose name a
+  kernel's pattern matches.
+"""
+from __future__ import annotations
+
+import glob
+import os
+import re
+from dataclasses import dataclass
+
+HOST_PREFIX = "bench."
+WINDOW = "bench.window"
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+OUTSIDE = "outside apply"
+
+
+def find_xplane(trace_dir: str) -> str:
+    paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return paths[-1]
+
+
+def _module(name: str) -> str:
+    """``jit_qsgd_quantize(1234...)`` -> ``jit_qsgd_quantize``."""
+    return re.sub(r"\(\d+\)$", "", name)
+
+
+def _instruction(name: str) -> str:
+    """``%qsgd_quantize.1 = (s8[...]) custom-call(...)`` -> ``qsgd_quantize.1``."""
+    return name.split(" = ", 1)[0].lstrip("%")
+
+
+def read_xplane(path: str) -> dict:
+    """The device operations and benchmark host spans of one trace, as
+    ``{"device": {plane: [[name, start_ns, dur_ns], ...]}, "host": [...]}``.
+    An operation is named ``<module>/<instruction>``: the jitted program
+    it ran in (from the ``XLA Modules`` line) and its HLO instruction."""
+    import bisect
+
+    import jax
+
+    data = jax.profiler.ProfileData.from_file(path)
+    device, host = {}, []
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            lines = {line.name: list(line.events) for line in plane.lines}
+            if OPS_LINE not in lines:
+                continue
+            mods = sorted((e.start_ns, e.start_ns + e.duration_ns, _module(e.name))
+                          for e in lines.get(MODULES_LINE, []))
+            starts = [m[0] for m in mods]
+            ops = device.setdefault(plane.name, [])
+            for e in lines[OPS_LINE]:
+                i = bisect.bisect_right(starts, e.start_ns) - 1
+                mod = mods[i][2] if i >= 0 and e.start_ns <= mods[i][1] else "?"
+                ops.append([f"{mod}/{_instruction(e.name)}", float(e.start_ns),
+                            float(e.duration_ns)])
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host.extend([e.name, float(e.start_ns), float(e.duration_ns)]
+                            for e in line.events if e.name.startswith(HOST_PREFIX))
+    return {"device": device, "host": host}
+
+
+def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
+    out: list[list[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+@dataclass
+class Reduced:
+    window_s: float
+    busy_s: float                     # averaged over the chips
+    chips: int
+    ops: dict[str, float]             # operation name -> seconds, all chips
+    idle: dict[str, float]            # host label -> idle seconds, chip average
+    gaps: list[tuple[float, str]]     # (seconds, host label), longest first, chip 0
+
+    def kernel_seconds(self, pattern: str) -> float:
+        rx = re.compile(pattern)
+        return sum(s for name, s in self.ops.items() if rx.search(name))
+
+    def breakdown(self, n: int = 10) -> dict:
+        ops = sorted(self.ops.items(), key=lambda kv: -kv[1])[:n]
+        idle = sorted(self.idle.items(), key=lambda kv: -kv[1])[:n]
+        return {"device_ops": [[k, v] for k, v in ops], "idle_gaps": [[k, v] for k, v in idle]}
+
+
+def _label(mid: float, spans: list[tuple[str, float, float]]) -> str:
+    inner = [(b - a, name) for name, a, b in spans if a <= mid <= b]
+    return min(inner)[1][len(HOST_PREFIX):] if inner else OUTSIDE
+
+
+def reduce(trace: dict) -> Reduced:
+    """Busy time, idle gaps and per-operation time inside the window."""
+    windows = [(s, s + d) for name, s, d in trace["host"] if name == WINDOW]
+    if not windows:
+        raise ValueError(f"the trace has no {WINDOW} span")
+    lo, hi = windows[0]
+    spans = [(name, s, s + d) for name, s, d in trace["host"] if name != WINDOW]
+    chips = sorted(trace["device"])
+    if not chips:
+        raise ValueError("the trace has no device plane with an XLA Ops line")
+    busy, ops, idle, gaps0 = 0.0, {}, {}, []
+    for i, chip in enumerate(chips):
+        inside = []
+        for name, s, d in trace["device"][chip]:
+            a, b = max(s, lo), min(s + d, hi)
+            if b > a:
+                inside.append((a, b))
+                ops[name] = ops.get(name, 0.0) + (b - a) * 1e-9
+        merged = _union(inside)
+        busy += sum(b - a for a, b in merged)
+        edges = [lo] + [x for ab in merged for x in ab] + [hi]
+        for a, b in zip(edges[::2], edges[1::2]):
+            if b > a:
+                label = _label(0.5 * (a + b), spans)
+                idle[label] = idle.get(label, 0.0) + (b - a) * 1e-9 / len(chips)
+                if i == 0:
+                    gaps0.append(((b - a) * 1e-9, label))
+    return Reduced(
+        window_s=(hi - lo) * 1e-9, busy_s=busy * 1e-9 / len(chips), chips=len(chips),
+        ops=ops, idle=idle, gaps=sorted(gaps0, reverse=True),
+    )
