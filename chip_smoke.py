@@ -8,8 +8,8 @@ device is a TPU and the Pallas kernels are on (``REPRO_KERNEL_MODE`` not
 ``jnp``): nothing here falls back to the CPU, to interpret mode or to
 the jnp kernels.
 
-- **A: async FL main path.**  The canned profiling fixture
-  (``tools/profile_sim.canned_run``: 600 nodes, 4 zones, 16 apps x 8
+- **A: async FL main path.**  The canned fixture
+  (``tools/profile_sim.canned_fixture``: 600 nodes, 4 zones, 16 apps x 8
   workers, heterogeneous compute, >= 10% churn, 3 applies per app)
   through ``run_async`` with qsgd-int8 commits and delta-qsgd
   broadcasts, then one synchronous ``rounds.run_round``, whose

@@ -15,6 +15,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro import tracing
+
 from . import recovery as recovery_mod
 from .forest import DataflowTree, Forest
 from .nodeid import IdSpace
@@ -206,30 +208,31 @@ class TotoroSystem:
         recorded per commit — the weight discount happens at apply time
         so one ``ApplyBuffered`` policy governs the whole buffer.
         """
-        h = self.apps[app_id]
-        payload = delta
-        if h.privacy_fn:
-            payload = h.privacy_fn(payload)
-        wire = h.compress_fn(payload) if h.compress_fn else payload
-        nbytes = _nbytes(wire)
-        tree = h.tree
-        if worker == tree.root or worker not in tree.parent:
-            path = [worker]
-        else:
-            path = tree.path_to_root(worker)
-        n_edges = len(path) - 1
-        time_ms = self.overlay.path_latency(path)
-        h.traffic_bytes += nbytes * n_edges
-        received = h.decompress_fn(wire) if h.decompress_fn else payload
-        h.buffer.append(
-            BufferedDelta(worker=worker, delta=received, weight=float(weight), staleness=int(staleness))
-        )
-        return {
-            "time_ms": time_ms,
-            "bytes": nbytes * n_edges,
-            "edges": n_edges,
-            "buffered": len(h.buffer),
-        }
+        with tracing.span("verb.commit"):
+            h = self.apps[app_id]
+            payload = delta
+            if h.privacy_fn:
+                payload = h.privacy_fn(payload)
+            wire = h.compress_fn(payload) if h.compress_fn else payload
+            nbytes = _nbytes(wire)
+            tree = h.tree
+            if worker == tree.root or worker not in tree.parent:
+                path = [worker]
+            else:
+                path = tree.path_to_root(worker)
+            n_edges = len(path) - 1
+            time_ms = self.overlay.path_latency(path)
+            h.traffic_bytes += nbytes * n_edges
+            received = h.decompress_fn(wire) if h.decompress_fn else payload
+            h.buffer.append(
+                BufferedDelta(worker=worker, delta=received, weight=float(weight), staleness=int(staleness))
+            )
+            return {
+                "time_ms": time_ms,
+                "bytes": nbytes * n_edges,
+                "edges": n_edges,
+                "buffered": len(h.buffer),
+            }
 
     def ApplyBuffered(
         self,
@@ -262,75 +265,77 @@ class TotoroSystem:
         from repro.kernels.ops import buffered_aggregate, buffered_aggregate_quantized
         from repro.kernels.tree_aggregate import staleness_weights
 
-        h = self.apps[app_id]
-        if len(h.buffer) < max(1, min_k):
-            return {"result": None, "arrivals": len(h.buffer), "version": h.version}
-        entries, h.buffer = h.buffer, []
-        quantized = [isinstance(e.delta, QuantizedDelta) for e in entries]
-        if any(quantized) and not all(quantized):
-            raise ValueError(
-                "ApplyBuffered: mixed quantized and raw deltas in one buffer "
-                "— an app's CompressionPolicy must cover every commit"
-            )
-        if h.aggregate_fn is not None:
-            # custom aggregators see plain pytrees: dequantize up front
-            # (the fused scale/staleness composition below only applies
-            # to the built-in kernel path)
-            deltas = [e.delta.dequantize() if q else e.delta
-                      for e, q in zip(entries, quantized)]
-            result = h.aggregate_fn(
-                deltas,
-                list(staleness_weights(
-                    np.asarray([e.weight for e in entries], np.float64),
-                    np.asarray([e.staleness for e in entries], np.float64),
-                    staleness_alpha,
-                )),
-            )
-            combined = None
-        elif all(quantized) and entries:
-            # dequantize INSIDE the aggregation: per-row scales compose
-            # with the staleness discount in one kernel call
-            flat, combined = buffered_aggregate_quantized(
-                [e.delta.q for e in entries],
-                [e.delta.scale for e in entries],
-                [e.weight for e in entries],
-                [e.staleness for e in entries],
-                alpha=staleness_alpha,
-            )
-            result = entries[0].delta.unflatten(np.asarray(flat))
-        else:
-            result, combined = buffered_aggregate(
-                [e.delta for e in entries],
-                [e.weight for e in entries],
-                [e.staleness for e in entries],
-                alpha=staleness_alpha,
-            )
-        h.version += 1
-        stal = [e.staleness for e in entries]
-        hist = np.bincount(np.asarray(stal, np.int64)).tolist() if entries else []
-        stats = {
-            "result": result,
-            "arrivals": len(entries),
-            "workers": [e.worker for e in entries],
-            "staleness": stal,
-            "staleness_hist": hist,  # hist[s] = commits applied at staleness s
-            "weights": None if combined is None else [float(w) for w in combined],
-            "version": h.version,
-            "k": len(entries) if k is None else int(k),
-        }
-        h.round_records.append(
-            {
-                "version": h.version,
+        with tracing.span("verb.apply"):
+            h = self.apps[app_id]
+            if len(h.buffer) < max(1, min_k):
+                return {"result": None, "arrivals": len(h.buffer), "version": h.version}
+            entries, h.buffer = h.buffer, []
+            quantized = [isinstance(e.delta, QuantizedDelta) for e in entries]
+            if any(quantized) and not all(quantized):
+                raise ValueError(
+                    "ApplyBuffered: mixed quantized and raw deltas in one buffer "
+                    "— an app's CompressionPolicy must cover every commit"
+                )
+            if h.aggregate_fn is not None:
+                # custom aggregators see plain pytrees: dequantize up front
+                # (the fused scale/staleness composition below only applies
+                # to the built-in kernel path)
+                deltas = [e.delta.dequantize() if q else e.delta
+                          for e, q in zip(entries, quantized)]
+                result = h.aggregate_fn(
+                    deltas,
+                    list(staleness_weights(
+                        np.asarray([e.weight for e in entries], np.float64),
+                        np.asarray([e.staleness for e in entries], np.float64),
+                        staleness_alpha,
+                    )),
+                )
+                combined = None
+            elif all(quantized) and entries:
+                # dequantize INSIDE the aggregation: per-row scales compose
+                # with the staleness discount in one kernel call
+                flat, combined = buffered_aggregate_quantized(
+                    [tracing.implicit_push(e.delta.q) for e in entries],
+                    [tracing.implicit_push(e.delta.scale) for e in entries],
+                    [e.weight for e in entries],
+                    [e.staleness for e in entries],
+                    alpha=staleness_alpha,
+                )
+                result = entries[0].delta.unflatten(tracing.pull(flat))
+            else:
+                result, combined = buffered_aggregate(
+                    [e.delta for e in entries],
+                    [e.weight for e in entries],
+                    [e.staleness for e in entries],
+                    alpha=staleness_alpha,
+                )
+            h.version += 1
+            stal = [e.staleness for e in entries]
+            hist = np.bincount(np.asarray(stal, np.int64)).tolist() if entries else []
+            stats = {
+                "result": result,
                 "arrivals": len(entries),
-                "k": stats["k"],
-                "staleness_hist": hist,
-                "selector_scores": selector_scores,
-                "transport": transport,
+                "workers": [e.worker for e in entries],
+                "staleness": stal,
+                "staleness_hist": hist,  # hist[s] = commits applied at staleness s
+                "weights": (None if combined is None
+                            else [float(tracing.pull(w)) for w in combined]),
+                "version": h.version,
+                "k": len(entries) if k is None else int(k),
             }
-        )
-        if h.on_aggregate:
-            h.on_aggregate(app_id, result)
-        return stats
+            h.round_records.append(
+                {
+                    "version": h.version,
+                    "arrivals": len(entries),
+                    "k": stats["k"],
+                    "staleness_hist": hist,
+                    "selector_scores": selector_scores,
+                    "transport": transport,
+                }
+            )
+            if h.on_aggregate:
+                h.on_aggregate(app_id, result)
+            return stats
 
     def Discover(self, node: int) -> dict[int, dict]:
         """AD-tree application discovery (journal addition, Appendix A)."""
@@ -361,7 +366,7 @@ def _nbytes(obj) -> float:
     if hasattr(obj, "nbytes"):
         return float(obj.nbytes)
     try:
-        return float(sum(np.asarray(x).nbytes for x in jax.tree.leaves(obj)))
+        return float(sum(tracing.pull(x).nbytes for x in jax.tree.leaves(obj)))
     except Exception:
         return float(len(str(obj)))
 
@@ -373,7 +378,7 @@ def _weighted_mean(values, weights):
     w = w / w.sum()
 
     def avg(*leaves):
-        return sum(wi * np.asarray(l, np.float64) for wi, l in zip(w, leaves))
+        return sum(wi * tracing.pull(l, np.float64) for wi, l in zip(w, leaves))
 
     return jax.tree.map(avg, *values)
 
@@ -402,7 +407,7 @@ def _aggregate_hierarchical(overlay, tree, payload, weights, *, use_kernel=True)
 
     def flatten(obj):
         ls = jax.tree.leaves(obj)
-        return np.concatenate([np.ravel(np.asarray(l)).astype(np.float32) for l in ls])
+        return np.concatenate([np.ravel(tracing.pull(l)).astype(np.float32) for l in ls])
 
     # node -> [partial weighted-sum vec, kernel weight, subtree weight]
     state: dict[int, list] = {
@@ -423,7 +428,7 @@ def _aggregate_hierarchical(overlay, tree, payload, weights, *, use_kernel=True)
                 g[i, j] = state[c][0]
                 w[i, j] = state[c][1]
         if use_kernel:
-            out = np.asarray(kops.tree_aggregate_groups(g, w))
+            out = tracing.pull(kops.tree_aggregate_groups(tracing.implicit_push(g), tracing.implicit_push(w)))
         else:
             out = (g.astype(np.float64) * w[..., None]).sum(axis=1)
         lvl_bytes, lvl_ms = 0.0, 0.0

@@ -93,6 +93,8 @@ from typing import Callable
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 from .congestion import CongestionEnv, UplinkState, fair_share_rates
 
 
@@ -630,7 +632,8 @@ class EventCore:
                     self._dead -= 1
                 continue  # cancelled
             self.now = t
-            cb(t)
+            with tracing.span("event"):
+                cb(t)
             n += 1
             self.events_dispatched += 1
             if self._tick_hook is not None:

@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.fl import engine
 from repro.fl.compression import (
     CompressionPolicy,
@@ -165,23 +166,24 @@ class AsyncTrainer:
         the downlink: the reference stays within one quantizer bound of
         the true params at every version, and a worker chaining cached
         deltas from any base lands bit-for-bit on this state."""
-        if policy.downlink == "qsgd-int8":
-            qd = quantize_broadcast_delta(params, policy, broadcast_key(policy, ai, version))
-            deq = qd.dequantize()
-            return jax.tree.map(
-                lambda p, v: np.asarray(v, dtype=np.asarray(p).dtype), params, deq
+        with tracing.span("broadcast"):
+            if policy.downlink == "qsgd-int8":
+                qd = quantize_broadcast_delta(params, policy, broadcast_key(policy, ai, version))
+                deq = qd.dequantize()
+                return jax.tree.map(
+                    lambda p, v: np.asarray(v, dtype=tracing.pull(p).dtype), params, deq
+                )
+            delta = jax.tree.map(
+                lambda p, r: tracing.pull(p, np.float32) - tracing.pull(r, np.float32),
+                params, self._recon[ai],
             )
-        delta = jax.tree.map(
-            lambda p, r: np.asarray(p, np.float32) - np.asarray(r, np.float32),
-            params, self._recon[ai],
-        )
-        qd = quantize_broadcast_delta(delta, policy, broadcast_key(policy, ai, version))
-        cache = self._delta_cache[ai]
-        cache[version] = qd
-        for v in [v for v in cache if v <= version - int(policy.chain_cap)]:
-            del cache[v]
-        self._recon[ai] = apply_delta_chain(self._recon[ai], [qd])
-        return self._recon[ai]
+            qd = quantize_broadcast_delta(delta, policy, broadcast_key(policy, ai, version))
+            cache = self._delta_cache[ai]
+            cache[version] = qd
+            for v in [v for v in cache if v <= version - int(policy.chain_cap)]:
+                del cache[v]
+            self._recon[ai] = apply_delta_chain(self._recon[ai], [qd])
+            return self._recon[ai]
 
     def apply(
         self, ai: int, t: float, *, k: int | None = None, selector_scores=None,
@@ -197,110 +199,114 @@ class AsyncTrainer:
         from the scheduler; they ride into the app handle's
         ``round_records`` via ``ApplyBuffered``.
         """
-        app = self.apps[ai]
-        pending, self._pending[ai] = self._pending[ai], []
-        if not pending:  # commit batch drained (e.g. by churn)
-            return None
-        cur = self.version[ai]
-        groups: dict[int, list[tuple[int, int]]] = {}
-        for w, v, seq in pending:
-            groups.setdefault(v, []).append((w, seq))
-        versions = sorted(groups)
-        if self.megabatch:
-            # every version group of this apply stacks into ONE compiled
-            # dispatch: megabatched_local_train carries per-worker start
-            # params, so staleness-ragged buffers stop costing one XLA
-            # program (and often one compile) per version
-            trained = engine.fused_local_training(
-                [(app, [w for w, _ in groups[v]], self._snapshots[ai][v]) for v in versions]
-            )
-        else:  # pre-optimization path: one dispatch per version group
-            trained = [
-                engine.local_training(
-                    app, [w for w, _ in groups[v]], params=self._snapshots[ai][v],
-                    bucketed=False,
+        with tracing.span("apply", app=ai, version=self.version[ai] + 1):
+            app = self.apps[ai]
+            pending, self._pending[ai] = self._pending[ai], []
+            if not pending:  # commit batch drained (e.g. by churn)
+                return None
+            cur = self.version[ai]
+            groups: dict[int, list[tuple[int, int]]] = {}
+            for w, v, seq in pending:
+                groups.setdefault(v, []).append((w, seq))
+            versions = sorted(groups)
+            if self.megabatch:
+                # every version group of this apply stacks into ONE compiled
+                # dispatch: megabatched_local_train carries per-worker start
+                # params, so staleness-ragged buffers stop costing one XLA
+                # program (and often one compile) per version
+                trained = engine.fused_local_training(
+                    [(app, [w for w, _ in groups[v]], self._snapshots[ai][v]) for v in versions]
                 )
-                for v in versions
-            ]
-        policy = self._compression[ai]
-        losses, loss_weights = [], []
-        for v, (deltas, weights, group_losses) in zip(versions, trained):
-            ws = groups[v]
-            for (w, seq), d, wt, l in zip(ws, deltas, weights, group_losses):
-                payload = d
-                if policy is not None and policy.enabled:
-                    target = d
-                    if policy.error_feedback:
-                        # EF-SGD: fold the worker's carried residual into
-                        # this commit before quantizing, then carry the
-                        # fresh quantization error forward
-                        r = self._ef[ai].get(w)
-                        if r is not None:
-                            target = jax.tree.map(
-                                lambda a, b: jnp.asarray(a, jnp.float32) + b, d, r
+            else:  # pre-optimization path: one dispatch per version group
+                trained = [
+                    engine.local_training(
+                        app, [w for w, _ in groups[v]], params=self._snapshots[ai][v],
+                        bucketed=False,
+                    )
+                    for v in versions
+                ]
+            policy = self._compression[ai]
+            losses, loss_weights = [], []
+            for v, (deltas, weights, group_losses) in zip(versions, trained):
+                ws = groups[v]
+                for (w, seq), d, wt, l in zip(ws, deltas, weights, group_losses):
+                    payload = d
+                    if policy is not None and policy.enabled:
+                        target = d
+                        if policy.error_feedback:
+                            # EF-SGD: fold the worker's carried residual into
+                            # this commit before quantizing, then carry the
+                            # fresh quantization error forward
+                            r = self._ef[ai].get(w)
+                            if r is not None:
+                                target = jax.tree.map(
+                                    lambda a, b: tracing.push(a, jnp.float32) + b, d, r
+                                )
+                        payload = quantize_delta(target, policy, commit_key(policy, ai, seq))
+                        if policy.error_feedback:
+                            deq = payload.dequantize()
+                            self._ef[ai][w] = jax.tree.map(
+                                lambda a, b: tracing.push(a, jnp.float32)
+                                - tracing.push(tracing.pull(b), jnp.float32),
+                                target, deq,
                             )
-                    payload = quantize_delta(target, policy, commit_key(policy, ai, seq))
-                    if policy.error_feedback:
-                        deq = payload.dequantize()
-                        self._ef[ai][w] = jax.tree.map(
-                            lambda a, b: jnp.asarray(a, jnp.float32)
-                            - jnp.asarray(np.asarray(b), jnp.float32),
-                            target, deq,
-                        )
-                self.system.CommitDelta(
-                    app.handle.app_id, w, payload, weight=wt, staleness=cur - v
-                )
-                losses.append(l)
-                loss_weights.append(wt)
-                if self.selector is not None:
-                    loss_val = float(l)
-                    if np.isfinite(loss_val):
-                        dnorm = 0.0  # loss is the stat signal; skip W host transfers
-                    else:
-                        dnorm = float(
-                            np.sqrt(
-                                sum(
-                                    float(np.sum(np.square(np.asarray(x))))
-                                    for x in jax.tree.leaves(d)
+                    self.system.CommitDelta(
+                        app.handle.app_id, w, payload, weight=wt, staleness=cur - v
+                    )
+                    losses.append(l)
+                    loss_weights.append(wt)
+                    if self.selector is not None:
+                        loss_val = float(l)
+                        if np.isfinite(loss_val):
+                            dnorm = 0.0  # loss is the stat signal; skip W host transfers
+                        else:
+                            dnorm = float(
+                                np.sqrt(
+                                    sum(
+                                        float(np.sum(np.square(tracing.pull(x))))
+                                        for x in jax.tree.leaves(d)
+                                    )
                                 )
                             )
-                        )
-                    self.selector.on_train(ai, w, loss_val, dnorm)
-            self._refs[ai][v] -= len(ws)
-        stats = self.system.ApplyBuffered(
-            app.handle.app_id, staleness_alpha=self.staleness_alpha,
-            k=k, selector_scores=selector_scores, transport=transport,
-        )
-        agg = stats["result"]
-        app.params = jax.tree.map(lambda p, d: (p + d).astype(p.dtype), app.params, agg)
-        app.round_num += 1
-        self.version[ai] = cur + 1
-        # the snapshot is what workers RECEIVE for this version: the
-        # exact params, or the compressed broadcast state when the
-        # downlink axis is on (every worker at a version holds the same
-        # canonical state, so version-group training is unchanged)
-        held = app.params
-        if policy is not None and policy.downlink_enabled:
-            held = self._broadcast_state(ai, app.params, cur + 1, policy)
-        self._snapshots[ai][cur + 1] = held
-        self._refs[ai][cur + 1] = self._refs[ai].get(cur + 1, 0)
-        self._gc_snapshots(ai)
-        if self.replicate:
-            self.system.replicate_master_state(
-                app.handle.app_id, {"round": app.round_num, "version": cur + 1}
+                        self.selector.on_train(ai, w, loss_val, dnorm)
+                self._refs[ai][v] -= len(ws)
+            stats = self.system.ApplyBuffered(
+                app.handle.app_id, staleness_alpha=self.staleness_alpha,
+                k=k, selector_scores=selector_scores, transport=transport,
             )
-        record = {
-            "app_id": app.handle.app_id,
-            "t_ms": t,
-            "version": cur + 1,
-            "arrivals": len(pending),
-            "k": k,
-            "loss": float(np.average(losses, weights=loss_weights)),
-            "mean_staleness": float(np.mean([cur - v for _, v, _ in pending])),
-        }
-        self.history.append(record)
-        app.history.append(record)
-        return record
+            agg = stats["result"]
+            app.params = jax.tree.map(
+                lambda p, d: (p + tracing.implicit_push(d)).astype(p.dtype), app.params, agg
+            )
+            app.round_num += 1
+            self.version[ai] = cur + 1
+            # the snapshot is what workers RECEIVE for this version: the
+            # exact params, or the compressed broadcast state when the
+            # downlink axis is on (every worker at a version holds the same
+            # canonical state, so version-group training is unchanged)
+            held = app.params
+            if policy is not None and policy.downlink_enabled:
+                held = self._broadcast_state(ai, app.params, cur + 1, policy)
+            self._snapshots[ai][cur + 1] = held
+            self._refs[ai][cur + 1] = self._refs[ai].get(cur + 1, 0)
+            self._gc_snapshots(ai)
+            if self.replicate:
+                with tracing.span("replicate"):
+                    self.system.replicate_master_state(
+                        app.handle.app_id, {"round": app.round_num, "version": cur + 1}
+                    )
+            record = {
+                "app_id": app.handle.app_id,
+                "t_ms": t,
+                "version": cur + 1,
+                "arrivals": len(pending),
+                "k": k,
+                "loss": float(np.average(losses, weights=loss_weights)),
+                "mean_staleness": float(np.mean([cur - v for _, v, _ in pending])),
+            }
+            self.history.append(record)
+            app.history.append(record)
+            return record
 
     def _gc_snapshots(self, ai: int) -> None:
         """Drop param versions no in-flight worker can still reference."""

@@ -54,6 +54,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 
 def qsgd_quantize(x: jax.Array, *, levels: int = 127, key=None, rand=None):
     """Stochastic int8 quantization with per-row scale.
@@ -307,7 +309,7 @@ class QuantizedDelta:
 
     def unflatten(self, flat) -> Any:
         """Rebuild the delta pytree from a flat (>= length,) f32 vector."""
-        vec = np.asarray(flat)[: self.length]
+        vec = tracing.pull(flat)[: self.length]
         leaves, off = [], 0
         for s in self.shapes:
             size = int(np.prod(s)) if s else 1
@@ -328,7 +330,7 @@ def _flatten_grid(delta, chunk: int):
     leaves, treedef = jax.tree.flatten(delta)
     shapes = tuple(np.shape(l) for l in leaves)
     flat = jnp.concatenate(
-        [jnp.ravel(l).astype(jnp.float32) for l in leaves]
+        [jnp.ravel(tracing.implicit_push(l)).astype(jnp.float32) for l in leaves]
     ) if leaves else jnp.zeros((0,), jnp.float32)
     n = int(flat.size)
     rows = max(1, math.ceil(n / chunk))
@@ -369,32 +371,33 @@ def quantize_delta(delta, policy: CompressionPolicy, key=None) -> QuantizedDelta
     ``commit_key``)."""
     if not policy.enabled:
         raise ValueError("quantize_delta requires an enabled policy (kind != 'none')")
-    chunk = int(policy.chunk)
-    x2d, flat, n, shapes, treedef = _flatten_grid(delta, chunk)
-    wire = None
-    if policy.kind == "signsgd":
-        rows = x2d.shape[0]
-        counts = np.clip(n - chunk * np.arange(rows), 1, chunk).astype(np.float32)
-        s = jnp.sum(jnp.abs(x2d), axis=-1, keepdims=True) / counts[:, None]
-        q = jnp.sign(x2d).astype(jnp.int8)
-        wire = policy.wire_bytes(4.0 * n)
-    elif policy.kind == "topk":
-        k = max(1, math.ceil(n * float(policy.topk_frac)))
-        if n > k:
-            _, idx = jax.lax.top_k(jnp.abs(flat), k)
-            sparse = jnp.zeros_like(flat).at[idx].set(flat[idx])
+    with tracing.span("quantize"):
+        chunk = int(policy.chunk)
+        x2d, flat, n, shapes, treedef = _flatten_grid(delta, chunk)
+        wire = None
+        if policy.kind == "signsgd":
             rows = x2d.shape[0]
-            x2d = jnp.zeros((rows * chunk,), jnp.float32).at[:n].set(sparse)
-            x2d = x2d.reshape(rows, chunk)
-        q, s = _qsgd_grid(x2d, key, int(policy.levels))
-        wire = policy.wire_bytes(4.0 * n)
-    else:
-        q, s = _qsgd_grid(x2d, key, int(policy.levels))
-    return QuantizedDelta(
-        q=np.asarray(q), scale=np.asarray(s), length=n, shapes=shapes,
-        treedef=treedef, levels=int(policy.levels), chunk=chunk,
-        wire_nbytes=wire,
-    )
+            counts = np.clip(n - chunk * np.arange(rows), 1, chunk).astype(np.float32)
+            s = jnp.sum(jnp.abs(x2d), axis=-1, keepdims=True) / tracing.implicit_push(counts[:, None])
+            q = jnp.sign(x2d).astype(jnp.int8)
+            wire = policy.wire_bytes(4.0 * n)
+        elif policy.kind == "topk":
+            k = max(1, math.ceil(n * float(policy.topk_frac)))
+            if n > k:
+                _, idx = jax.lax.top_k(jnp.abs(flat), k)
+                sparse = jnp.zeros_like(flat).at[idx].set(flat[idx])
+                rows = x2d.shape[0]
+                x2d = jnp.zeros((rows * chunk,), jnp.float32).at[:n].set(sparse)
+                x2d = x2d.reshape(rows, chunk)
+            q, s = _qsgd_grid(x2d, key, int(policy.levels))
+            wire = policy.wire_bytes(4.0 * n)
+        else:
+            q, s = _qsgd_grid(x2d, key, int(policy.levels))
+        return QuantizedDelta(
+            q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,
+            treedef=treedef, levels=int(policy.levels), chunk=chunk,
+            wire_nbytes=wire,
+        )
 
 
 def dequantize_delta(qd: QuantizedDelta) -> Any:
@@ -413,15 +416,16 @@ def quantize_broadcast_delta(delta, policy: CompressionPolicy, key=None) -> Quan
         raise ValueError(
             "quantize_broadcast_delta requires an enabled downlink (downlink != 'none')"
         )
-    chunk = int(policy.chunk)
-    x2d, _, n, shapes, treedef = _flatten_grid(delta, chunk)
-    levels = int(policy.downlink_levels) if policy.downlink == "delta-qsgd" else int(policy.levels)
-    q, s = _qsgd_grid(x2d, key, levels)
-    wire = policy.downlink_wire_bytes(4.0 * n, chain=1)
-    return QuantizedDelta(
-        q=np.asarray(q), scale=np.asarray(s), length=n, shapes=shapes,
-        treedef=treedef, levels=levels, chunk=chunk, wire_nbytes=wire,
-    )
+    with tracing.span("quantize"):
+        chunk = int(policy.chunk)
+        x2d, _, n, shapes, treedef = _flatten_grid(delta, chunk)
+        levels = int(policy.downlink_levels) if policy.downlink == "delta-qsgd" else int(policy.levels)
+        q, s = _qsgd_grid(x2d, key, levels)
+        wire = policy.downlink_wire_bytes(4.0 * n, chain=1)
+        return QuantizedDelta(
+            q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,
+            treedef=treedef, levels=levels, chunk=chunk, wire_nbytes=wire,
+        )
 
 
 def apply_delta_chain(params, deltas: list) -> Any:
@@ -437,30 +441,32 @@ def apply_delta_chain(params, deltas: list) -> Any:
     model, same policy)."""
     if not deltas:
         return params
-    qd0 = deltas[0]
-    rows, chunk = qd0.q.shape
-    leaves = jax.tree.leaves(params)
-    flat = np.concatenate(
-        [np.ravel(np.asarray(l)).astype(np.float32) for l in leaves]
-    ) if leaves else np.zeros((0,), np.float32)
-    if flat.size != qd0.length:
-        raise ValueError(
-            f"params have {flat.size} elements but the chain was built for {qd0.length}"
-        )
-    w2d = np.zeros((rows * chunk,), np.float32)
-    w2d[: flat.size] = flat
-    w2d = w2d.reshape(rows, chunk)
-    q = np.stack([d.q for d in deltas])          # (D, rows, chunk) int8
-    s = np.stack([d.scale for d in deltas])      # (D, rows, 1) f32
-    if chunk == 256:
-        from repro.kernels import ops as kops
+    with tracing.span("chain"):
+        qd0 = deltas[0]
+        rows, chunk = qd0.q.shape
+        leaves = jax.tree.leaves(params)
+        flat = np.concatenate(
+            [np.ravel(tracing.pull(l)).astype(np.float32) for l in leaves]
+        ) if leaves else np.zeros((0,), np.float32)
+        if flat.size != qd0.length:
+            raise ValueError(
+                f"params have {flat.size} elements but the chain was built for {qd0.length}"
+            )
+        w2d = np.zeros((rows * chunk,), np.float32)
+        w2d[: flat.size] = flat
+        w2d = w2d.reshape(rows, chunk)
+        q = np.stack([d.q for d in deltas])          # (D, rows, chunk) int8
+        s = np.stack([d.scale for d in deltas])      # (D, rows, 1) f32
+        if chunk == 256:
+            from repro.kernels import ops as kops
 
-        out = np.asarray(kops.apply_quantized_broadcast(w2d, q, s))
-    else:
-        out = w2d
-        for d in range(q.shape[0]):
-            out = out + q[d].astype(np.float32) * s[d]
-    rebuilt = qd0.unflatten(out.reshape(-1))
-    return jax.tree.map(
-        lambda p, v: np.asarray(v, dtype=np.asarray(p).dtype), params, rebuilt
-    )
+            out = tracing.pull(kops.apply_quantized_broadcast(
+                tracing.implicit_push(w2d), tracing.implicit_push(q), tracing.implicit_push(s)))
+        else:
+            out = w2d
+            for d in range(q.shape[0]):
+                out = out + q[d].astype(np.float32) * s[d]
+        rebuilt = qd0.unflatten(out.reshape(-1))
+        return jax.tree.map(
+            lambda p, v: np.asarray(v, dtype=tracing.pull(p).dtype), params, rebuilt
+        )

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.fl import small_models as sm
 
 _BUCKETED = True  # module default for pack_shards/local_training bucketing
@@ -100,7 +101,7 @@ def pack_shards(
     """
     if not workers:  # a drained commit batch: empty padded stacks, not max([])
         z = np.zeros((0, 0), np.float32)
-        return jnp.asarray(z), jnp.asarray(z, jnp.int32), jnp.asarray(z)
+        return tracing.push(z), tracing.push(z, jnp.int32), tracing.push(z)
     bs = [len(data_by_worker[w][1]) for w in workers]
     B = max(bs) if bs else 1
     if b_bucket is not None:
@@ -110,17 +111,17 @@ def pack_shards(
     if w_bucket is not None:
         assert w_bucket >= W, (w_bucket, W)
         W = w_bucket
-    x0 = np.asarray(data_by_worker[workers[0]][0])
+    x0 = tracing.pull(data_by_worker[workers[0]][0])
     xs = np.zeros((W, B) + x0.shape[1:], np.float32)
     ys = np.zeros((W, B), np.int32)
     mask = np.zeros((W, B), np.float32)
     for i, w in enumerate(workers):
         x, y = data_by_worker[w]
         b = len(y)
-        xs[i, :b] = np.asarray(x, np.float32)
-        ys[i, :b] = np.asarray(y, np.int32)
+        xs[i, :b] = tracing.pull(x, np.float32)
+        ys[i, :b] = tracing.pull(y, np.int32)
         mask[i, :b] = 1.0
-    return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask)
+    return tracing.push(xs), tracing.push(ys), tracing.push(mask)
 
 
 def _masked_ce(logits, y, mask):
@@ -226,7 +227,7 @@ def local_training(
                 logits_fn=logits_fn, steps=app.local_steps, lr=app.lr, mu=app.mu,
             )
             deltas.append(jax.tree.map(lambda a, b: a - b, new_p, start))
-            losses.append(float(loss))
+            losses.append(float(tracing.pull(loss)))
         return deltas, weights, losses
 
     if bucketed is None:
@@ -249,9 +250,9 @@ def local_training(
     stacked = jax.tree.map(lambda n, p: n - p[None], new_params, start)
     # one device->host transfer per leaf, then cheap numpy row views —
     # per-worker device slicing would cost W x leaves dispatches
-    stacked_np = jax.tree.map(np.asarray, stacked)
+    stacked_np = jax.tree.map(tracing.pull, stacked)
     deltas = [jax.tree.map(lambda l, i=i: l[i], stacked_np) for i in range(W)]
-    return deltas, weights, [float(l) for l in np.asarray(losses)[:W]]
+    return deltas, weights, [float(l) for l in tracing.pull(losses)[:W]]
 
 
 def fused_local_training(jobs: list, *, bucketed: bool | None = None) -> list:
@@ -267,87 +268,90 @@ def fused_local_training(jobs: list, *, bucketed: bool | None = None) -> list:
     unstacked per job.  Returns ``[(deltas, weights, losses), ...]``
     aligned with ``jobs``.
     """
-    if bucketed is None:
-        bucketed = _BUCKETED
-    results: list = [None] * len(jobs)
-    groups: dict[tuple, list[int]] = {}
-    for j, (app, workers, start) in enumerate(jobs):
-        if not workers:
-            results[j] = ([], [], [])
-            continue
-        feat = np.asarray(app.data[workers[0]][0]).shape[1:]
-        if start is None:
-            start = app.params
-        # the param treedef + leaf shapes are part of the fusion key:
-        # two apps may share a model NAME (and feat/steps/lr/mu) while
-        # differing in num_classes or hidden sizes, and stacking those
-        # into one params buffer would be a shape error
-        params_sig = (
-            jax.tree.structure(start),
-            tuple(np.shape(l) for l in jax.tree.leaves(start)),
-        )
-        key = (app.model, app.local_steps, app.lr, app.mu, feat, params_sig)
-        groups.setdefault(key, []).append(j)
+    with tracing.span("train"):
+        if bucketed is None:
+            bucketed = _BUCKETED
+        results: list = [None] * len(jobs)
+        groups: dict[tuple, list[int]] = {}
+        for j, (app, workers, start) in enumerate(jobs):
+            if not workers:
+                results[j] = ([], [], [])
+                continue
+            feat = tracing.pull(app.data[workers[0]][0]).shape[1:]
+            if start is None:
+                start = app.params
+            # the param treedef + leaf shapes are part of the fusion key:
+            # two apps may share a model NAME (and feat/steps/lr/mu) while
+            # differing in num_classes or hidden sizes, and stacking those
+            # into one params buffer would be a shape error
+            params_sig = (
+                jax.tree.structure(start),
+                tuple(np.shape(l) for l in jax.tree.leaves(start)),
+            )
+            key = (app.model, app.local_steps, app.lr, app.mu, feat, params_sig)
+            groups.setdefault(key, []).append(j)
 
-    for key, idxs in groups.items():
-        model, steps, lr, mu, feat, _params_sig = key
-        logits_fn = sm.LOGITS[model]
-        w_tot = sum(len(jobs[j][1]) for j in idxs)
-        b_max = max(
-            len(jobs[j][0].data[w][1]) for j in idxs for w in jobs[j][1]
-        )
-        W = bucket_size(w_tot) if bucketed else w_tot
-        B = bucket_size(b_max) if bucketed else b_max
-        xs = np.zeros((W, B) + feat, np.float32)
-        ys = np.zeros((W, B), np.int32)
-        mask = np.zeros((W, B), np.float32)
-        row = 0
-        spans = []  # (job index, row offset, worker count)
-        for j in idxs:
-            app, workers, _ = jobs[j]
-            spans.append((j, row, len(workers)))
-            for w in workers:
-                x, yv = app.data[w]
-                b = len(yv)
-                xs[row, :b] = np.asarray(x, np.float32)
-                ys[row, :b] = np.asarray(yv, np.int32)
-                mask[row, :b] = 1.0
-                row += 1
-        # per-row start params; phantom rows reuse the first job's params
-        # (zero mask -> zero grads -> exactly-zero deltas, discarded)
-        first = jobs[idxs[0]][2]
-        if first is None:
-            first = jobs[idxs[0]][0].params
-        leaves0, treedef = jax.tree.flatten(first)
-        rows_per_leaf = [
-            np.empty((W,) + np.shape(l), np.asarray(l).dtype) for l in leaves0
-        ]
-        for j, off, count in spans:
-            start = jobs[j][2] if jobs[j][2] is not None else jobs[j][0].params
-            for arr, leaf in zip(rows_per_leaf, jax.tree.leaves(start)):
-                arr[off : off + count] = np.asarray(leaf)
-        for arr, leaf in zip(rows_per_leaf, leaves0):
-            arr[row:] = np.asarray(leaf)
-        params_stack = jax.tree.unflatten(
-            treedef, [jnp.asarray(a) for a in rows_per_leaf]
-        )
-        DISPATCH.record(("mega", model, steps, lr, mu, xs.shape, _params_sig))
-        new_params, losses = megabatched_local_train(
-            params_stack, jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask),
-            logits_fn=logits_fn, steps=steps, lr=lr, mu=mu,
-        )
-        stacked = jax.tree.map(lambda n, p: n - p, new_params, params_stack)
-        stacked_np = jax.tree.map(np.asarray, stacked)
-        losses_np = np.asarray(losses)
-        for j, off, count in spans:
-            app, workers, _ = jobs[j]
-            deltas = [
-                jax.tree.map(lambda l, i=off + i: l[i], stacked_np)
-                for i in range(count)
-            ]
-            weights = [float(len(app.data[w][1])) for w in workers]
-            results[j] = (deltas, weights, [float(l) for l in losses_np[off : off + count]])
-    return results
+        for key, idxs in groups.items():
+            model, steps, lr, mu, feat, _params_sig = key
+            logits_fn = sm.LOGITS[model]
+            w_tot = sum(len(jobs[j][1]) for j in idxs)
+            b_max = max(
+                len(jobs[j][0].data[w][1]) for j in idxs for w in jobs[j][1]
+            )
+            W = bucket_size(w_tot) if bucketed else w_tot
+            B = bucket_size(b_max) if bucketed else b_max
+            with tracing.span("train.pack"):
+                xs = np.zeros((W, B) + feat, np.float32)
+                ys = np.zeros((W, B), np.int32)
+                mask = np.zeros((W, B), np.float32)
+                row = 0
+                spans = []  # (job index, row offset, worker count)
+                for j in idxs:
+                    app, workers, _ = jobs[j]
+                    spans.append((j, row, len(workers)))
+                    for w in workers:
+                        x, yv = app.data[w]
+                        b = len(yv)
+                        xs[row, :b] = tracing.pull(x, np.float32)
+                        ys[row, :b] = tracing.pull(yv, np.int32)
+                        mask[row, :b] = 1.0
+                        row += 1
+                # per-row start params; phantom rows reuse the first job's params
+                # (zero mask -> zero grads -> exactly-zero deltas, discarded)
+                first = jobs[idxs[0]][2]
+                if first is None:
+                    first = jobs[idxs[0]][0].params
+                leaves0, treedef = jax.tree.flatten(first)
+                rows_per_leaf = [
+                    np.empty((W,) + np.shape(l), tracing.pull(l).dtype) for l in leaves0
+                ]
+                for j, off, count in spans:
+                    start = jobs[j][2] if jobs[j][2] is not None else jobs[j][0].params
+                    for arr, leaf in zip(rows_per_leaf, jax.tree.leaves(start)):
+                        arr[off : off + count] = tracing.pull(leaf)
+                for arr, leaf in zip(rows_per_leaf, leaves0):
+                    arr[row:] = tracing.pull(leaf)
+                params_stack = jax.tree.unflatten(
+                    treedef, [tracing.push(a) for a in rows_per_leaf]
+                )
+                xs_d, ys_d, mask_d = tracing.push(xs), tracing.push(ys), tracing.push(mask)
+            DISPATCH.record(("mega", model, steps, lr, mu, xs.shape, _params_sig))
+            new_params, losses = megabatched_local_train(
+                params_stack, xs_d, ys_d, mask_d,
+                logits_fn=logits_fn, steps=steps, lr=lr, mu=mu,
+            )
+            stacked = jax.tree.map(lambda n, p: n - p, new_params, params_stack)
+            stacked_np = jax.tree.map(tracing.pull, stacked)
+            losses_np = tracing.pull(losses)
+            for j, off, count in spans:
+                app, workers, _ = jobs[j]
+                deltas = [
+                    jax.tree.map(lambda l, i=off + i: l[i], stacked_np)
+                    for i in range(count)
+                ]
+                weights = [float(len(app.data[w][1])) for w in workers]
+                results[j] = (deltas, weights, [float(l) for l in losses_np[off : off + count]])
+        return results
 
 
 def run_round(system, app, *, use_kernel: bool = True, vectorized: bool = True) -> dict:
