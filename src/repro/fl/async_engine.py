@@ -46,8 +46,6 @@ from repro.fl.compression import (
     CompressionPolicy,
     apply_delta_chain,
     as_policy,
-    broadcast_key,
-    commit_key,
     quantize_broadcast_delta,
     quantize_delta,
 )
@@ -168,7 +166,7 @@ class AsyncTrainer:
         deltas from any base lands bit-for-bit on this state."""
         with tracing.span("broadcast"):
             if policy.downlink == "qsgd-int8":
-                qd = quantize_broadcast_delta(params, policy, broadcast_key(policy, ai, version))
+                qd = quantize_broadcast_delta(params, policy, app=ai, version=version)
                 deq = qd.dequantize()
                 return jax.tree.map(
                     lambda p, v: np.asarray(v, dtype=tracing.pull(p).dtype), params, deq
@@ -177,7 +175,7 @@ class AsyncTrainer:
                 lambda p, r: tracing.pull(p, np.float32) - tracing.pull(r, np.float32),
                 params, self._recon[ai],
             )
-            qd = quantize_broadcast_delta(delta, policy, broadcast_key(policy, ai, version))
+            qd = quantize_broadcast_delta(delta, policy, app=ai, version=version)
             cache = self._delta_cache[ai]
             cache[version] = qd
             for v in [v for v in cache if v <= version - int(policy.chain_cap)]:
@@ -242,7 +240,7 @@ class AsyncTrainer:
                                 target = jax.tree.map(
                                     lambda a, b: tracing.push(a, jnp.float32) + b, d, r
                                 )
-                        payload = quantize_delta(target, policy, commit_key(policy, ai, seq))
+                        payload = quantize_delta(target, policy, app=ai, seq=seq)
                         if policy.error_feedback:
                             deq = payload.dequantize()
                             self._ef[ai][w] = jax.tree.map(

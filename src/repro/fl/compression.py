@@ -26,6 +26,13 @@ not share rounding bias — the old deterministic default (``rand=0.5``
 everywhere) rounded every commit half-down identically.  A fixed
 (policy, app, seq) triple reproduces the wire bytes exactly.
 
+One quantize is two device programs: the grid program (``_grid``: the
+rounding key folded from the raw counters, the leaves raveled to f32,
+concatenated and zero-padded onto the (rows, chunk) grid, the uniforms
+drawn) and the kernel (``kernels.ops.qsgd_quantize``).  The counters
+enter as traced values, so one program per model shape serves every
+commit and every broadcast.
+
 Compressed downlink (docs/performance.md "compressed downlink"): the
 ``downlink`` axis governs the *broadcast* direction — the master's
 model downloads.  ``"qsgd-int8"`` quantizes each new version before it
@@ -45,6 +52,7 @@ chain into the held params in one pass (``apply_delta_chain``).
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any
@@ -255,6 +263,21 @@ def as_policy(value) -> CompressionPolicy | None:
     raise TypeError(f"expected CompressionPolicy, kind string or None, got {value!r}")
 
 
+_BROADCAST_LANE = 0x0D0C  # folded into the seed to root the downlink's keys
+
+
+@functools.lru_cache(maxsize=64)  # one root per policy seed and lane
+def _key_root(seed: int, lane: int | None):
+    """Root of a rounding-key chain: ``PRNGKey(seed)``, with ``lane``
+    folded in for the downlink."""
+    root = jax.random.PRNGKey(seed)
+    return root if lane is None else jax.random.fold_in(root, lane)
+
+
+def _fold(root, app, count):
+    return jax.random.fold_in(jax.random.fold_in(root, app), count)
+
+
 def commit_key(policy: CompressionPolicy, app_idx: int, commit_seq: int):
     """The per-commit rounding key: policy seed -> app -> commit number.
 
@@ -262,18 +285,18 @@ def commit_key(policy: CompressionPolicy, app_idx: int, commit_seq: int):
     commit (``AsyncTrainer.commit``), so the chain is deterministic for
     a given event trace: a fixed (seed, app, seq) reproduces the wire
     bytes exactly, while consecutive commits draw decorrelated uniforms
-    (tests/test_compression.py)."""
-    base = jax.random.PRNGKey(int(policy.seed))
-    return jax.random.fold_in(jax.random.fold_in(base, int(app_idx)), int(commit_seq))
+    (tests/test_compression.py).  ``quantize_delta(..., app=, seq=)``
+    folds the same key inside its grid program."""
+    return _fold(_key_root(int(policy.seed), None), int(app_idx), int(commit_seq))
 
 
 def broadcast_key(policy: CompressionPolicy, app_idx: int, version: int):
     """The per-broadcast rounding key: seed -> downlink lane -> app ->
     model version.  Folding a fixed lane constant first decorrelates the
     broadcast stream from the commit stream even when (app, version)
-    collides with some (app, seq)."""
-    base = jax.random.fold_in(jax.random.PRNGKey(int(policy.seed)), 0x0D0C)
-    return jax.random.fold_in(jax.random.fold_in(base, int(app_idx)), int(version))
+    collides with some (app, seq).  ``quantize_broadcast_delta(...,
+    app=, version=)`` folds the same key inside its grid program."""
+    return _fold(_key_root(int(policy.seed), _BROADCAST_LANE), int(app_idx), int(version))
 
 
 @dataclass(frozen=True)
@@ -325,38 +348,67 @@ class QuantizedDelta:
         return self.unflatten(flat.reshape(-1))
 
 
-def _flatten_grid(delta, chunk: int):
-    """Flatten a pytree onto the (rows, chunk) quantization grid."""
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _grid(leaves, key, app, count, *, chunk: int):
+    """The one program in front of the kernel: the leaves raveled to f32
+    in flatten order, zero-padded onto the (rows, chunk) grid, and the
+    uniforms that round it.  ``key`` is a chain root with the traced
+    counters ``app`` and ``count`` folded in, or with both ``None`` the
+    rounding key itself; ``key=None`` rounds half-down (``rand=0.5``)."""
+    flat = [jnp.ravel(l).astype(jnp.float32) for l in leaves]
+    n = sum(f.size for f in flat)
+    rows = max(1, math.ceil(n / chunk))
+    x2d = jnp.concatenate(flat + [jnp.zeros((rows * chunk - n,), jnp.float32)])
+    x2d = x2d.reshape(rows, chunk)
+    if key is None:
+        return x2d, jnp.full((rows, chunk), 0.5, jnp.float32)
+    if app is not None:
+        key = _fold(key, app, count)
+    return x2d, jax.random.uniform(key, (rows, chunk), jnp.float32)
+
+
+def _rounding(key, root, app, count):
+    """``_grid``'s rounding arguments: ``key`` as given, or with the raw
+    counters (``app``, ``count``) the chain ``root`` to fold them into."""
+    if (app is None) != (count is None):
+        raise ValueError("pass both counters of the rounding key, or neither")
+    if app is None:
+        return key, None, None
+    if key is not None:
+        raise ValueError("pass a rounding key or its counters, not both")
+    return root, np.uint32(app), np.uint32(count)
+
+
+def _to_grid(delta, chunk: int, key, app, count):
+    """``delta`` on the quantization grid with its uniforms, from one
+    ``_grid`` dispatch, and what rebuilds the pytree."""
     leaves, treedef = jax.tree.flatten(delta)
     shapes = tuple(np.shape(l) for l in leaves)
-    flat = jnp.concatenate(
-        [jnp.ravel(tracing.implicit_push(l)).astype(jnp.float32) for l in leaves]
-    ) if leaves else jnp.zeros((0,), jnp.float32)
-    n = int(flat.size)
-    rows = max(1, math.ceil(n / chunk))
-    padded = jnp.zeros((rows * chunk,), jnp.float32).at[:n].set(flat)
-    return padded.reshape(rows, chunk), flat, n, shapes, treedef
+    x2d, rand = _grid(
+        [tracing.implicit_push(l) for l in leaves], key, app, count, chunk=chunk
+    )
+    return x2d, rand, sum(math.prod(s) for s in shapes), shapes, treedef
 
 
-def _qsgd_grid(x2d, key, levels: int):
-    """QSGD-quantize one (rows, chunk) grid, kernel-routed when the
-    chunking matches the Pallas 256-lane row."""
-    rows, chunk = x2d.shape
-    if key is None:
-        rand = jnp.full((rows, chunk), 0.5, jnp.float32)
-    else:
-        rand = jax.random.uniform(key, (rows, chunk), jnp.float32)
-    if chunk == 256:
+def _qsgd_grid(x2d, rand, levels: int):
+    """QSGD-quantize one (rows, chunk) grid: one kernel dispatch when the
+    chunking matches the Pallas 256-lane row, else the pure-JAX path."""
+    if x2d.shape[1] == 256:
         from repro.kernels import ops as kops
 
         return kops.qsgd_quantize(x2d, rand, levels=levels)
     return qsgd_quantize(x2d, levels=levels, rand=rand)
 
 
-def quantize_delta(delta, policy: CompressionPolicy, key=None) -> QuantizedDelta:
+def quantize_delta(
+    delta, policy: CompressionPolicy, key=None, *, app=None, seq=None
+) -> QuantizedDelta:
     """Serialize an update pytree under ``policy`` (must be enabled).
 
-    qsgd-int8 routes through the kernel wrapper (``kernels.ops.
+    Rounding: ``app`` and ``seq`` (the commit path) fold
+    ``commit_key(policy, app, seq)`` inside the grid program; else
+    ``key`` is the rounding key, and ``key=None`` rounds half-down
+    (tests only).  qsgd-int8 is then one kernel dispatch (``kernels.ops.
     qsgd_quantize``: Pallas on TPU, compiled ref off-TPU) when the
     chunking matches the kernel's 256-lane row; any other ``chunk``
     takes the pure-JAX path — both are bit-identical given the same
@@ -366,14 +418,17 @@ def quantize_delta(delta, policy: CompressionPolicy, key=None) -> QuantizedDelta
     QSGD-quantizes the survivors.  All three ride ``QuantizedDelta`` —
     the same buffer, the same fused dequantize-in-aggregate apply path —
     with ``wire_nbytes`` carrying the packed/sparse wire model where the
-    int8 grid overstates it.  ``key=None`` falls back to deterministic
-    round-half-down (tests only; the commit path always threads
-    ``commit_key``)."""
+    int8 grid overstates it.  Counts ``quantize_fused`` for a call that
+    was the grid program and one kernel dispatch, else
+    ``quantize_eager``."""
     if not policy.enabled:
         raise ValueError("quantize_delta requires an enabled policy (kind != 'none')")
     with tracing.span("quantize"):
         chunk = int(policy.chunk)
-        x2d, flat, n, shapes, treedef = _flatten_grid(delta, chunk)
+        rounding = _rounding(key, _key_root(int(policy.seed), None), app, seq)
+        if policy.kind == "signsgd":  # signs draw no uniforms
+            rounding = (None, None, None)
+        x2d, rand, n, shapes, treedef = _to_grid(delta, chunk, *rounding)
         wire = None
         if policy.kind == "signsgd":
             rows = x2d.shape[0]
@@ -384,15 +439,18 @@ def quantize_delta(delta, policy: CompressionPolicy, key=None) -> QuantizedDelta
         elif policy.kind == "topk":
             k = max(1, math.ceil(n * float(policy.topk_frac)))
             if n > k:
+                flat = x2d.reshape(-1)[:n]
                 _, idx = jax.lax.top_k(jnp.abs(flat), k)
                 sparse = jnp.zeros_like(flat).at[idx].set(flat[idx])
                 rows = x2d.shape[0]
                 x2d = jnp.zeros((rows * chunk,), jnp.float32).at[:n].set(sparse)
                 x2d = x2d.reshape(rows, chunk)
-            q, s = _qsgd_grid(x2d, key, int(policy.levels))
+            q, s = _qsgd_grid(x2d, rand, int(policy.levels))
             wire = policy.wire_bytes(4.0 * n)
         else:
-            q, s = _qsgd_grid(x2d, key, int(policy.levels))
+            q, s = _qsgd_grid(x2d, rand, int(policy.levels))
+        fused = policy.kind == "qsgd-int8" and chunk == 256
+        tracing.count("quantize_fused" if fused else "quantize_eager")
         return QuantizedDelta(
             q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,
             treedef=treedef, levels=int(policy.levels), chunk=chunk,
@@ -407,20 +465,28 @@ def dequantize_delta(qd: QuantizedDelta) -> Any:
 # -- downlink: version deltas + fused chain application ------------------------
 
 
-def quantize_broadcast_delta(delta, policy: CompressionPolicy, key=None) -> QuantizedDelta:
+def quantize_broadcast_delta(
+    delta, policy: CompressionPolicy, key=None, *, app=None, version=None
+) -> QuantizedDelta:
     """Serialize one version delta for the broadcast direction: QSGD on
     the coarse ``downlink_levels`` lattice, ``wire_nbytes`` set to the
     bit-packed size (``delta_wire_bytes``) the scheduler prices chained
-    downloads at."""
+    downloads at.  Rounding as in ``quantize_delta``: ``app`` and
+    ``version`` fold ``broadcast_key(policy, app, version)`` inside the
+    grid program, else ``key`` as given."""
     if not policy.downlink_enabled:
         raise ValueError(
             "quantize_broadcast_delta requires an enabled downlink (downlink != 'none')"
         )
     with tracing.span("quantize"):
         chunk = int(policy.chunk)
-        x2d, _, n, shapes, treedef = _flatten_grid(delta, chunk)
+        root = _key_root(int(policy.seed), _BROADCAST_LANE)
+        x2d, rand, n, shapes, treedef = _to_grid(
+            delta, chunk, *_rounding(key, root, app, version)
+        )
         levels = int(policy.downlink_levels) if policy.downlink == "delta-qsgd" else int(policy.levels)
-        q, s = _qsgd_grid(x2d, key, levels)
+        q, s = _qsgd_grid(x2d, rand, levels)
+        tracing.count("quantize_fused" if chunk == 256 else "quantize_eager")
         wire = policy.downlink_wire_bytes(4.0 * n, chain=1)
         return QuantizedDelta(
             q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,

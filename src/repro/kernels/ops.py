@@ -236,15 +236,12 @@ def _qsgd_dequantize_jnp(q, scale):
 
 
 def qsgd_quantize(x: jax.Array, rand: jax.Array, *, levels: int = 127):
-    """(R, 256) -> (int8, scales); pads rows to the block size.
+    """(R, 256) -> (int8, scales), any R: one dispatch of the kernel's
+    own program, which takes the ragged last row block itself.
     ``levels`` (static) is the per-sign lattice size (<= 127)."""
     if _use_jnp():
         return _qsgd_quantize_jnp(x, rand, levels=levels)
-    xp, pad = _pad_to(x, _q.ROWS_PER_BLOCK, axis=0)
-    rp, _ = _pad_to(rand, _q.ROWS_PER_BLOCK, axis=0)
-    q, s = _q.qsgd_quantize(xp, rp, interpret=_interpret(), levels=levels)
-    R = x.shape[0]
-    return q[:R], s[:R]
+    return _q.qsgd_quantize(x, rand, interpret=_interpret(), levels=levels)
 
 
 def qsgd_dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:

@@ -7,7 +7,9 @@ bits so the kernel is bit-identical to ``ref.quantize_ref`` (and to the
 pure-JAX path used inside the train step).
 
 Tiling: (ROWS_PER_BLOCK, 256) blocks in VMEM — the trailing 256 is lane-
-aligned; row blocks keep the footprint < 1 MB.
+aligned; row blocks keep the footprint < 1 MB.  Any row count R runs on
+``cdiv(R, ROWS_PER_BLOCK)`` blocks: rows are independent, so the last
+block's rows past R only feed outputs that are never written back.
 """
 from __future__ import annotations
 
@@ -40,13 +42,13 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
 def qsgd_quantize(
     x: jax.Array, rand: jax.Array, *, interpret: bool = False, levels: int = LEVELS
 ):
-    """x, rand: (R, 256) with R % ROWS_PER_BLOCK == 0 -> (int8 (R,256), f32 (R,1)).
+    """x, rand: (R, 256), any R >= 1 -> (int8 (R,256), f32 (R,1)).
 
     ``levels`` (static, <= 127) is the per-sign lattice size — the
     ``CompressionPolicy.levels`` knob; the grid respecializes per value."""
     R, W = x.shape
-    assert W == ROW and R % ROWS_PER_BLOCK == 0, (R, W)
-    grid = (R // ROWS_PER_BLOCK,)
+    assert W == ROW, (R, W)
+    grid = (pl.cdiv(R, ROWS_PER_BLOCK),)
     return pl.pallas_call(
         functools.partial(_quant_kernel, levels=levels),
         grid=grid,

@@ -25,9 +25,11 @@ Span names used by the program:
   the wait for the program that produces it.
 
 ``counters`` are monotone: device arrays copied to the host
-(``d2h_pulls``, ``d2h_bytes``) and host values uploaded, explicitly by
+(``d2h_pulls``, ``d2h_bytes``), host values uploaded, explicitly by
 ``push`` or inside the jax operation ``implicit_push`` hands them to
-(``h2d_pushes``, ``h2d_bytes``); they move only while tracing is on.
+(``h2d_pushes``, ``h2d_bytes``), and quantize calls that ran as the grid
+program and one kernel dispatch (``quantize_fused``) or took an eager
+branch (``quantize_eager``); they move only while tracing is on.
 """
 from __future__ import annotations
 
@@ -40,7 +42,10 @@ import numpy as np
 _on = False
 records: list[list] = []  # [name, t0, t1, parent, attrs]
 _open: list[int] = []     # indices in ``records`` of the spans open now
-counters = {"d2h_pulls": 0, "d2h_bytes": 0, "h2d_pushes": 0, "h2d_bytes": 0}
+counters = {
+    "d2h_pulls": 0, "d2h_bytes": 0, "h2d_pushes": 0, "h2d_bytes": 0,
+    "quantize_fused": 0, "quantize_eager": 0,
+}
 
 
 class _Off:
@@ -110,6 +115,12 @@ def clear() -> None:
     if _open:
         raise RuntimeError(f"{len(_open)} span(s) still open")
     records.clear()
+
+
+def count(name: str) -> None:
+    """Add one to ``counters[name]`` while tracing is on."""
+    if _on:
+        counters[name] += 1
 
 
 def host_cached(x) -> bool:

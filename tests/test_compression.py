@@ -234,6 +234,109 @@ def test_commit_key_reproduces_and_decorrelates():
     np.testing.assert_array_equal(qa.scale, qc.scale)  # scales are rand-free
 
 
+# -- one grid program per quantize == the eager sequence it replaced ----------
+
+
+def _eager_quantize(delta, chunk, key, levels):
+    """The oracle: the eager flatten, pad, draw and padded kernel call
+    that ``quantize_delta`` ran as separate dispatches before the grid
+    program folded them together."""
+    leaves, _ = jax.tree.flatten(delta)
+    flat = jnp.concatenate([jnp.ravel(l).astype(jnp.float32) for l in leaves])
+    n = int(flat.size)
+    rows = max(1, math.ceil(n / chunk))
+    x2d = jnp.zeros((rows * chunk,), jnp.float32).at[:n].set(flat).reshape(rows, chunk)
+    if key is None:
+        rand = jnp.full((rows, chunk), 0.5, jnp.float32)
+    else:
+        rand = jax.random.uniform(key, (rows, chunk), jnp.float32)
+    if kops.kernel_mode() == "jnp":
+        q, s = kops._qsgd_quantize_jnp(x2d, rand, levels=levels)
+    else:
+        pad = ((0, (-rows) % kq.ROWS_PER_BLOCK), (0, 0))
+        q, s = kq.qsgd_quantize(jnp.pad(x2d, pad), jnp.pad(rand, pad), interpret=True,
+                                levels=levels)
+    return np.asarray(q)[:rows], np.asarray(s)[:rows], n
+
+
+_GRID_CASES = {
+    # FedAvg's MNIST 2NN, 784-200-200-10: 199,210 values on 779 rows
+    "mnist-2nn": {"w1": (784, 200), "b1": (200,), "w2": (200, 200), "b2": (200,),
+                  "w3": (200, 10), "b3": (10,)},
+    "ragged": {"a": (13, 7), "b": (5,)},
+    "exact-rows": {"w": (4, 128)},
+    "one-element": {"s": ()},
+    "bf16": {"w": (300,), "b": (7,)},
+}
+
+
+def _grid_delta(case, seed=0):
+    rng = np.random.default_rng(seed)
+    delta = {k: rng.normal(0, 0.1, s).astype(np.float32) for k, s in _GRID_CASES[case].items()}
+    if case == "bf16":
+        delta["w"] = delta["w"].astype(jnp.bfloat16)
+    return delta
+
+
+def _same(qd, oracle, delta):
+    q, s, n = oracle
+    np.testing.assert_array_equal(qd.q, q)
+    np.testing.assert_array_equal(qd.scale, s)
+    assert qd.length == n
+    assert qd.shapes == tuple(np.shape(l) for l in jax.tree.leaves(delta))
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+@pytest.mark.parametrize("rounding", ["commit", "broadcast", "key", "half"])
+@pytest.mark.parametrize("case", list(_GRID_CASES))
+def test_grid_program_bit_identical_to_eager_sequence(kernel_mode_guard, mode, rounding, case):
+    kops.set_kernel_mode(mode)
+    delta = _grid_delta(case)
+    for levels in (127, 7):
+        if rounding == "broadcast":
+            policy = CompressionPolicy(kind="qsgd-int8", seed=11, downlink="delta-qsgd",
+                                       downlink_levels=levels)
+            qd = comp.quantize_broadcast_delta(delta, policy, app=2, version=9)
+            oracle = _eager_quantize(delta, 256, comp.broadcast_key(policy, 2, 9), levels)
+        else:
+            policy = CompressionPolicy(kind="qsgd-int8", seed=11, levels=levels)
+            if rounding == "commit":
+                qd = comp.quantize_delta(delta, policy, app=3, seq=40)
+                key = comp.commit_key(policy, 3, 40)
+            else:
+                key = jax.random.PRNGKey(5) if rounding == "key" else None
+                qd = comp.quantize_delta(delta, policy, key)
+            oracle = _eager_quantize(delta, 256, key, levels)
+        assert qd.levels == levels
+        _same(qd, oracle, delta)
+
+
+def test_grid_program_compiles_once_per_shape_set():
+    """Counters are traced: ten commits across three apps and five
+    broadcast versions run one compiled grid program per model shape."""
+    policy = CompressionPolicy(kind="qsgd-int8", seed=4, downlink="delta-qsgd")
+    before = comp._grid._cache_size()
+    for shapes in ({"w": (37, 11), "b": (11,)}, {"w": (41, 13), "b": (13,)}):
+        rng = np.random.default_rng(0)
+        delta = {k: rng.normal(size=s).astype(np.float32) for k, s in shapes.items()}
+        qs = [comp.quantize_delta(delta, policy, app=i % 3, seq=i) for i in range(10)]
+        qs += [comp.quantize_broadcast_delta(delta, policy, app=1, version=v) for v in range(5)]
+        assert len({q.q.tobytes() for q in qs}) == len(qs)  # every key differs
+        before += 1
+        assert comp._grid._cache_size() == before
+
+
+def test_rounding_key_or_its_counters():
+    policy = CompressionPolicy(kind="qsgd-int8", downlink="qsgd-int8")
+    delta = {"w": np.ones(5, np.float32)}
+    with pytest.raises(ValueError, match="not both"):
+        comp.quantize_delta(delta, policy, jax.random.PRNGKey(0), app=0, seq=0)
+    with pytest.raises(ValueError, match="both counters"):
+        comp.quantize_delta(delta, policy, app=0)
+    with pytest.raises(ValueError, match="both counters"):
+        comp.quantize_broadcast_delta(delta, policy, version=3)
+
+
 # -- fused dequantize-in-aggregate ---------------------------------------------
 
 

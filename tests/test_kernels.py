@@ -61,6 +61,26 @@ def test_quantize_bit_exact_and_bounded(R):
     assert bool(jnp.all(jnp.abs(deq - x) <= s + 1e-6))
 
 
+@pytest.mark.parametrize("R", [1, 255, 257, 779])
+def test_quantize_kernel_takes_ragged_rows(R):
+    """The Pallas kernel (interpret) at a row count off its block size:
+    equal to the reference, and bit for bit to the block-padded call."""
+    from repro.kernels import quantize
+
+    key = jax.random.key(R)
+    x = jax.random.normal(key, (R, 256)) * 5
+    rnd = jax.random.uniform(jax.random.fold_in(key, 1), (R, 256))
+    q, s = quantize.qsgd_quantize(x, rnd, interpret=True)
+    assert q.shape == (R, 256) and s.shape == (R, 1)
+    qr, sr = ref.quantize_ref(x, rnd)
+    assert bool(jnp.all(q == qr))
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
+    pad = ((0, (-R) % quantize.ROWS_PER_BLOCK), (0, 0))
+    qp, sp = quantize.qsgd_quantize(jnp.pad(x, pad), jnp.pad(rnd, pad), interpret=True)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(qp)[:R])
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sp)[:R])
+
+
 def test_quantize_unbiased_with_uniform_noise():
     """E[dequant] == x under stochastic rounding (QSGD property)."""
     key = jax.random.key(3)

@@ -75,11 +75,20 @@ def test_tree_aggregate_groups_compiles(one_chip, G, C, L):
     )
 
 
-@pytest.mark.parametrize("levels", [127, CompressionPolicy().downlink_levels])
-def test_qsgd_quantize_compiles(one_chip, levels):
+@pytest.mark.parametrize(
+    "rows,levels",
+    [
+        pytest.param(ROWS, 127, id="127"),
+        pytest.param(ROWS, CompressionPolicy().downlink_levels,
+                     id=str(CompressionPolicy().downlink_levels)),
+        # the MNIST 2NN's delta: a ragged last row block
+        pytest.param(779, 127, id="rows779"),
+    ],
+)
+def test_qsgd_quantize_compiles(one_chip, rows, levels):
     _compile(
         lambda x, r: quantize.qsgd_quantize(x, r, levels=levels), one_chip,
-        ((ROWS, quantize.ROW), jnp.float32), ((ROWS, quantize.ROW), jnp.float32),
+        ((rows, quantize.ROW), jnp.float32), ((rows, quantize.ROW), jnp.float32),
     )
 
 

@@ -200,3 +200,18 @@ def test_async_run_is_the_same_with_the_tracer_on(tracer):
     assert after["d2h_pulls"] > before["d2h_pulls"]
     assert after["h2d_bytes"] > before["h2d_bytes"]
     assert tracer._open == []
+
+
+def test_every_quantize_of_a_run_is_one_grid_program_and_one_kernel(tracer):
+    """With the benchmark's policy (qsgd-int8 commits, delta-qsgd
+    broadcasts) every commit and every broadcast quantize is fused."""
+    tracer.enable()
+    before = tracer.snapshot()
+    res, _ = _run()
+    tracer.disable()
+    after = tracer.snapshot()
+    calls = sum(r[0] == "quantize" for r in tracer.records)
+    commits = sum(h["arrivals"] for h in res["history"])
+    assert calls == commits + len(res["history"]) > 0
+    assert after["quantize_fused"] - before["quantize_fused"] == calls
+    assert after["quantize_eager"] == before["quantize_eager"]
