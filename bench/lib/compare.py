@@ -34,17 +34,25 @@ from __future__ import annotations
 
 import math
 
+import jax
 import numpy as np
 
 NEGLIGIBLE = 1e-3  # of the median leaf's first update: moves by round-off alone
 
 
-def _norms(tree: dict) -> dict[str, float]:
-    return {k: float(np.linalg.norm(np.asarray(v, np.float64).ravel())) for k, v in tree.items()}
+def _leaves(tree) -> dict[str, np.ndarray]:
+    """The leaves of a weights pytree by their path, in float64."""
+    return {jax.tree_util.keystr(path): np.asarray(v, np.float64)
+            for path, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def _diff(a: dict, b: dict) -> dict:
-    return {k: np.asarray(a[k], np.float64) - np.asarray(b[k], np.float64) for k in b}
+def _norms(leaves: dict) -> dict[str, float]:
+    return {k: float(np.linalg.norm(v.ravel())) for k, v in leaves.items()}
+
+
+def _diff(a, b) -> dict:
+    la, lb = _leaves(a), _leaves(b)
+    return {k: la[k] - lb[k] for k in lb}
 
 
 def worst_leaf_gap(got: dict, ref: dict, keep: list[str]) -> float:

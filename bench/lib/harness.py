@@ -6,7 +6,8 @@ result line.
 installed, warms up until at least ``warm_applies`` applies have run and
 the last ``quiet_applies`` built no program, measures the next
 ``seconds`` (whole applies), and, with
-``trace``, records a profiler trace of exactly that window.  Once the
+``trace``, records a profiler trace of exactly that window and turns the
+program's own spans and counters (``repro.tracing``) on for it.  Once the
 window has closed and the device's peak memory is read, the program's
 state is freed and the reference follows the apps drawn as the window
 opened through every apply up to the last of the window; the numbers of
@@ -87,6 +88,11 @@ class RunData:
     compiles: int          # XLA programs built in the window
     kernel_calls: list     # (kernel, t, arg shapes, kwargs), in the window
     trace: trace_mod.Reduced | None = None
+    # the program's spans in a traced window, (name, t0, t1, parent,
+    # self_s), and its counters' growth over the window; None where the
+    # run was not traced or the program has no ``repro.tracing``
+    program: list | None = None
+    counters: dict | None = None
 
     @property
     def n(self) -> int:
@@ -98,12 +104,24 @@ class RunData:
     def per_apply_ms(self, *names: str) -> float | None:
         return 1e3 * self.span_s(*names) / self.n if self.n else None
 
+    def self_ms_per_apply(self, *names: str) -> float | None:
+        """Self time of the program's spans ``names``, per apply (ms);
+        ``None`` where none of them ran."""
+        if self.program is None or not self.n:
+            return None
+        own = [s[4] for s in self.program if s[0] in names]
+        return 1e3 * sum(own) / self.n if own else None
+
+    def counted_per_apply(self, name: str) -> float | None:
+        """Growth of the program's counter ``name`` over the window, per apply."""
+        if self.counters is None or name not in self.counters or not self.n:
+            return None
+        return self.counters[name] / self.n
+
     def train_flops(self) -> float:
-        """Matmul operations of the local training the window's applies
-        required: per commit, steps x shard x per-sample operations."""
-        cfg = self.spec.config
-        per_commit = (int(cfg["local_steps"]) * int(cfg["shard"])
-                      * spec_mod.mlp_flops_per_sample(self.spec.model))
+        """Operations of the local training the window's applies
+        required: per commit, as the model's kind counts them."""
+        per_commit = self.spec.kind.train_flops(self.spec.model, self.spec.config)
         return float(sum(a[3] for a in self.applies) * per_commit)
 
     def kernel_cost(self, kernel: str) -> tuple[float, float, int]:
@@ -177,6 +195,7 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, log: probe.Comp
     t_built = time.perf_counter()
 
     tracing: dict = {}
+    prog = probe.program_tracing() if trace else None
 
     def on_open():
         if trace:
@@ -189,11 +208,18 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, log: probe.Comp
             jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
             tracing["window"] = jax.profiler.TraceAnnotation(trace_mod.WINDOW)
             tracing["window"].__enter__()
+            if prog is not None:
+                tracing["first"], tracing["counters"] = len(prog.records), prog.snapshot()
+                prog.enable()
 
     def on_close():
         if trace:
             import jax
 
+            if prog is not None:
+                prog.disable()
+                c0 = tracing["counters"]
+                tracing["counters"] = {k: v - c0.get(k, 0) for k, v in prog.snapshot().items()}
             tracing["window"].__exit__(None, None, None)
             jax.profiler.stop_trace()
 
@@ -209,6 +235,8 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, log: probe.Comp
         _drive(spec, dep, rec)
     finally:
         gclog.close()
+        if prog is not None:
+            prog.disable()
     w = rec.window
     window_s = w.t_close - w.t_open
     device["memory_peak_bytes"] = memory_peak()
@@ -235,6 +263,10 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, log: probe.Comp
         events=w.events_close - w.events_open,
         compiles=len(log.between(w.t_open, w.t_close)), kernel_calls=list(rec.kernel_calls),
     )
+    if prog is not None:
+        run.program = probe.program_spans(prog.records, tracing["first"], w.t_open, w.t_close)
+        run.counters = tracing["counters"]
+        prog.clear()
     setup_s = w.t_open - t_start
     setup = {"build_s": t_built - t_start, "warm_s": w.t_open - t_built,
              "warm_applies": len(rec.warm_marks),

@@ -10,13 +10,17 @@ after it, around the calls into each layer:
 - ``quantize_delta`` and ``TotoroSystem.CommitDelta`` (span ``commit``);
 - ``TotoroSystem.ApplyBuffered`` and ``AsyncTrainer._broadcast_state``
   (span ``aggregate``);
-- ``kernels.ops.tree_aggregate_groups`` / ``qsgd_quantize`` /
-  ``apply_quantized_broadcast``: the shapes each kernel is called with,
-  for the roofline readers (no timing);
+- the ``CALL`` of every cost model under ``bench/kernels/``, in its
+  ``MODULE`` (``repro.kernels.ops`` unless the cost model says
+  otherwise): the shapes each kernel is called with, for the roofline
+  readers (no timing);
 - ``AsyncTrainer.begin_download`` / ``commit`` / ``drop``: the
   benchmark's own bookkeeping of which commit trained from which
   version, for the reference, and against which every apply's record
-  (version, commits, staleness, simulated time) is checked.
+  (version, commits, staleness, simulated time) is checked.  This
+  bookkeeping runs inside the program's own span ``bench``
+  (``repro.tracing``), so that the program's spans do not count it as
+  theirs.
 
 The window opens at the end of an apply once at least ``warm_applies``
 applies have run, ``follow`` apps have their first three applies in, and
@@ -38,6 +42,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import gc
+import importlib
 import time
 from dataclasses import dataclass, field
 
@@ -104,8 +109,38 @@ class Window:
     events_close: int = 0
 
 
-def host_copy(tree: dict) -> dict:
-    return {k: np.asarray(v) for k, v in tree.items()}
+def host_copy(tree):
+    """A pytree of arrays, copied to the host."""
+    import jax
+
+    return jax.tree.map(np.asarray, tree)
+
+
+def program_tracing():
+    """The program's ``repro.tracing`` module, or ``None`` where the
+    program has none."""
+    try:
+        return importlib.import_module("repro.tracing")
+    except ModuleNotFoundError as e:
+        if e.name not in ("repro", "repro.tracing"):
+            raise
+        return None
+
+
+def program_spans(records: list, first: int, t_open: float, t_close: float) -> list:
+    """The program's span records from index ``first`` on, as
+    ``(name, t0, t1, parent, self_s)``: times cut to the window, parents
+    renumbered from ``first`` (``-1`` for a parent outside), and the self
+    time, the duration less that of the span's children."""
+    out = []
+    for name, t0, t1, parent, _attrs in records[first:]:
+        t0, t1 = max(t0, t_open), min(t1, t_close)
+        out.append([name, t0, max(t1, t0), parent - first if parent >= first else -1, 0.0])
+    for s in out:
+        s[4] += s[2] - s[1]
+        if s[3] >= 0:
+            out[s[3]][4] -= s[2] - s[1]
+    return [tuple(s) for s in out]
 
 
 @dataclass
@@ -275,10 +310,11 @@ def _shape(x):
 @contextlib.contextmanager
 def installed(rec: Recorder, system):
     """Install every probe for one run; restore the program on exit."""
+    from bench.lib.spec import kernel, kernels
     from repro.core import sim
     from repro.fl import async_engine, engine
-    from repro.kernels import ops
 
+    tracing = program_tracing()
     trainer_cls = async_engine.AsyncTrainer
     saved = []
 
@@ -313,7 +349,9 @@ def installed(rec: Recorder, system):
             t0 = rec.clock()
             with rec.span("apply"):
                 record = orig(self, ai, t, **kw)
-            rec.after_apply(self, ai, t0, rec.clock(), commits, record, t_sim=t)
+            t1 = rec.clock()
+            with tracing.span("bench") if tracing else contextlib.nullcontext():
+                rec.after_apply(self, ai, t0, t1, commits, record, t_sim=t)
             return record
         return apply
 
@@ -362,8 +400,10 @@ def installed(rec: Recorder, system):
         patch(async_engine, "quantize_delta", spanned("commit"))
         patch(system, "CommitDelta", spanned("commit"))
         patch(system, "ApplyBuffered", spanned("aggregate"))
-        for k in ("tree_aggregate_groups", "qsgd_quantize", "apply_quantized_broadcast"):
-            patch(ops, k, shapes(k))
+        calls = {(getattr(km, "MODULE", "repro.kernels.ops"), km.CALL)
+                 for km in map(kernel, kernels())}
+        for module, call in sorted(calls):
+            patch(importlib.import_module(module), call, shapes(call))
         yield rec
     finally:
         for owner, name, own in reversed(saved):

@@ -10,12 +10,13 @@ number within its app.  From these it recomputes, in straightforward
 (``matmul_precision``, one bfloat16 pass in the deployment's file):
 
 1. local training: ``steps`` SGD steps at ``lr`` on the worker's whole
-   shard, cross-entropy mean over its samples, from the state the worker
-   downloaded; the commit is new minus start;
+   shard, under the plain loss of the model's kind
+   (``bench/models/<kind>.py``), from the state the worker downloaded;
+   the commit is new minus start;
 2. commit quantization (``commit="qsgd-int8"``): the update's leaves in
-   wire order (sorted by name) are concatenated, zero-padded to rows of
-   256, and each row is rounded stochastically to ``levels`` steps of
-   ``max|row| / levels``; the rounding draws ``uniform(key, (rows,
+   wire order (jax's flatten order: by key, nested dicts depth first)
+   are concatenated, zero-padded to rows of 256, and each row is rounded
+   stochastically to ``levels`` steps of ``max|row| / levels``; the rounding draws ``uniform(key, (rows,
    256))`` with ``key = fold_in(fold_in(PRNGKey(seed), app), commit)``;
 3. buffered aggregation: commit weights ``shard / (1 + staleness) **
    alpha`` with staleness the apply's version minus the commit's,
@@ -47,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.lib.spec import model_kind
+
 MODES = ("sound", "fp8", "bf16", "int4", "frozen", "half", "altered")
 BROADCAST_LANE = 0x0D0C
 CHUNK = 256
@@ -57,9 +60,9 @@ class AppResult:
     """One app after each followed apply: weights, mean local loss and
     the state a worker downloading that version trains from."""
 
-    params: list    # per apply: {leaf: np.ndarray}
+    params: list    # per apply: the weights' pytree of np.ndarray
     losses: list    # per apply: float
-    held: list      # per apply: {leaf: np.ndarray}
+    held: list      # per apply: the same pytree
 
 
 def _fp8(a):
@@ -70,32 +73,32 @@ def _fp8(a):
     return a + jax.lax.stop_gradient(r - a)
 
 
-def _logits(p, x, fp8: bool = False):
-    mm = (lambda a, b: _fp8(a) @ _fp8(b)) if fp8 else (lambda a, b: a @ b)
-    h = jax.nn.relu(mm(x, p["w1"]) + p["b1"])
-    h = jax.nn.relu(mm(h, p["w2"]) + p["b2"])
-    return mm(h, p["w3"]) + p["b3"]
+def _cast(tree, dtype):
+    """The float leaves of ``tree`` in ``dtype``; other leaves as they are."""
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
 
-@partial(jax.jit, static_argnames=("steps", "lr", "dtype", "fp8"))
-def _local_sgd(p0, x, y, *, steps: int, lr: float, dtype: str, fp8: bool = False):
-    """``steps`` SGD steps from ``p0`` on (x, y); returns the update in
-    float32 and the mean of the step losses."""
+def _samples(batch) -> int:
+    return int(jax.tree.leaves(batch)[0].shape[0])
+
+
+@partial(jax.jit, static_argnames=("loss", "steps", "lr", "dtype", "fp8"))
+def _local_sgd(p0, batch, *, loss, steps: int, lr: float, dtype: str, fp8: bool = False):
+    """``steps`` SGD steps from ``p0`` on ``batch`` under the kind's
+    ``loss``; returns the update in float32 and the mean of the step
+    losses."""
     dt = jnp.dtype(dtype)
-    p = {k: v.astype(dt) for k, v in p0.items()}
-    x = x.astype(dt)
-
-    def loss(q):
-        lp = jax.nn.log_softmax(_logits(q, x, fp8))
-        return -jnp.mean(jnp.take_along_axis(lp, y[:, None], axis=1))
+    mm = (lambda a, b: _fp8(a) @ _fp8(b)) if fp8 else (lambda a, b: a @ b)
+    p = _cast(p0, dt)
 
     losses = []
     start = p
     for _ in range(steps):
-        value, grad = jax.value_and_grad(loss)(p)
-        p = {k: p[k] - jnp.asarray(lr, dt) * grad[k] for k in p}
+        value, grad = jax.value_and_grad(lambda q: loss(q, batch, mm=mm, dtype=dt))(p)
+        p = jax.tree.map(lambda a, g: a - jnp.asarray(lr, dt) * g, p, grad)
         losses.append(value.astype(jnp.float32))
-    update = {k: (p[k] - start[k]).astype(jnp.float32) for k in p}
+    update = jax.tree.map(lambda a, s: (a - s).astype(jnp.float32), p, start)
     return update, jnp.mean(jnp.stack(losses))
 
 
@@ -112,21 +115,18 @@ def _round_rows(flat, key, *, levels: int):
     return (q * scale).reshape(-1)[:n]
 
 
-def _keys(leaves) -> list[str]:
-    return sorted(leaves)  # wire order: a dict pytree flattens by sorted key
-
-
 def _flat(tree):
-    return jnp.concatenate([jnp.ravel(tree[k]).astype(jnp.float32) for k in _keys(tree)])
+    return jnp.concatenate([jnp.ravel(a).astype(jnp.float32) for a in jax.tree.leaves(tree)])
 
 
 def _unflat(vec, like):
-    out, off = {}, 0
-    for k in _keys(like):
-        size = int(np.prod(like[k].shape))
-        out[k] = vec[off:off + size].reshape(like[k].shape)
+    leaves, treedef = jax.tree.flatten(like)
+    out, off = [], 0
+    for a in leaves:
+        size = int(np.prod(a.shape))
+        out.append(vec[off:off + size].reshape(a.shape))
         off += size
-    return out
+    return jax.tree.unflatten(treedef, out)
 
 
 def commit_key(seed: int, app: int, commit: int):
@@ -150,19 +150,21 @@ def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
     dtype = "bfloat16" if mode == "bf16" else "float32"
     steps, lr = int(config["local_steps"]), float(config["lr"])
     alpha = float(config["staleness_alpha"])
+    loss_fn = model_kind(config["model"]).loss
 
     with jax.default_matmul_precision(str(config["matmul_precision"])):
-        p = {k: jnp.asarray(v, jnp.float32) for k, v in params0.items()}
+        p = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params0)
         recon = p
         held = {0: p}
         out = AppResult([], [], [])
         for v, commits in enumerate(schedule):
             updates, weights, stale, losses = [], [], [], []
             for i, (worker, base, seq) in enumerate(sorted(commits, key=lambda c: c[1])):
-                x, y = data[worker]
+                batch = data[worker]
                 if mode == "half":
-                    x, y = x[: len(y) // 2], y[: len(y) // 2]
-                upd, loss = _local_sgd(held[base], jnp.asarray(x), jnp.asarray(y),
+                    half = _samples(batch) // 2
+                    batch = jax.tree.map(lambda a: a[:half], batch)
+                upd, loss = _local_sgd(held[base], jax.tree.map(jnp.asarray, batch), loss=loss_fn,
                                        steps=steps, lr=lr, dtype=dtype, fp8=mode == "fp8")
                 u = _flat(upd)
                 if commit_kind == "qsgd-int8":
@@ -172,7 +174,7 @@ def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
                 if mode == "altered" and i == 0:
                     u = -u
                 updates.append(u)
-                weights.append(float(len(data[worker][1])))
+                weights.append(float(_samples(data[worker])))
                 stale.append(v - base)
                 losses.append(float(loss))
             w = jnp.asarray(weights, jnp.float32) * (
@@ -184,14 +186,14 @@ def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
             else:
                 agg = jnp.sum(w[:, None] * jnp.stack(updates), axis=0) / jnp.sum(w)
             if mode != "frozen":
-                p = {k: p[k] + d for k, d in _unflat(agg, p).items()}
+                p = jax.tree.map(jnp.add, p, _unflat(agg, p))
                 if mode == "bf16":
-                    p = {k: a.astype(jnp.bfloat16).astype(jnp.float32) for k, a in p.items()}
+                    p = jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), p)
             if broadcast == "delta-qsgd":
                 delta = _flat(p) - _flat(recon)
                 step = _round_rows(delta, broadcast_key(policy_seed, app, v + 1),
                                    levels=b_levels)
-                recon = {k: recon[k] + d for k, d in _unflat(step, recon).items()}
+                recon = jax.tree.map(jnp.add, recon, _unflat(step, recon))
                 held[v + 1] = recon
             elif broadcast == "none":
                 held[v + 1] = p

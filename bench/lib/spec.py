@@ -5,12 +5,17 @@ and traffic mix.  The files they point to:
 
 - ``configs[].file``: the deployment (population, dataflow trees, model,
   FL algorithm settings);
+- ``bench/models/<kind>.py``: the model that the configuration's
+  ``model.kind`` names: its shapes, weights, data, plain loss for the
+  reference, operation count and CPU-rehearsal size
+  (``bench/models/__init__.py`` gives the contract);
 - ``bench/traffic/<traffic>.json``: the mix of concurrent apps and the
   compression they use;
 - ``bench/cells/<cell>.json``: what decides ``correct`` in this cell
   (apps followed by the reference, the limit on each number compared);
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric;
-- ``bench/kernels/<kernel>.py``: one cost model per kernel;
+- ``bench/kernels/<kernel>.py``: one cost model per kernel; the probes
+  record the calls of every one found there;
 - ``bench/peaks.json``: the chip's published peaks, keyed by
   ``device_kind``.
 
@@ -21,6 +26,7 @@ from __future__ import annotations
 import importlib
 import json
 import math
+import pkgutil
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -45,8 +51,12 @@ class Spec:
         return self.config["model"]
 
     @property
+    def kind(self):
+        return model_kind(self.model)
+
+    @property
     def n_params(self) -> int:
-        return sum(math.prod(s) for s in mlp_shapes(self.model).values())
+        return n_params(self.model)
 
 
 def load_json(path: Path) -> dict:
@@ -84,15 +94,19 @@ def cell_spec(name: str, root: Path = ROOT) -> Spec:
     )
 
 
-def shrunk(spec: Spec, *, nodes: int, apps: int, warm_applies: int, hidden: int | None = None,
-           shard: int | None = None) -> Spec:
+def shrunk(spec: Spec, *, nodes: int, apps: int, warm_applies: int, shard: int | None = None,
+           **sizes) -> Spec:
     """The same cell with fewer nodes, apps and warm-up applies, and
-    optionally a narrower hidden layer and fewer samples per worker: the
-    size at which its rehearsals run on a CPU in the tests."""
+    optionally fewer samples per worker and a smaller model (``sizes``,
+    as the kind's ``shrink`` takes them): the size at which its
+    rehearsals run on a CPU in the tests.  The parameter count and the
+    priced ``model_bytes`` follow the model."""
     from dataclasses import replace
 
-    model = {**spec.model, **({"hidden": hidden} if hidden else {})}
-    config = {**spec.config, "nodes": nodes, "model": model,
+    model = spec.kind.shrink(spec.model, **sizes)
+    n = n_params(model)
+    config = {**spec.config, "nodes": nodes, "model": model, "params": n,
+              "model_bytes": n * stored_itemsize(spec.config),
               **({"shard": shard} if shard else {})}
     return replace(
         spec, config=config, traffic={**spec.traffic, "apps": apps},
@@ -121,21 +135,30 @@ def kernel(name: str):
     return importlib.import_module(f"bench.kernels.{name}")
 
 
-def mlp_shapes(model: dict) -> dict[str, tuple[int, ...]]:
-    """Leaf shapes of the apps' MLP (two hidden layers, ReLU)."""
-    d, h, c = int(model["dim"]), int(model["hidden"]), int(model["classes"])
-    return {
-        "w1": (d, h), "b1": (h,),
-        "w2": (h, h), "b2": (h,),
-        "w3": (h, c), "b3": (c,),
-    }
+def model_kind(model: dict):
+    """The module ``bench/models/<kind>.py`` of ``model["kind"]``."""
+    kind = model["kind"]
+    try:
+        return importlib.import_module(f"bench.models.{kind}")
+    except ModuleNotFoundError as e:
+        if e.name != f"bench.models.{kind}":
+            raise
+        raise KeyError(f"model kind {kind!r} has no module bench/models/{kind}.py") from None
 
 
-def mlp_flops_per_sample(model: dict) -> int:
-    """Matmul operations of one local SGD step on one sample: forward
-    2 MACs per weight; backward the weight gradients of all three layers
-    and the input gradients of layers 2 and 3 (the data needs none).
-    Biases and activations are left out."""
-    d, h, c = int(model["dim"]), int(model["hidden"]), int(model["classes"])
-    weights = d * h + h * h + h * c
-    return 2 * weights + 2 * weights + 2 * (h * h + h * c)
+def n_params(model: dict) -> int:
+    return sum(math.prod(s) for s in model_kind(model).shapes(model).values())
+
+
+def stored_itemsize(config: dict) -> int:
+    """Bytes per parameter in the configuration's stored ``dtype``."""
+    import jax.numpy as jnp
+
+    return int(jnp.dtype(config["dtype"]).itemsize)
+
+
+def kernels() -> list[str]:
+    """The name of every cost model under ``bench/kernels/``."""
+    import bench.kernels
+
+    return sorted(m.name for m in pkgutil.iter_modules(bench.kernels.__path__))

@@ -108,6 +108,16 @@ def test_followed_apps_are_drawn_among_those_with_three_applies(sound_run, tiny)
         assert len(sound_run.replay["schedule"][a]) >= probe.FOLLOWED_APPLIES
 
 
+def test_untraced_run_leaves_the_program_tracer_off(sound_run):
+    """With ``--trace 0`` the program's spans and counters stay off, so
+    they cannot move an end-to-end reading; their readers find nothing."""
+    from repro import tracing
+
+    assert sound_run.run.program is None and sound_run.run.counters is None
+    assert tracing._on is False and tracing.records == []
+    assert S.reader("quantize_ms_per_apply")(sound_run.run) is None
+
+
 def test_control_fails_the_limits(sound_run, tiny):
     """The cell's control (the reference one precision down, put in the
     program's place on the same schedule) fails its limits."""
