@@ -13,7 +13,10 @@ yardstick cannot move with the program:
   kind (``bench/models/<kind>.py``).  Equal shard sizes give every seed
   the same amount of work;
 - every app's weights are drawn by the kind on the device in one jitted
-  call.
+  call;
+- where the kind has a frozen part that every app shares (``shared``),
+  it is drawn once, and the one copy is handed to every app through the
+  kind's ``program_fields``.
 
 Every draw derives from ``--seed`` and a fixed salt per purpose, so the
 same seed gives the same deployment, data, weights, compute speeds,
@@ -28,8 +31,9 @@ import numpy as np
 
 from bench.lib.spec import Spec
 
-# salts: one independent stream per purpose
-OVERLAY, PLACEMENT, WEIGHTS, DATA, COMPUTE, CHURN, ROUNDING, FOLLOW = range(8)
+# salts: one independent stream per purpose; a new one is appended, so
+# the streams before it keep their bits
+OVERLAY, PLACEMENT, WEIGHTS, DATA, COMPUTE, CHURN, ROUNDING, FOLLOW, SHARED = range(9)
 
 
 def sub_seed(seed: int, salt: int, *more: int) -> int:
@@ -49,6 +53,7 @@ class Deployment:
     params0: list            # the initial weights the benchmark drew
     data: list               # per app: {worker node: shard}
     policy_seed: int          # roots the commit and broadcast rounding keys
+    shared: object = None     # the kind's frozen weights, one copy for all apps
 
 
 def policy_kwargs(traffic: dict) -> dict:
@@ -91,6 +96,11 @@ def build(spec: Spec, seed: int) -> Deployment:
     kind = spec.kind
     n_apps, n_workers = int(tr["apps"]), int(cfg["workers_per_app"])
     params0 = kind.init_params(sub_seed(seed, WEIGHTS), spec.model, n_apps)
+    shared, fields = None, {}
+    if hasattr(kind, "shared"):
+        shared = kind.shared(sub_seed(seed, SHARED), spec.model)
+        if hasattr(kind, "program_fields"):
+            fields = kind.program_fields(shared)
     apps, data = [], []
     for a in range(n_apps):
         workers = [int(n) for n in rng.choice(nodes, size=n_workers, replace=False)]
@@ -102,7 +112,7 @@ def build(spec: Spec, seed: int) -> Deployment:
         apps.append(rounds.FLApp(
             name=handle.name, handle=handle, params=params0[a], model=kind.PROGRAM,
             local_steps=int(cfg["local_steps"]), lr=float(cfg["lr"]), mu=0.0,
-            data=by_worker,
+            data=by_worker, **fields,
         ))
 
     policy_seed = sub_seed(seed, ROUNDING)
@@ -123,5 +133,5 @@ def build(spec: Spec, seed: int) -> Deployment:
     )
     return Deployment(
         system=system, apps=apps, run_kwargs=run_kwargs, params0=params0, data=data,
-        policy_seed=policy_seed,
+        policy_seed=policy_seed, shared=shared,
     )

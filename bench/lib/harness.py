@@ -9,7 +9,8 @@ the last ``quiet_applies`` built no program, measures the next
 ``trace``, records a profiler trace of exactly that window and turns the
 program's own spans and counters (``repro.tracing``) on for it.  Once the
 window has closed and the device's peak memory is read, the program's
-state is freed and the reference follows the apps drawn as the window
+state is freed (all but the kind's frozen weights, which the reference
+reads too) and the reference follows the apps drawn as the window
 opened through every apply up to the last of the window; the numbers of
 ``compare.py``, and the count of applies whose record disagreed with the
 benchmark's own bookkeeping, against the cell's limits decide
@@ -254,6 +255,7 @@ def run_cell(spec: Spec, seed: int, seconds: float, trace: bool, log: probe.Comp
         schedule={a: rec.schedule[a] for a in follow},
         params0={a: probe.host_copy(dep.params0[a]) for a in follow},
         data={a: dep.data[a] for a in follow}, policy_seed=dep.policy_seed,
+        shared=dep.shared,  # stays on the device: one copy for every followed app
     )
     mismatches = len(rec.mismatches)
     applies = list(rec.applies)
@@ -350,7 +352,7 @@ def readings(spec: Spec, replay: dict, program: dict | None, modes=()) -> dict:
             return {n: {"followed_applies": float("nan")} for n in names}
         kw = dict(app=a, params0=replay["params0"][a], data=replay["data"][a],
                   schedule=schedule, config=spec.config, traffic=spec.traffic,
-                  policy_seed=replay["policy_seed"])
+                  policy_seed=replay["policy_seed"], shared=replay["shared"])
         sound = reference.follow(mode="sound", **kw)
         ref = (sound.params, sound.losses, sound.held)
         for n in names:

@@ -28,6 +28,11 @@ number within its app.  From these it recomputes, in straightforward
    worker downloading that version trains from ``R``.  Without a
    compressed broadcast the workers train from ``P`` itself.
 
+A kind with a frozen part that every app shares (``shared``, drawn by
+the fixture) gets it in every local step as a traced argument; it is
+never trained, rounded, aggregated or broadcast, and ``params``,
+``held`` and every update hold the trained leaves alone.
+
 ``mode`` computes the same thing otherwise, for the control and the
 faults (``bench/control.py``): ``"fp8"`` rounds every matmul operand of
 local training to float8 (e4m3, one scale per tensor), the step below
@@ -36,7 +41,8 @@ step below the stated float32 storage; ``"int4"`` rounds commits to 7
 steps instead of 127;
 ``"frozen"`` leaves the weights unchanged by each apply; ``"half"``
 trains each worker on the first half of its shard; ``"altered"``
-negates the first commit of each apply.
+negates the first commit of each apply.  The frozen part is rounded
+only as ``mm`` and ``dtype`` round it in the kind's ``loss``.
 """
 from __future__ import annotations
 
@@ -84,18 +90,20 @@ def _samples(batch) -> int:
 
 
 @partial(jax.jit, static_argnames=("loss", "steps", "lr", "dtype", "fp8"))
-def _local_sgd(p0, batch, *, loss, steps: int, lr: float, dtype: str, fp8: bool = False):
+def _local_sgd(p0, batch, shared=None, *, loss, steps: int, lr: float, dtype: str,
+               fp8: bool = False):
     """``steps`` SGD steps from ``p0`` on ``batch`` under the kind's
-    ``loss``; returns the update in float32 and the mean of the step
-    losses."""
+    ``loss``, which also reads the frozen ``shared`` where the kind has
+    one; returns the update in float32 and the mean of the step losses."""
     dt = jnp.dtype(dtype)
     mm = (lambda a, b: _fp8(a) @ _fp8(b)) if fp8 else (lambda a, b: a @ b)
+    frozen = {} if shared is None else {"shared": shared}
     p = _cast(p0, dt)
 
     losses = []
     start = p
     for _ in range(steps):
-        value, grad = jax.value_and_grad(lambda q: loss(q, batch, mm=mm, dtype=dt))(p)
+        value, grad = jax.value_and_grad(lambda q: loss(q, batch, mm=mm, dtype=dt, **frozen))(p)
         p = jax.tree.map(lambda a, g: a - jnp.asarray(lr, dt) * g, p, grad)
         losses.append(value.astype(jnp.float32))
     update = jax.tree.map(lambda a, s: (a - s).astype(jnp.float32), p, start)
@@ -139,7 +147,7 @@ def broadcast_key(seed: int, app: int, version: int):
 
 
 def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
-           traffic: dict, policy_seed: int, mode: str = "sound") -> AppResult:
+           traffic: dict, policy_seed: int, mode: str = "sound", shared=None) -> AppResult:
     """Replay one app's followed applies; see the module docstring."""
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
@@ -164,8 +172,9 @@ def follow(*, app: int, params0: dict, data: dict, schedule: list, config: dict,
                 if mode == "half":
                     half = _samples(batch) // 2
                     batch = jax.tree.map(lambda a: a[:half], batch)
-                upd, loss = _local_sgd(held[base], jax.tree.map(jnp.asarray, batch), loss=loss_fn,
-                                       steps=steps, lr=lr, dtype=dtype, fp8=mode == "fp8")
+                upd, loss = _local_sgd(held[base], jax.tree.map(jnp.asarray, batch), shared,
+                                       loss=loss_fn, steps=steps, lr=lr, dtype=dtype,
+                                       fp8=mode == "fp8")
                 u = _flat(upd)
                 if commit_kind == "qsgd-int8":
                     u = _round_rows(u, commit_key(policy_seed, app, seq), levels=levels)

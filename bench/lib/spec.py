@@ -6,8 +6,9 @@ and traffic mix.  The files they point to:
 - ``configs[].file``: the deployment (population, dataflow trees, model,
   FL algorithm settings);
 - ``bench/models/<kind>.py``: the model that the configuration's
-  ``model.kind`` names: its shapes, weights, data, plain loss for the
-  reference, operation count and CPU-rehearsal size
+  ``model.kind`` names: its trained shapes, weights, any frozen part
+  its apps share, data, plain loss for the reference, operation count
+  and CPU-rehearsal size
   (``bench/models/__init__.py`` gives the contract);
 - ``bench/traffic/<traffic>.json``: the mix of concurrent apps and the
   compression they use;

@@ -223,27 +223,32 @@ def _toy_kind() -> types.ModuleType:
     return toy
 
 
+def _one_cell_root(root, model: dict, params: int):
+    """``root`` made a checkout whose only cell, ``toy.mix``, runs
+    ``model`` (``params`` trained weights, 4 bytes each) on the m16 mix."""
+    bm = S.benchmark()
+    cfg = S.load_json(os.path.join(ROOT, "bench", "configs", "fedavg-mnist-2nn.json"))
+    cfg.update(name="toy", model=model, params=params, model_bytes=4 * params, nodes=48)
+    for d in ("bench/traffic", "bench/cells", "configs"):
+        (root / d).mkdir(parents=True)
+    (root / "configs" / "toy.json").write_text(json.dumps(cfg))
+    traffic = S.load_json(os.path.join(ROOT, "bench", "traffic", "apps16-qsgd.json"))
+    (root / "bench" / "traffic" / "toy-mix.json").write_text(json.dumps(traffic))
+    cell = S.load_json(os.path.join(ROOT, "bench", "cells", "fedavg-mnist-2nn.m16.json"))
+    (root / "bench" / "cells" / "toy.mix.json").write_text(json.dumps(cell))
+    bm.update(configs=[{"name": "toy", "file": "configs/toy.json"}],
+              workloads=[{"name": "toy.mix", "config": "toy", "traffic": "toy-mix", "chips": 1}])
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    return root
+
+
 @pytest.fixture
 def toy_root(tmp_path, monkeypatch):
     """A checkout whose only cell runs the toy kind, which is importable
     as ``bench.models.toy_tokens`` but exists as no file."""
     monkeypatch.setitem(sys.modules, "bench.models.toy_tokens", _toy_kind())
-    bm = S.benchmark()
-    cfg = S.load_json(os.path.join(ROOT, "bench", "configs", "fedavg-mnist-2nn.json"))
     model = {"kind": "toy_tokens", "vocab": 97, "width": 24, "context": 5}
-    n = 97 * 24 * 2 + 97
-    cfg.update(name="toy", model=model, params=n, model_bytes=4 * n, nodes=48)
-    for d in ("bench/traffic", "bench/cells", "configs"):
-        (tmp_path / d).mkdir(parents=True)
-    (tmp_path / "configs" / "toy.json").write_text(json.dumps(cfg))
-    traffic = S.load_json(os.path.join(ROOT, "bench", "traffic", "apps16-qsgd.json"))
-    (tmp_path / "bench" / "traffic" / "toy-mix.json").write_text(json.dumps(traffic))
-    cell = S.load_json(os.path.join(ROOT, "bench", "cells", "fedavg-mnist-2nn.m16.json"))
-    (tmp_path / "bench" / "cells" / "toy.mix.json").write_text(json.dumps(cell))
-    bm.update(configs=[{"name": "toy", "file": "configs/toy.json"}],
-              workloads=[{"name": "toy.mix", "config": "toy", "traffic": "toy-mix", "chips": 1}])
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm))
-    return tmp_path
+    return _one_cell_root(tmp_path, model, 97 * 24 * 2 + 97)
 
 
 def test_a_new_kind_needs_no_bench_edit(toy_root):
@@ -286,3 +291,212 @@ def test_a_new_kind_needs_no_bench_edit(toy_root):
     frozen = runs["frozen"]
     off = compare.app_numbers(p0, (frozen.params, frozen.losses, frozen.held), ref, broadcast=True)
     assert off["update_gap"] == pytest.approx(1.0)
+
+
+# -- a kind with a frozen base that every app shares -----------------------------
+
+def _frozen_base_kind(calls: dict) -> types.ModuleType:
+    """A token model over a frozen embedding table that every app shares,
+    stored in bfloat16 as a served base is, under a trainable head: the
+    mean of the tokens' embeddings (a matmul of their counts with the
+    table), a linear head, next-class cross-entropy.  ``calls`` records
+    each draw of the base and each ``program_fields`` call."""
+    toy = types.ModuleType("bench.models.toy_frozen")
+    toy.PROGRAM = "toy"
+
+    def shapes(model):
+        v, d = int(model["vocab"]), int(model["width"])
+        return {"head/b": (v,), "head/w": (d, v)}
+
+    def init_params(key_seed, model, n_apps):
+        v, d = int(model["vocab"]), int(model["width"])
+
+        @partial(jax.jit, static_argnums=(1,))
+        def draw(key, n):
+            return [{"head": {"b": jnp.zeros((v,), jnp.float32),
+                              "w": jax.random.normal(k, (d, v), jnp.float32) / d ** 0.5}}
+                    for k in jax.random.split(key, n)]
+
+        return draw(jax.random.key(key_seed), n_apps)
+
+    def shared(key_seed, model):
+        v, d = int(model["vocab"]), int(model["width"])
+        table = jax.jit(lambda key: jax.random.normal(key, (v, d), jnp.float32).astype(
+            jnp.dtype(model["base_dtype"])))(jax.random.key(key_seed))
+        base = {"embed": {"table": table}}
+        calls["shared"].append(base)
+        return base
+
+    def program_fields(base):
+        calls["fields"].append(base)
+        return {"frozen": base}
+
+    def loss(p, batch, *, mm, dtype, shared):
+        tokens, target = batch
+        table = shared["embed"]["table"].astype(dtype)
+        counts = jnp.mean(jax.nn.one_hot(tokens, table.shape[0], dtype=dtype), axis=1)
+        h = mm(counts, table)
+        lp = jax.nn.log_softmax(mm(h, p["head"]["w"]) + p["head"]["b"])
+        return -jnp.mean(jnp.take_along_axis(lp, target[:, None], axis=1))
+
+    def train_flops(model, config):
+        v, d = int(model["vocab"]), int(model["width"])
+        return 6 * int(config["shard"]) * d * v
+
+    def shrink(model, *, vocab=None):
+        return {**model, **({"vocab": vocab} if vocab else {})}
+
+    toy.app_data = _toy_kind().app_data
+    for f in (shapes, init_params, shared, program_fields, loss, train_flops, shrink):
+        setattr(toy, f.__name__, f)
+    return toy
+
+
+@pytest.fixture
+def frozen_root(tmp_path, monkeypatch):
+    """A checkout whose only cell runs the frozen-base toy kind, and a
+    program whose ``FLApp`` takes the field the kind hands the base in.
+    Yields the root and the kind's record of calls."""
+    from dataclasses import dataclass
+
+    from repro.fl import rounds
+
+    @dataclass
+    class FrozenFLApp(rounds.FLApp):
+        frozen: object = None
+
+    monkeypatch.setattr(rounds, "FLApp", FrozenFLApp)
+    calls = {"shared": [], "fields": []}
+    monkeypatch.setitem(sys.modules, "bench.models.toy_frozen", _frozen_base_kind(calls))
+    model = {"kind": "toy_frozen", "vocab": 97, "width": 24, "context": 5,
+             "base_dtype": "bfloat16"}
+    return _one_cell_root(tmp_path, model, 97 * 24 + 97), calls
+
+
+def _paths(tree) -> set[str]:
+    """The leaves of ``tree`` by their path, as ``shapes`` names them."""
+    return {"/".join(str(k.key) for k in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _frozen_deployment(frozen_root):
+    root, calls = frozen_root
+    spec = S.shrunk(S.cell_spec("toy.mix", root=root), nodes=48, apps=2, warm_applies=2,
+                    shard=16, vocab=31)
+    return spec, fixture.build(spec, SEED), calls
+
+
+def _three_applies(workers):
+    """Two fresh commits; a stale one beside a fresh one; one from the latest version."""
+    return [[(workers[0], 0, 0), (workers[1], 0, 1)],
+            [(workers[2], 1, 2), (workers[3], 0, 3)],
+            [(workers[4], 2, 4)]]
+
+
+def test_frozen_base_is_drawn_once_and_reaches_every_app(frozen_root):
+    spec, dep, calls = _frozen_deployment(frozen_root)
+    assert len(calls["shared"]) == 1 and calls["shared"][0] is dep.shared
+    assert len(calls["fields"]) == 1 and calls["fields"][0] is dep.shared
+    assert len(dep.apps) == 2 and all(app.frozen is dep.shared for app in dep.apps)
+    table = dep.shared["embed"]["table"]
+    assert isinstance(table, jax.Array) and table.dtype == jnp.bfloat16
+    assert table.shape == (31, 24)
+    # only the trained leaves are priced and carried: the base is on every node
+    kind = spec.kind
+    assert spec.n_params == 31 * 24 + 31
+    trained = sum(math.prod(s) for s in kind.shapes(spec.model).values())
+    assert spec.config["model_bytes"] == 4 * trained
+    assert dep.run_kwargs["model_bytes"] == float(spec.config["model_bytes"])
+    assert all(_paths(p) == set(kind.shapes(spec.model)) for p in dep.params0)
+    # the same seed draws the same base and weights
+    again = fixture.build(spec, SEED)
+    assert np.array_equal(np.asarray(again.shared["embed"]["table"], np.float32),
+                          np.asarray(table, np.float32))
+    _same(again.params0, dep.params0)
+
+
+@pytest.mark.parametrize("mode", reference.MODES)
+def test_reference_follows_a_frozen_base_app_in_every_mode(frozen_root, mode):
+    spec, dep, calls = _frozen_deployment(frozen_root)
+    keys = set(spec.kind.shapes(spec.model))
+    p0 = jax.tree.map(np.asarray, dep.params0[0])
+    kw = dict(app=0, params0=p0, data=dep.data[0], schedule=_three_applies(list(dep.data[0])),
+              config=spec.config, traffic=spec.traffic, policy_seed=dep.policy_seed,
+              shared=dep.shared)
+    sound = reference.follow(mode="sound", **kw)
+    run = reference.follow(mode=mode, **kw)
+    assert len(run.params) == len(run.held) == 3 and all(map(math.isfinite, run.losses))
+    for tree in run.params + run.held:
+        assert _paths(tree) == keys
+    assert len(calls["shared"]) == 1  # following draws nothing
+    if mode == "sound":
+        assert not np.array_equal(run.params[0]["head"]["w"], p0["head"]["w"])
+    elif mode == "frozen":
+        _same(run.params[-1], p0)
+    else:
+        assert not np.array_equal(run.params[-1]["head"]["w"], sound.params[-1]["head"]["w"])
+
+
+def test_frozen_base_is_an_argument_not_a_constant(frozen_root):
+    """The update has the trained leaves alone; another base of the same
+    shape changes the loss and reuses the compiled step."""
+    spec, dep, _ = _frozen_deployment(frozen_root)
+    p0 = jax.tree.map(jnp.asarray, dep.params0[1])
+    batch = jax.tree.map(jnp.asarray, next(iter(dep.data[1].values())))
+    table = dep.shared["embed"]["table"]
+    other = {"embed": {"table": jax.random.normal(jax.random.key(7), table.shape,
+                                                  jnp.float32).astype(table.dtype)}}
+    kw = dict(loss=spec.kind.loss, steps=1, lr=0.1, dtype="float32")
+    before = reference._local_sgd._cache_size()
+    upd, loss = reference._local_sgd(p0, batch, dep.shared, **kw)
+    compiled = reference._local_sgd._cache_size()
+    upd2, loss2 = reference._local_sgd(p0, batch, other, **kw)
+    assert compiled == before + 1 and reference._local_sgd._cache_size() == compiled
+    assert _paths(upd) == _paths(upd2) == set(spec.kind.shapes(spec.model))
+    assert float(loss) != float(loss2)
+
+
+def test_replay_hands_the_one_base_to_every_follow(frozen_root, monkeypatch):
+    """The harness's replay gives every followed app, in every mode, the
+    deployment's one copy of the base."""
+    from bench.lib import harness
+
+    spec, dep, _ = _frozen_deployment(frozen_root)
+    follow = [0, 1]
+    replay = dict(follow=follow,
+                  schedule={a: _three_applies(list(dep.data[a])) for a in follow},
+                  params0={a: jax.tree.map(np.asarray, dep.params0[a]) for a in follow},
+                  data={a: dep.data[a] for a in follow}, policy_seed=dep.policy_seed,
+                  shared=dep.shared)
+    seen = []
+    orig = reference.follow
+
+    def follow_(**kw):
+        seen.append((kw["mode"], kw["shared"]))
+        return orig(**kw)
+
+    monkeypatch.setattr(reference, "follow", follow_)
+    got = harness.readings(spec, replay, None, ["fp8", "frozen"])
+    assert [m for m, _ in seen] == ["sound", "fp8", "frozen"] * 2
+    assert all(s is dep.shared for _, s in seen)
+    assert got["frozen"]["update_gap"] == pytest.approx(1.0)
+    assert 0.0 < got["fp8"]["update_gap"] < 1.0
+
+
+def test_a_kind_without_shared_gets_the_old_loss_arguments(tiny, monkeypatch):
+    """The MLP kind's ``loss`` is called with ``mm`` and ``dtype`` alone."""
+    seen = []
+    orig = mlp.loss
+
+    def loss(p, batch, **kw):
+        seen.append(sorted(kw))
+        return orig(p, batch, **kw)
+
+    monkeypatch.setattr(mlp, "loss", loss)
+    dep = fixture.build(tiny, SEED)
+    assert dep.shared is None
+    workers = list(dep.data[0])
+    reference.follow(app=0, params0=jax.tree.map(np.asarray, dep.params0[0]), data=dep.data[0],
+                     schedule=[[(workers[0], 0, 0)]], config=tiny.config, traffic=tiny.traffic,
+                     policy_seed=dep.policy_seed)
+    assert seen and all(k == ["dtype", "mm"] for k in seen)
