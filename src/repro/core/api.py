@@ -252,6 +252,10 @@ class TotoroSystem:
         full uniform-staleness buffer the result is exactly the
         synchronous FedAvg weighted mean.  Returns ``result=None`` when
         fewer than ``min_k`` commits are buffered (buffer untouched).
+        A buffer of ``QuantizedDelta`` on one grid, with no custom
+        ``aggregate_fn``, takes the compiled path (``kernels.ops.
+        buffered_aggregate_quantized``): ``result`` is then a pytree of
+        device arrays and the combined weights are the verb's one pull.
 
         ``k`` (the scheduler's effective buffer threshold for this
         apply), ``selector_scores`` (per-client utilities) and
@@ -261,6 +265,8 @@ class TotoroSystem:
         staleness histogram, scores, transport — to the handle's
         ``round_records``.
         """
+        import jax
+
         from repro.fl.compression import QuantizedDelta
         from repro.kernels.ops import buffered_aggregate, buffered_aggregate_quantized
         from repro.kernels.tree_aggregate import staleness_weights
@@ -277,6 +283,7 @@ class TotoroSystem:
                     "— an app's CompressionPolicy must cover every commit"
                 )
             if h.aggregate_fn is not None:
+                tracing.count("apply_eager")
                 # custom aggregators see plain pytrees: dequantize up front
                 # (the fused scale/staleness composition below only applies
                 # to the built-in kernel path)
@@ -291,18 +298,27 @@ class TotoroSystem:
                     )),
                 )
                 combined = None
-            elif all(quantized) and entries:
+            elif all(quantized):
+                if len({e.delta.q.shape for e in entries}) != 1:
+                    raise ValueError(
+                        "ApplyBuffered: quantized deltas on different grids in one buffer"
+                    )
                 # dequantize INSIDE the aggregation: per-row scales compose
-                # with the staleness discount in one kernel call
-                flat, combined = buffered_aggregate_quantized(
-                    [tracing.implicit_push(e.delta.q) for e in entries],
-                    [tracing.implicit_push(e.delta.scale) for e in entries],
+                # with the staleness discount in one kernel call; the K
+                # grids go up as one stack each and the aggregate stays on
+                # the device, already cut into the params' leaves
+                tracing.count("apply_fused")
+                d0 = entries[0].delta
+                leaves, combined = buffered_aggregate_quantized(
+                    tracing.implicit_push(np.stack([tracing.pull(e.delta.q) for e in entries])),
+                    tracing.implicit_push(np.stack([tracing.pull(e.delta.scale) for e in entries])),
                     [e.weight for e in entries],
                     [e.staleness for e in entries],
-                    alpha=staleness_alpha,
+                    alpha=staleness_alpha, shapes=d0.shapes,
                 )
-                result = entries[0].delta.unflatten(tracing.pull(flat))
+                result = jax.tree.unflatten(d0.treedef, leaves)
             else:
+                tracing.count("apply_eager")
                 result, combined = buffered_aggregate(
                     [e.delta for e in entries],
                     [e.weight for e in entries],
@@ -318,8 +334,7 @@ class TotoroSystem:
                 "workers": [e.worker for e in entries],
                 "staleness": stal,
                 "staleness_hist": hist,  # hist[s] = commits applied at staleness s
-                "weights": (None if combined is None
-                            else [float(tracing.pull(w)) for w in combined]),
+                "weights": None if combined is None else tracing.pull(combined).tolist(),
                 "version": h.version,
                 "k": len(entries) if k is None else int(k),
             }

@@ -44,11 +44,19 @@ from repro import tracing
 from repro.fl import engine
 from repro.fl.compression import (
     CompressionPolicy,
-    apply_delta_chain,
+    advance_reference,
     as_policy,
     quantize_broadcast_delta,
     quantize_delta,
+    reference_grid,
 )
+
+
+@jax.jit
+def _add_update(params, update):
+    """``params + update`` leaf by leaf, in the params' dtypes: one
+    program for the whole tree."""
+    return jax.tree.map(lambda p, d: (p + d).astype(p.dtype), params, update)
 
 
 class AsyncTrainer:
@@ -73,12 +81,12 @@ class AsyncTrainer:
     direction: the per-version snapshot the workers train from becomes
     the *broadcast state* — ``deq(quantize(params_v))`` for
     ``downlink="qsgd-int8"``, or for ``"delta-qsgd"`` the reference
-    reconstruction updated by one fused ``apply_delta_chain`` step per
-    apply, with the quantized version delta cached (bounded to
-    ``chain_cap`` entries) so stale workers can chain their gap.  Every
-    worker at version v holds the same canonical state, so version-group
-    megabatching is untouched; the master always aggregates into the
-    exact f32 params.
+    reconstruction updated by one fused chain step per apply (kept on
+    the device as its grid), with the quantized version delta cached
+    (bounded to ``chain_cap`` entries) so stale workers can chain their
+    gap.  Every worker at version v holds the same canonical state, so
+    version-group megabatching is untouched; the master always
+    aggregates into the exact f32 params.
     """
 
     def __init__(
@@ -112,6 +120,7 @@ class AsyncTrainer:
         # workers hold (== _snapshots[ai][version]) and the bounded
         # version-delta cache, keyed by the version each delta produces
         self._recon = [a.params for a in self.apps]
+        self._recon_grid = [None] * n  # the same on the (rows, 256) grid, on the device
         self._delta_cache = [dict() for _ in range(n)]  # version -> QuantizedDelta
         self.history: list[dict] = []
 
@@ -159,28 +168,38 @@ class AsyncTrainer:
         ``downlink="qsgd-int8"``: the dequantized full-model broadcast.
         ``"delta-qsgd"``: the reference reconstruction — the previous
         reference plus this version's quantized delta, folded in by one
-        fused ``apply_delta_chain`` step.  Quantizing against the
+        fused chain step.  Quantizing against the
         *reference* (not the previous exact params) is error feedback on
         the downlink: the reference stays within one quantizer bound of
         the true params at every version, and a worker chaining cached
-        deltas from any base lands bit-for-bit on this state."""
+        deltas from any base lands bit-for-bit on this state.
+
+        delta-qsgd runs on the device (``broadcast_fused``): the reference
+        is kept as its grid, the grid program forms ``params - reference``
+        on it, the quantized delta is cached with q and scale on the
+        device, and the one pull is the held state's.  qsgd-int8 runs on
+        the host (``broadcast_eager``)."""
         with tracing.span("broadcast"):
             if policy.downlink == "qsgd-int8":
+                tracing.count("broadcast_eager")
                 qd = quantize_broadcast_delta(params, policy, app=ai, version=version)
                 deq = qd.dequantize()
                 return jax.tree.map(
                     lambda p, v: np.asarray(v, dtype=tracing.pull(p).dtype), params, deq
                 )
-            delta = jax.tree.map(
-                lambda p, r: tracing.pull(p, np.float32) - tracing.pull(r, np.float32),
-                params, self._recon[ai],
-            )
-            qd = quantize_broadcast_delta(delta, policy, app=ai, version=version)
+            tracing.count("broadcast_fused")
+            ref = self._recon_grid[ai]
+            if ref is None:
+                ref = reference_grid(
+                    [tracing.implicit_push(l) for l in jax.tree.leaves(self._recon[ai])],
+                    chunk=int(policy.chunk),
+                )
+            qd = quantize_broadcast_delta(params, policy, app=ai, version=version, reference=ref)
             cache = self._delta_cache[ai]
             cache[version] = qd
             for v in [v for v in cache if v <= version - int(policy.chain_cap)]:
                 del cache[v]
-            self._recon[ai] = apply_delta_chain(self._recon[ai], [qd])
+            self._recon_grid[ai], self._recon[ai] = advance_reference(ref, qd, self._recon[ai])
             return self._recon[ai]
 
     def apply(
@@ -272,9 +291,8 @@ class AsyncTrainer:
                 app.handle.app_id, staleness_alpha=self.staleness_alpha,
                 k=k, selector_scores=selector_scores, transport=transport,
             )
-            agg = stats["result"]
-            app.params = jax.tree.map(
-                lambda p, d: (p + tracing.implicit_push(d)).astype(p.dtype), app.params, agg
+            app.params = _add_update(
+                app.params, jax.tree.map(tracing.implicit_push, stats["result"])
             )
             app.round_num += 1
             self.version[ai] = cur + 1

@@ -13,7 +13,8 @@ pytree into a ``QuantizedDelta`` (int8 payload + per-chunk f32 scales),
 ``CommitDelta`` buffers it as-is, and ``ApplyBuffered`` dequantizes
 *inside* the buffered aggregation (``kernels.ops.
 buffered_aggregate_quantized``: per-row scales compose with the
-staleness weights in one kernel call).  The scheduler prices commit
+staleness weights in one kernel call, between two compiled programs,
+and the aggregate stays on the device).  The scheduler prices commit
 flows at ``CompressionPolicy.wire_bytes(model_bytes)``, so the
 compressed byte count is what enters ``EventCore.open_flow`` — fair
 shares, caps, relay admission and sampled cold loads all see the
@@ -49,6 +50,11 @@ Delta payloads pack the small ``downlink_levels`` lattice at
 scheduler prices every broadcast leg at ``downlink_wire_bytes`` and the
 fused ``kernels.ops.apply_quantized_broadcast`` kernel folds a whole
 chain into the held params in one pass (``apply_delta_chain``).
+``AsyncTrainer`` keeps its reference on the device as the (rows, 256)
+grid (``reference_grid``): ``quantize_broadcast_delta(...,
+reference=)`` subtracts it inside the grid program, the q and scale it
+caches stay on the device, and ``advance_reference`` folds each delta
+in and pulls the held state once.
 """
 from __future__ import annotations
 
@@ -305,17 +311,18 @@ class QuantizedDelta:
     per-chunk f32 scales + the pytree structure needed to rebuild it.
 
     ``q`` is (R, chunk) int8 (the flattened, zero-padded delta), ``scale``
-    (R, 1) f32.  Dequantization is ``q * scale`` row-wise; padding
-    elements quantize to exactly 0 (|0/scale + u| < 1 for u in [0, 1))
-    and are dropped by ``unflatten``.
+    (R, 1) f32: host arrays, except in ``AsyncTrainer``'s delta-qsgd
+    broadcast cache, which keeps them on the device.  Dequantization is
+    ``q * scale`` row-wise; padding elements quantize to exactly 0
+    (|0/scale + u| < 1 for u in [0, 1)) and are dropped by ``unflatten``.
 
     ``wire_nbytes`` overrides the modeled wire size when the serialized
     format is narrower than the in-memory int8 grid (bit-packed signsgd,
     sparse topk, packed downlink deltas); ``None`` means the arrays ARE
     the wire format (dense qsgd-int8)."""
 
-    q: np.ndarray
-    scale: np.ndarray
+    q: Any                      # np.ndarray, or a jax.Array on the device
+    scale: Any
     length: int                 # unpadded element count
     shapes: tuple               # leaf shapes, flatten order
     treedef: Any
@@ -348,18 +355,35 @@ class QuantizedDelta:
         return self.unflatten(flat.reshape(-1))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _grid(leaves, key, app, count, *, chunk: int):
-    """The one program in front of the kernel: the leaves raveled to f32
-    in flatten order, zero-padded onto the (rows, chunk) grid, and the
-    uniforms that round it.  ``key`` is a chain root with the traced
-    counters ``app`` and ``count`` folded in, or with both ``None`` the
-    rounding key itself; ``key=None`` rounds half-down (``rand=0.5``)."""
+def _on_grid(leaves, chunk: int):
+    """The leaves raveled to f32 in flatten order and zero-padded onto
+    the (rows, chunk) grid (traced)."""
     flat = [jnp.ravel(l).astype(jnp.float32) for l in leaves]
     n = sum(f.size for f in flat)
     rows = max(1, math.ceil(n / chunk))
     x2d = jnp.concatenate(flat + [jnp.zeros((rows * chunk - n,), jnp.float32)])
-    x2d = x2d.reshape(rows, chunk)
+    return x2d.reshape(rows, chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def reference_grid(leaves, *, chunk: int):
+    """A state's leaves on the (rows, chunk) grid, on the device: the
+    form ``AsyncTrainer`` keeps the delta-qsgd reference in."""
+    return _on_grid(leaves, chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _grid(leaves, key, app, count, ref=None, *, chunk: int):
+    """The one program in front of the kernel: the leaves raveled to f32
+    in flatten order, zero-padded onto the (rows, chunk) grid, less
+    ``ref`` (a grid of the same shape) where given, and the uniforms that
+    round it.  ``key`` is a chain root with the traced counters ``app``
+    and ``count`` folded in, or with both ``None`` the rounding key
+    itself; ``key=None`` rounds half-down (``rand=0.5``)."""
+    x2d = _on_grid(leaves, chunk)
+    if ref is not None:
+        x2d = x2d - ref
+    rows = x2d.shape[0]
     if key is None:
         return x2d, jnp.full((rows, chunk), 0.5, jnp.float32)
     if app is not None:
@@ -379,13 +403,14 @@ def _rounding(key, root, app, count):
     return root, np.uint32(app), np.uint32(count)
 
 
-def _to_grid(delta, chunk: int, key, app, count):
-    """``delta`` on the quantization grid with its uniforms, from one
-    ``_grid`` dispatch, and what rebuilds the pytree."""
+def _to_grid(delta, chunk: int, key, app, count, ref=None):
+    """``delta`` (less the grid ``ref``, where given) on the quantization
+    grid with its uniforms, from one ``_grid`` dispatch, and what
+    rebuilds the pytree."""
     leaves, treedef = jax.tree.flatten(delta)
     shapes = tuple(np.shape(l) for l in leaves)
     x2d, rand = _grid(
-        [tracing.implicit_push(l) for l in leaves], key, app, count, chunk=chunk
+        [tracing.implicit_push(l) for l in leaves], key, app, count, ref, chunk=chunk
     )
     return x2d, rand, sum(math.prod(s) for s in shapes), shapes, treedef
 
@@ -466,14 +491,20 @@ def dequantize_delta(qd: QuantizedDelta) -> Any:
 
 
 def quantize_broadcast_delta(
-    delta, policy: CompressionPolicy, key=None, *, app=None, version=None
+    delta, policy: CompressionPolicy, key=None, *, app=None, version=None, reference=None
 ) -> QuantizedDelta:
     """Serialize one version delta for the broadcast direction: QSGD on
     the coarse ``downlink_levels`` lattice, ``wire_nbytes`` set to the
     bit-packed size (``delta_wire_bytes``) the scheduler prices chained
     downloads at.  Rounding as in ``quantize_delta``: ``app`` and
     ``version`` fold ``broadcast_key(policy, app, version)`` inside the
-    grid program, else ``key`` as given."""
+    grid program, else ``key`` as given.
+
+    With ``reference`` (the workers' held state on the grid, a device
+    array: ``reference_grid``) ``delta`` is the new params: the grid
+    program subtracts the reference on the grid, the same f32
+    subtraction as ``params - reference`` leaf by leaf, and the
+    returned q and scale stay on the device."""
     if not policy.downlink_enabled:
         raise ValueError(
             "quantize_broadcast_delta requires an enabled downlink (downlink != 'none')"
@@ -482,14 +513,16 @@ def quantize_broadcast_delta(
         chunk = int(policy.chunk)
         root = _key_root(int(policy.seed), _BROADCAST_LANE)
         x2d, rand, n, shapes, treedef = _to_grid(
-            delta, chunk, *_rounding(key, root, app, version)
+            delta, chunk, *_rounding(key, root, app, version), ref=reference
         )
         levels = int(policy.downlink_levels) if policy.downlink == "delta-qsgd" else int(policy.levels)
         q, s = _qsgd_grid(x2d, rand, levels)
         tracing.count("quantize_fused" if chunk == 256 else "quantize_eager")
         wire = policy.downlink_wire_bytes(4.0 * n, chain=1)
+        if reference is None:
+            q, s = tracing.pull(q), tracing.pull(s)
         return QuantizedDelta(
-            q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,
+            q=q, scale=s, length=n, shapes=shapes,
             treedef=treedef, levels=levels, chunk=chunk, wire_nbytes=wire,
         )
 
@@ -521,8 +554,8 @@ def apply_delta_chain(params, deltas: list) -> Any:
         w2d = np.zeros((rows * chunk,), np.float32)
         w2d[: flat.size] = flat
         w2d = w2d.reshape(rows, chunk)
-        q = np.stack([d.q for d in deltas])          # (D, rows, chunk) int8
-        s = np.stack([d.scale for d in deltas])      # (D, rows, 1) f32
+        q = np.stack([tracing.pull(d.q) for d in deltas])      # (D, rows, chunk) int8
+        s = np.stack([tracing.pull(d.scale) for d in deltas])  # (D, rows, 1) f32
         if chunk == 256:
             from repro.kernels import ops as kops
 
@@ -536,3 +569,27 @@ def apply_delta_chain(params, deltas: list) -> Any:
         return jax.tree.map(
             lambda p, v: np.asarray(v, dtype=tracing.pull(p).dtype), params, rebuilt
         )
+
+
+def advance_reference(grid, qd: QuantizedDelta, like) -> tuple:
+    """Fold one quantized version delta into the reference
+    reconstruction kept on the device as its (rows, chunk) ``grid``: one
+    ``kernels.ops.apply_quantized_broadcast`` dispatch at the kernel's
+    256-wide row, the same additions as ``apply_delta_chain``'s.
+    Returns the new grid and the state the workers hold as host leaves
+    of ``like``'s structure and dtypes, from one pull.  Where a leaf is
+    not float32 the grid is rebuilt from the held leaves, so that it
+    holds their rounding."""
+    from repro.kernels import ops as kops
+
+    with tracing.span("chain"):
+        if qd.chunk == 256:
+            grid = kops.apply_quantized_broadcast(grid, qd.q[None], qd.scale[None])
+        else:
+            grid = grid + qd.q.astype(jnp.float32) * qd.scale
+        rebuilt = qd.unflatten(tracing.pull(grid).reshape(-1))
+        held = jax.tree.map(lambda p, v: np.asarray(v, dtype=p.dtype), like, rebuilt)
+        leaves = jax.tree.leaves(held)
+        if any(l.dtype != np.float32 for l in leaves):
+            grid = reference_grid([tracing.implicit_push(l) for l in leaves], chunk=qd.chunk)
+        return grid, held

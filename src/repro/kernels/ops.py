@@ -28,6 +28,7 @@ tile padding and pytree-level application as before.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -176,13 +177,43 @@ def buffered_aggregate(updates: list, weights, staleness, *, alpha: float = 0.5)
     return _unflatten_like(agg, updates[0]), w
 
 
-def buffered_aggregate_quantized(qs, scales, weights, staleness, *, alpha: float = 0.5):
+@jax.jit
+def _quantized_operands(q, s, w):
+    """The aggregation kernel's operands from K quantized deltas, in one
+    program: the lattice points as the (R, K, C) f32 group layout and
+    the per-(row, worker) weights ``w_k * s_{k,r}``, with ``w`` (K,) the
+    staleness-discounted weights."""
+    q, s = jnp.asarray(q), jnp.asarray(s)  # (K, R, C), (K, R, 1)
+    g = jnp.transpose(q.astype(jnp.float32), (1, 0, 2))  # (R, K, C)
+    gw = jnp.transpose(w[:, None] * s.reshape(s.shape[0], -1))  # (R, K)
+    return g, gw
+
+
+@functools.partial(jax.jit, static_argnames=("shapes",))
+def _normalised(agg, w, *, shapes=None):
+    """The weighted sum over the combined weight, raveled; with
+    ``shapes`` cut into leaves of those shapes (flatten order, the grid's
+    zero padding dropped)."""
+    flat = jnp.ravel(agg / jnp.maximum(w.sum(), 1e-12))
+    if shapes is None:
+        return flat
+    leaves, off = [], 0
+    for shape in shapes:
+        size = math.prod(shape)
+        leaves.append(flat[off : off + size].reshape(shape))
+        off += size
+    return tuple(leaves)
+
+
+def buffered_aggregate_quantized(qs, scales, weights, staleness, *, alpha: float = 0.5,
+                                 shapes: tuple | None = None):
     """Staleness-weighted aggregate of K *quantized* deltas, dequantized
     inside the aggregation (the compressed-transport apply path).
 
     ``qs``: K int8 arrays (R, C) — each worker's flattened delta on the
-    QSGD lattice; ``scales``: K f32 arrays (R, 1) — the per-chunk
-    max-abs scales.  Instead of dequantizing each delta and re-running
+    QSGD lattice — or their (K, R, C) stack; ``scales``: K f32 arrays
+    (R, 1) — the per-chunk max-abs scales — or their (K, R, 1) stack.
+    Instead of dequantizing each delta and re-running
     ``buffered_aggregate``, the per-row scale composes with the
     staleness discount into ONE weight per (row, worker):
 
@@ -195,16 +226,19 @@ def buffered_aggregate_quantized(qs, scales, weights, staleness, *, alpha: float
     fused path rides the same Pallas kernel / compiled fallback and the
     same shape buckets as the uncompressed apply.
 
-    Returns (flat (R*C,) f32 aggregate, combined weights (K,) f32);
-    callers unflatten via ``QuantizedDelta.unflatten``.
+    The staleness discount (``tree_aggregate.staleness_weights``, called
+    from the host as the async path's one change to the math), then one
+    program builds the kernel's operands, the kernel runs as its own
+    dispatch, and one program normalizes.  Every operand stays on the
+    device.
+
+    Returns (aggregate, combined weights (K,) f32): the aggregate flat
+    (R*C,) f32, or with ``shapes`` (the delta's leaf shapes) the tuple of
+    its leaves.
     """
     w = _ta.staleness_weights(weights, staleness, alpha)  # (K,)
-    q = jnp.stack([jnp.asarray(x) for x in qs]).astype(jnp.float32)  # (K, R, C)
-    s = jnp.stack([jnp.asarray(x).reshape(-1) for x in scales])  # (K, R)
-    g = jnp.transpose(q, (1, 0, 2))  # (R, K, C)
-    gw = jnp.transpose(w[:, None] * s)  # (R, K): staleness x per-row scale
-    agg = tree_aggregate_groups(g, gw) / jnp.maximum(w.sum(), 1e-12)
-    return jnp.ravel(agg), w
+    g, gw = _quantized_operands(qs, scales, w)
+    return _normalised(tree_aggregate_groups(g, gw), w, shapes=shapes), w
 
 
 def jain_fairness(x) -> float:

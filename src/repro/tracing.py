@@ -27,9 +27,12 @@ Span names used by the program:
 ``counters`` are monotone: device arrays copied to the host
 (``d2h_pulls``, ``d2h_bytes``), host values uploaded, explicitly by
 ``push`` or inside the jax operation ``implicit_push`` hands them to
-(``h2d_pushes``, ``h2d_bytes``), and quantize calls that ran as the grid
+(``h2d_pushes``, ``h2d_bytes``), quantize calls that ran as the grid
 program and one kernel dispatch (``quantize_fused``) or took an eager
-branch (``quantize_eager``); they move only while tracing is on.
+branch (``quantize_eager``), ``ApplyBuffered`` aggregations that took the
+compiled quantized path (``apply_fused``) or another (``apply_eager``),
+and broadcast states built on the device (``broadcast_fused``) or on
+the host (``broadcast_eager``); they move only while tracing is on.
 """
 from __future__ import annotations
 
@@ -45,6 +48,7 @@ _open: list[int] = []     # indices in ``records`` of the spans open now
 counters = {
     "d2h_pulls": 0, "d2h_bytes": 0, "h2d_pushes": 0, "h2d_bytes": 0,
     "quantize_fused": 0, "quantize_eager": 0,
+    "apply_fused": 0, "apply_eager": 0, "broadcast_fused": 0, "broadcast_eager": 0,
 }
 
 

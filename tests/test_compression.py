@@ -369,6 +369,50 @@ def test_fused_aggregate_matches_unfused_reference(kernel_mode_guard, mode):
     np.testing.assert_allclose(np.asarray(combined), w, rtol=1e-6)
 
 
+def _eager_apply(qds, weights, staleness, alpha):
+    """The quantized apply one eager operation at a time, as the verb ran
+    it before its glue was compiled: the oracle of the compiled path.
+    Returns (result pytree, combined weights)."""
+    from repro.kernels.tree_aggregate import staleness_weights
+
+    w = staleness_weights(weights, staleness, alpha)
+    q = jnp.stack([jnp.asarray(d.q) for d in qds]).astype(jnp.float32)
+    s = jnp.stack([jnp.asarray(d.scale).reshape(-1) for d in qds])
+    g = jnp.transpose(q, (1, 0, 2))
+    gw = jnp.transpose(w[:, None] * s)
+    agg = kops.tree_aggregate_groups(g, gw) / jnp.maximum(w.sum(), 1e-12)
+    return qds[0].unflatten(np.asarray(jnp.ravel(agg))), [float(x) for x in w]
+
+
+@pytest.mark.parametrize("alpha", [0.0, 0.5])
+@pytest.mark.parametrize("K", [1, 3, 4, 5])
+def test_compiled_apply_verb_bit_identical_to_eager(kernel_mode_guard, K, alpha):
+    """``ApplyBuffered``'s compiled quantized path (operands in one
+    program, the kernel, normalize-and-unflatten in one program) gives
+    the eager composition's result and weights bit for bit, with the
+    result left on the device."""
+    kops.set_kernel_mode("jnp")
+    rng = np.random.default_rng(K)
+    policy = CompressionPolicy(kind="qsgd-int8")
+    shapes = {"w": (37, 11), "b": (11,), "v": (300,)}
+    sys_, handles = _build_handles(1, workers=K, n_nodes=20, seed=K)
+    h = handles[0]
+    qds, weights, staleness = [], [], []
+    for k, w in enumerate(sorted(h.tree.members)[:K]):
+        delta = {n: rng.normal(0, 1.0, s).astype(np.float32) for n, s in shapes.items()}
+        qds.append(comp.quantize_delta(delta, policy, app=0, seq=k))
+        weights.append(float(rng.uniform(0.5, 3.0)))
+        staleness.append(k % 3)
+        sys_.CommitDelta(h.app_id, w, qds[-1], weight=weights[-1], staleness=staleness[-1])
+    out = sys_.ApplyBuffered(h.app_id, staleness_alpha=alpha)
+    want, want_w = _eager_apply(qds, weights, staleness, alpha)
+    assert out["weights"] == want_w
+    for name, shape in shapes.items():
+        got = out["result"][name]
+        assert isinstance(got, jax.Array) and got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got), want[name])
+
+
 # -- scheduler fixtures --------------------------------------------------------
 
 

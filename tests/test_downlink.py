@@ -33,8 +33,10 @@ Locks down the broadcast direction end to end:
   at; trained runs converge finitely.
 """
 import math
+import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -183,6 +185,62 @@ def test_chained_reconstruction_bit_exact_at_1_and_cap():
         for k in ("w", "b"):
             drift = np.abs(states[v][k] - trues[v][k]).max()
             assert drift <= step + 1e-6, (v, k, drift, step)
+
+
+@pytest.mark.parametrize("dtype,chunk", [(np.float32, 256), (jnp.bfloat16, 256),
+                                         (np.float32, 128)])
+def test_device_broadcast_state_matches_host_path(dtype, chunk):
+    """``AsyncTrainer._broadcast_state`` keeps the delta-qsgd reference
+    on the device: over 5 versions its held state and its cached q and
+    scale equal the host path (``quantize_broadcast_delta`` on
+    ``params - reference``, then ``apply_delta_chain``) bit for bit, in
+    float32 and in bfloat16 (the grid rebuilt from the rounded state),
+    at the kernels' chunk and another, and a stale base chaining its gap
+    from the cache lands on the same state."""
+    from repro import tracing
+    from repro.fl import async_engine
+
+    pol = CompressionPolicy(kind="qsgd-int8", downlink="delta-qsgd", chain_cap=3, chunk=chunk)
+    rng = np.random.default_rng(1)
+    params = {"w": jnp.asarray(rng.normal(0, 1, (40, 13)), dtype),
+              "b": jnp.asarray(rng.normal(0, 1, (17,)), dtype)}
+    app = types.SimpleNamespace(params=params, handle=None)
+    tr = async_engine.AsyncTrainer(None, [app], compression=pol)
+    recon, states = params, {0: params}
+    before = tracing.snapshot()
+    tracing.enable()
+    try:
+        for v in range(1, 6):
+            params = jax.tree.map(
+                lambda p: (p + jnp.asarray(rng.normal(0, 0.01, p.shape), p.dtype)).astype(dtype),
+                params,
+            )
+            held = tr._broadcast_state(0, params, v, pol)
+            delta = jax.tree.map(
+                lambda p, r: np.asarray(p, np.float32) - np.asarray(r, np.float32), params, recon
+            )
+            qd = comp.quantize_broadcast_delta(delta, pol, app=0, version=v)
+            recon = states[v] = comp.apply_delta_chain(recon, [qd])
+            cached = tr._delta_cache[0][v]
+            np.testing.assert_array_equal(np.asarray(cached.q), qd.q)
+            np.testing.assert_array_equal(np.asarray(cached.scale), qd.scale)
+            assert cached.nbytes == qd.nbytes
+            for k in ("w", "b"):
+                assert isinstance(held[k], np.ndarray) and held[k].dtype == dtype
+                np.testing.assert_array_equal(held[k], recon[k])
+    finally:
+        tracing.disable()
+        tracing.clear()
+    after = tracing.snapshot()
+    assert after["broadcast_fused"] - before["broadcast_fused"] == 5
+    assert after["broadcast_eager"] == before["broadcast_eager"]
+    # chain lengths 1 and chain_cap, from the device cache; a bfloat16
+    # state is rounded at every version, so only its chain of one lands
+    # on it bit for bit
+    for base in (4, 2) if dtype == np.float32 else (4,):
+        got = comp.apply_delta_chain(states[base], tr.delta_chain(0, base, 5))
+        for k in ("w", "b"):
+            np.testing.assert_array_equal(got[k], states[5][k])
 
 
 def test_apply_delta_chain_rejects_mismatched_grid():

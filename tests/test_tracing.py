@@ -215,3 +215,56 @@ def test_every_quantize_of_a_run_is_one_grid_program_and_one_kernel(tracer):
     assert calls == commits + len(res["history"]) > 0
     assert after["quantize_fused"] - before["quantize_fused"] == calls
     assert after["quantize_eager"] == before["quantize_eager"]
+
+
+def test_delta_qsgd_apply_keeps_the_aggregate_step_on_the_device(tracer):
+    """One apply of qsgd commits with a delta-qsgd broadcast: the compiled
+    apply and broadcast paths engage once each, ``verb.apply``,
+    ``broadcast`` and ``chain`` pull twice between them (the combined
+    weights and the held state), and a second apply with the same K
+    builds no program."""
+    sys_, apps = _deployment()
+    tr = async_engine.AsyncTrainer(
+        sys_, apps[:1], compression=CompressionPolicy(kind="qsgd-int8", downlink="delta-qsgd")
+    )
+    workers = tr.workers(0)[:4]
+
+    def one_apply():
+        for w in workers:
+            tr.begin_download(0, w)
+        for w in workers:
+            tr.commit(0, w, 0.0)
+        return tr.apply(0, 0.0)
+
+    one_apply()  # builds every program of the path
+    builds = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            builds.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    tracer.enable()
+    before = tracer.snapshot()
+    try:
+        assert one_apply()["arrivals"] == len(workers)
+    finally:
+        tracer.disable()
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    after = tracer.snapshot()
+    assert builds == []
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew["apply_fused"] == 1 and grew["apply_eager"] == 0
+    assert grew["broadcast_fused"] == 1 and grew["broadcast_eager"] == 0
+    recs = tracer.records
+
+    def under(i, names):
+        while i >= 0:
+            if recs[i][0] in names:
+                return True
+            i = recs[i][3]
+        return False
+
+    step = ("verb.apply", "broadcast", "chain")
+    pulls = [i for i, r in enumerate(recs) if r[0] == "xfer.d2h" and under(i, step)]
+    assert 1 <= len(pulls) <= 2
