@@ -26,8 +26,8 @@ and writes a ``BENCH_async.json`` artifact (the CI perf trajectory).
 The smoke run also gates the multi-app fairness acceptance criteria
 (``benchmarks/bench_fairness.py``): at M = 16 with one hot app, Jain's
 index over demand-normalized per-app uplink throughput must reach 0.8
-under the weighted-fair engine and improve on the legacy start-time
-pricing, with no app's time-to-target-loss regressing more than 5%.
+under the weighted-fair engine, and every app must reach the target
+loss.
 """
 from __future__ import annotations
 
@@ -249,8 +249,8 @@ def main() -> None:
         r = fairness["matrix"][0]
         g = fairness["time_to_loss_guard"]
         print(
-            f"fairness M=16: jain {r['jain_legacy']:.3f} -> {r['jain_fair']:.3f}; "
-            f"time-to-loss worst {g['max_regression']:.2f}x, mean {g['mean_ratio']:.2f}x"
+            f"fairness M=16: jain {r['jain_fair']:.3f}; "
+            f"time-to-loss spread {g['spread_fair']:.2f}x"
         )
         fairness_fails = bench_fairness.gate(fairness["matrix"], g)
         for msg in fairness_fails:

@@ -1,11 +1,6 @@
-"""Vectorized round engine + event-driven multi-app simulator benchmarks.
+"""Event-driven multi-app simulator benchmark.
 
-(a) Engine: one app's E local steps for all W workers as a single jitted
-vmap (``fl/engine.py``) vs the seed's per-worker dispatch loop — the
-vectorized path must be >=5x faster at W >= 64 (the win is amortized
-dispatch: one XLA program instead of W).
-
-(b) Table III: per-app round completion time for M in {1, 4, 16}
+Table III: per-app round completion time for M in {1, 4, 16}
 concurrent apps on one overlay, priced by the discrete-event simulator
 (``core/sim.py``, shared-link contention where trees overlap) vs the
 centralized single-coordinator queue (``fl/rounds.CentralizedBaseline``).
@@ -16,43 +11,18 @@ import types
 
 import numpy as np
 
-from .common import build_system, row, timeit
+from .common import build_system, row
 
 
 def run() -> list[str]:
-    from repro import data as data_mod
     from repro.core.sim import MultiAppSimulator, per_app_round_ms
-    from repro.fl import engine, rounds
+    from repro.fl import rounds
 
     out = []
     sys_, nodes, rng = build_system(n_nodes=1200, zones=4, seed=11)
-    dim, classes, shard = 32, 8, 16
+    dim, classes = 32, 8
 
-    # (a) vectorized engine vs per-worker reference loop
-    for W in (64, 128, 256):
-        x, y = data_mod.synthetic_classification(W * shard, dim, classes, seed=W)
-        workers = [int(w) for w in rng.choice(nodes, size=W, replace=False)]
-        app = rounds.make_app(
-            sys_, f"eng-{W}", workers=workers,
-            data_by_worker={
-                w: (x[i * shard : (i + 1) * shard], y[i * shard : (i + 1) * shard])
-                for i, w in enumerate(workers)
-            },
-            dim=dim, hidden=32, num_classes=classes, local_steps=2, lr=0.1,
-        )
-        ws = [w for w in sorted(app.handle.tree.members) if w in app.data]
-        tv, _ = timeit(lambda: engine.local_training(app, ws, vectorized=True))
-        tr, _ = timeit(lambda: engine.local_training(app, ws, vectorized=False))
-        out.append(
-            row(
-                f"engine_local_train_w{W}",
-                tv * 1e6,
-                f"loop_ms={tr*1e3:.1f};vec_ms={tv*1e3:.1f};speedup={tr/tv:.1f}x",
-            )
-        )
-        sys_.apps.pop(app.handle.app_id, None)
-
-    # (b) Table-III curve: M concurrent apps, shared links vs central queue
+    # Table-III curve: M concurrent apps, shared links vs central queue
     model_bytes = 4.0 * (dim * 32 + 32 + 32 * 32 + 32 + 32 * classes + classes)
     compute_ms = 40.0
     base = rounds.CentralizedBaseline()

@@ -1,13 +1,12 @@
-"""Multi-app uplink fairness: weighted-fair pricing vs start-time pricing.
+"""Multi-app uplink fairness under weighted-fair transfer pricing.
 
-The seed's transfer model priced each flow once, at start time, against
-whatever happened to be in flight — a flow that began alone kept its
-solo rate after contenders arrived, and vice versa.  At M >= 16 apps
-sharing one edge network that error compounds into uplink starvation
-(ROADMAP; the Table-III scaling claim bends).  This bench measures the
-fix on an M ∈ {4, 16, 64} matrix with **one hot app** (near-zero
-compute, so its workers hammer the shared relays continuously) against
-M-1 compute-bound apps:
+Every transfer hop is a fluid flow re-priced whenever a flow joins or
+leaves its uplink, so no app keeps a stale solo (or stale congested)
+rate; at M >= 16 apps sharing one edge network a start-time price
+compounds into uplink starvation (the Table-III scaling claim bends).
+This bench measures per-app progress on an M ∈ {4, 16, 64} matrix with
+**one hot app** (near-zero compute, so its workers hammer the shared
+relays continuously) against M-1 compute-bound apps:
 
 - **fairness matrix** (timing-only): every app moves the same transfer
   workload (a fixed number of buffered applies); the per-app *uplink
@@ -15,13 +14,12 @@ M-1 compute-bound apps:
   divided by its contended completion time (1.0 = as fast as running
   alone — solo-normalized throughput, the standard way to compare apps
   with different demands, and free of horizon-cut truncation bias).
-  Jain's index over those rates is gated **>= 0.8** for the
-  weighted-fair engine and must improve on the legacy pricing.
+  Jain's index over those rates is gated **>= 0.8** (weighted-fair
+  pricing with relay admission).
 - **time-to-loss guard** (trained, M = 16): the same hot/cold mix with
   real training; per-app simulated time until the mean local loss
-  reaches the target, fair vs legacy.  Gated: **no app regresses more
-  than 5%**, and the max/min spread across apps must not widen —
-  restoring fairness must not buy it by slowing anyone down.
+  reaches the target.  Gated: **every app reaches the target**; the
+  max/min spread across apps is reported.
 
 ``python -m benchmarks.bench_fairness --smoke`` runs M ∈ {4, 16} plus
 the trained guard and writes ``BENCH_fairness.json`` (a CI artifact);
@@ -80,11 +78,11 @@ def _admission():
 
 
 def fairness_compare(m: int, *, seed: int = 0) -> dict:
-    """One matrix column: legacy vs weighted-fair(+relay admission) on
-    identical topology/schedules.  Every app completes the same applies
-    target in every run (no horizon truncation); the per-app progress
-    rate is solo completion time / contended completion time, so 1.0
-    means the app ran as fast as it would alone."""
+    """One matrix column: weighted-fair pricing with relay admission.
+    Every app completes the same applies target in every run (no
+    horizon truncation); the per-app progress rate is solo completion
+    time / contended completion time, so 1.0 means the app ran as fast
+    as it would alone."""
     from repro.core.sim import AsyncBufferScheduler
     from repro.kernels.ops import jain_fairness
 
@@ -95,46 +93,34 @@ def fairness_compare(m: int, *, seed: int = 0) -> dict:
     def compute(handle, worker, cycle):
         return HOT_MS if handle.app_id == hot_id else COLD_MS
 
-    def run(fair, relay=None, subset=None):
+    def run(relay=None, subset=None):
         hs = handles if subset is None else [handles[i] for i in subset]
         sched = AsyncBufferScheduler(
             sys_, hs, model_bytes=cfg["model_bytes"], compute_ms=compute,
-            buffer_k=cfg["buffer_k"], fair=fair, relay_admission=relay,
+            buffer_k=cfg["buffer_k"], relay_admission=relay,
         )
         sched.run(cfg["applies"], max_events=8_000_000)
         return sched.transport_stats()
 
-    # solo baseline: each app alone on the same topology under the
-    # correct (fluid) pricing — its own workers still share intra-app
-    # relays; both modes normalize by this one true demand
-    solo = [run(True, subset=[a])["done_ms"][0] for a in range(m)]
-
-    def rates(st):
-        return [s / max(d, 1e-9) for s, d in zip(solo, st["done_ms"])]
-
-    legacy = run(False)
-    fair = run(True, _admission())
-    r_legacy, r_fair = rates(legacy), rates(fair)
+    # solo baseline: each app alone on the same topology — its own
+    # workers still share intra-app relays
+    solo = [run(subset=[a])["done_ms"][0] for a in range(m)]
+    fair = run(_admission())
+    r_fair = [s / max(d, 1e-9) for s, d in zip(solo, fair["done_ms"])]
     return {
         "m": m,
-        "jain_legacy": jain_fairness(r_legacy),
         "jain_fair": jain_fairness(r_fair),
-        "hot_ratio_legacy": r_legacy[0],
         "hot_ratio_fair": r_fair[0],
-        "min_ratio_legacy": min(r_legacy),
         "min_ratio_fair": min(r_fair),
         "deferred_commits": fair["deferred_commits"],
-        "jain_bytes_legacy": jain_fairness(legacy["uplink_bytes"]),
         "jain_bytes_fair": jain_fairness(fair["uplink_bytes"]),
-        "ratios_legacy": r_legacy,
         "ratios_fair": r_fair,
     }
 
 
 def time_to_loss_guard(*, m: int = 16, seed: int = 0, target: float = 0.35) -> dict:
-    """Trained fair-vs-legacy comparison at M apps with one hot app:
-    per-app simulated time-to-target-loss must not regress under the
-    fairness fix, and the cross-app spread must not widen."""
+    """Trained run at M apps with one hot app: every app must reach the
+    target loss; the cross-app spread of time-to-target is reported."""
     from repro import data as data_mod
     from repro.fl import async_engine, rounds
 
@@ -161,42 +147,28 @@ def time_to_loss_guard(*, m: int = 16, seed: int = 0, target: float = 0.35) -> d
                 return r["t_ms"]
         return float("inf")
 
-    def run(fair, relay=None):
-        sys_, nodes, rng = build_system(n_nodes=300, zones=4, seed=seed)
-        apps = make_apps(sys_, nodes, rng)
-        hot_id = apps[0].handle.app_id
+    sys_, nodes, rng = build_system(n_nodes=300, zones=4, seed=seed)
+    apps = make_apps(sys_, nodes, rng)
+    hot_id = apps[0].handle.app_id
 
-        def compute(handle, worker, cycle):
-            if handle.app_id == hot_id:
-                return 5.0
-            slow = np.random.default_rng([7, handle.app_id, worker])
-            return COLD_MS * (1.0 + 3.0 * float(slow.random()))
+    def compute(handle, worker, cycle):
+        if handle.app_id == hot_id:
+            return 5.0
+        slow = np.random.default_rng([7, handle.app_id, worker])
+        return COLD_MS * (1.0 + 3.0 * float(slow.random()))
 
-        res = async_engine.run_async(
-            sys_, apps, applies=applies, buffer_k=4, staleness_alpha=0.5,
-            model_bytes=4e5, compute_ms=compute, fair=fair, relay_admission=relay,
-        )
-        return [tt(res["history"], a.handle.app_id) for a in apps]
-
-    tt_legacy = run(False)
-    tt_fair = run(True, _admission())
-    ratio = [f / max(l, 1e-9) for f, l in zip(tt_fair, tt_legacy)]
-
-    def spread(ts):
-        finite = [t for t in ts if np.isfinite(t)]
-        return max(finite) / max(min(finite), 1e-9) if finite else float("inf")
-
+    res = async_engine.run_async(
+        sys_, apps, applies=applies, buffer_k=4, staleness_alpha=0.5,
+        model_bytes=4e5, compute_ms=compute, relay_admission=_admission(),
+    )
+    tt_fair = [tt(res["history"], a.handle.app_id) for a in apps]
+    finite = [t for t in tt_fair if np.isfinite(t)]
     return {
         "m": m,
         "target_loss": target,
-        "tt_legacy_ms": tt_legacy,
         "tt_fair_ms": tt_fair,
-        "tt_ratio": ratio,
-        "max_regression": max(ratio),
-        "mean_ratio": float(np.mean(ratio)),
-        "spread_legacy": spread(tt_legacy),
-        "spread_fair": spread(tt_fair),
-        "all_finite": bool(all(np.isfinite(t) for t in tt_fair + tt_legacy)),
+        "spread_fair": max(finite) / max(min(finite), 1e-9) if finite else float("inf"),
+        "all_finite": len(finite) == len(tt_fair),
     }
 
 
@@ -245,7 +217,7 @@ def compression_compare(
         apps = make_apps(sys_, nodes, rng)
         res = async_engine.run_async(
             sys_, apps, applies=applies, buffer_k=4, staleness_alpha=0.5,
-            model_bytes=model_bytes, compute_ms=5.0, fair=True,
+            model_bytes=model_bytes, compute_ms=5.0,
             compression=compression, max_events=8_000_000,
         )
         final = {}
@@ -323,7 +295,7 @@ def downlink_compare(
         apps = make_apps(sys_, nodes, rng)
         res = async_engine.run_async(
             sys_, apps, applies=applies, buffer_k=4, staleness_alpha=0.5,
-            model_bytes=model_bytes, compute_ms=5.0, fair=True,
+            model_bytes=model_bytes, compute_ms=5.0,
             compression=compression, max_events=8_000_000,
         )
         ids = [a.handle.app_id for a in apps]
@@ -439,24 +411,8 @@ def gate(results: list[dict], guard: dict | None) -> list[str]:
     for r in results:
         if r["jain_fair"] < 0.8:
             fails.append(f"M={r['m']}: jain_fair {r['jain_fair']:.3f} < 0.8")
-        if r["jain_fair"] < r["jain_legacy"]:
-            fails.append(
-                f"M={r['m']}: jain did not improve "
-                f"({r['jain_legacy']:.3f} -> {r['jain_fair']:.3f})"
-            )
-    if guard is not None:
-        if not guard["all_finite"]:
-            fails.append("time-to-loss guard: some app never reached the target")
-        if guard["max_regression"] > 1.05:
-            fails.append(
-                f"time-to-loss guard: worst app regressed "
-                f"{(guard['max_regression'] - 1) * 100:.1f}% (> 5%)"
-            )
-        if guard["spread_fair"] > guard["spread_legacy"] * 1.02:
-            fails.append(
-                f"time-to-loss guard: spread widened "
-                f"({guard['spread_legacy']:.2f} -> {guard['spread_fair']:.2f})"
-            )
+    if guard is not None and not guard["all_finite"]:
+        fails.append("time-to-loss guard: some app never reached the target")
     return fails
 
 
@@ -468,8 +424,8 @@ def run() -> list[str]:
             row(
                 f"fairness_m{m}",
                 0.0,
-                f"jain_legacy={r['jain_legacy']:.3f};jain_fair={r['jain_fair']:.3f};"
-                f"hot_ratio={r['hot_ratio_legacy']:.2f}->{r['hot_ratio_fair']:.2f};"
+                f"jain_fair={r['jain_fair']:.3f};"
+                f"hot_ratio={r['hot_ratio_fair']:.2f};"
                 f"deferred={r['deferred_commits']}",
             )
         )
@@ -489,16 +445,15 @@ def main(argv=None) -> None:
     results = [fairness_compare(m) for m in ms]
     for r in results:
         print(
-            f"M={r['m']}: jain legacy={r['jain_legacy']:.3f} -> fair={r['jain_fair']:.3f}  "
-            f"hot app ratio {r['hot_ratio_legacy']:.2f} -> {r['hot_ratio_fair']:.2f}  "
-            f"min ratio {r['min_ratio_legacy']:.2f} -> {r['min_ratio_fair']:.2f}  "
+            f"M={r['m']}: jain={r['jain_fair']:.3f}  "
+            f"hot app ratio {r['hot_ratio_fair']:.2f}  "
+            f"min ratio {r['min_ratio_fair']:.2f}  "
             f"deferred={r['deferred_commits']}"
         )
     guard = time_to_loss_guard()
     print(
         f"time-to-loss (M={guard['m']}, target {guard['target_loss']}): "
-        f"mean fair/legacy {guard['mean_ratio']:.2f}x, worst {guard['max_regression']:.2f}x, "
-        f"spread {guard['spread_legacy']:.2f} -> {guard['spread_fair']:.2f}"
+        f"every app reached it: {guard['all_finite']}, spread {guard['spread_fair']:.2f}"
     )
 
     from benchmarks.bench_async import _json_safe
@@ -519,8 +474,7 @@ def main(argv=None) -> None:
         print(f"GATE FAIL: {msg}")
     if fails:
         raise SystemExit(1)
-    print("fairness gates passed: jain >= 0.8, improves on legacy, "
-          "no app's time-to-loss regressed > 5%")
+    print("fairness gates passed: jain >= 0.8, every app reached the target loss")
 
 
 if __name__ == "__main__":

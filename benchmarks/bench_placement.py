@@ -115,7 +115,6 @@ def _run_once(m, seed, placement, *, pass_kwarg=True):
         model_bytes=MODEL_BYTES,
         compute_ms=async_engine.worker_compute_fn(20.0, 3.0, seed),
         base_ms=BASE_MS,
-        fair=True,
         churn=churn,
         max_events=8_000_000,
         **kw,

@@ -26,11 +26,11 @@ directly against the vectorized scale layer (docs/performance.md
   oracle — parent maps, children order, members, schedules), at
   N = 1e5 bulk bootstrap must be >= 10x faster than the loop, and mean
   member depth must fit ``a + c*log2(N)`` with R^2 >= 0.95.
-- **M=16 exactness anchor**: the cohort-batched core in exact mode must
-  produce a byte-identical event trace (ApplyEvent/ChurnRecord
-  dataclass equality, exact float timestamps) to the per-event
-  baseline, and ``congestion_mode="sampled"`` with ``hot_threshold=0``
-  must degenerate to the exact trace.
+- **M=16 exactness anchor**: ``congestion_mode="sampled"`` with
+  ``hot_threshold=0`` must degenerate to the exact trace
+  (ApplyEvent/ChurnRecord dataclass equality, exact float timestamps).
+  The cohort-batched heap's identity to one heap entry per event is a
+  test (tests/test_scale.py).
 - **sampled-congestion error**: apply-time relative error of sampled
   mode vs the exact trace, with and without periodic cold-cycle
   re-pricing (``resample_every``) — reported, not gated (the knob
@@ -38,7 +38,7 @@ directly against the vectorized scale layer (docs/performance.md
 
 Gates (CI fails on regression): hops and depth log-fit R^2 >= 0.95,
 zero oracle mismatches, bulk-vs-sequential tree identity, >= 10x
-bootstrap speedup at N=1e5, both trace-identity checks.
+bootstrap speedup at N=1e5, the sampled(ht=0) trace identity.
 ``--max-events`` threads the event budget through for longer runs (the
 budget error names it).
 
@@ -232,7 +232,7 @@ def _make_handles(sys_, nodes, rng, m, w, tag=""):
     return handles
 
 
-def _timing_run(m_apps, *, cohort, congestion_mode, hot_threshold=4, workers=8,
+def _timing_run(m_apps, *, congestion_mode, hot_threshold=4, workers=8,
                 applies=2, seed=0, base_ms=40.0, spread=6.0, model_bytes=2e5,
                 n_nodes=600, zones=4, max_events=1_000_000,
                 resample_every=None, resample_events=None) -> dict:
@@ -248,7 +248,7 @@ def _timing_run(m_apps, *, cohort, congestion_mode, hot_threshold=4, workers=8,
     )
     sched = AsyncBufferScheduler(
         sys_a, handles, model_bytes=model_bytes, compute_ms=per_worker,
-        buffer_k=max(2, workers // 2), churn=churn, cohort=cohort,
+        buffer_k=max(2, workers // 2), churn=churn,
         congestion_mode=congestion_mode, hot_threshold=hot_threshold,
         resample_every=resample_every, resample_events=resample_events,
     )
@@ -271,7 +271,7 @@ def event_scaling(ms, *, applies=2, seed=0, max_events=1_000_000) -> list[dict]:
     out = []
     for m in ms:
         r = _timing_run(
-            m, cohort=True, congestion_mode="sampled", applies=applies,
+            m, congestion_mode="sampled", applies=applies,
             seed=seed, max_events=max_events,
         )
         out.append(
@@ -289,23 +289,16 @@ def event_scaling(ms, *, applies=2, seed=0, max_events=1_000_000) -> list[dict]:
 
 
 def trace_identity(*, m_apps=16, applies=3, seed=0, max_events=1_000_000) -> dict:
-    """The exactness anchor: cohort/exact and sampled(ht=0) vs baseline."""
+    """The exactness anchor: sampled(ht=0) vs exact."""
     kw = dict(applies=applies, seed=seed, max_events=max_events)
-    base = _timing_run(m_apps, cohort=False, congestion_mode="exact", **kw)
-    coh = _timing_run(m_apps, cohort=True, congestion_mode="exact", **kw)
-    deg = _timing_run(
-        m_apps, cohort=True, congestion_mode="sampled", hot_threshold=0, **kw
-    )
+    exact = _timing_run(m_apps, congestion_mode="exact", **kw)
+    deg = _timing_run(m_apps, congestion_mode="sampled", hot_threshold=0, **kw)
     return {
         "m": int(m_apps),
-        "cohort_identical": base["events"] == coh["events"]
-        and base["churn"] == coh["churn"],
-        "sampled_ht0_identical": base["events"] == deg["events"]
-        and base["churn"] == deg["churn"],
-        "events_dispatched_baseline": base["events_dispatched"],
-        "events_dispatched_cohort": coh["events_dispatched"],
-        "heap_max_baseline": base["heap_max"],
-        "heap_max_cohort": coh["heap_max"],
+        "sampled_ht0_identical": exact["events"] == deg["events"]
+        and exact["churn"] == deg["churn"],
+        "events_dispatched": exact["events_dispatched"],
+        "heap_max": exact["heap_max"],
     }
 
 
@@ -317,13 +310,13 @@ def sampled_error(*, m_apps=8, applies=2, seed=1, base_ms=40.0,
     under bursty contention (ROADMAP follow-on (c)) — reported as data,
     not gated."""
     kw = dict(applies=applies, seed=seed, max_events=max_events)
-    exact = _timing_run(m_apps, cohort=True, congestion_mode="exact", **kw)
+    exact = _timing_run(m_apps, congestion_mode="exact", **kw)
     runs = {
         "sampled": _timing_run(
-            m_apps, cohort=True, congestion_mode="sampled", **kw
+            m_apps, congestion_mode="sampled", **kw
         ),
         "sampled_resampled": _timing_run(
-            m_apps, cohort=True, congestion_mode="sampled",
+            m_apps, congestion_mode="sampled",
             resample_every=2.0 * base_ms, **kw
         ),
     }
@@ -380,8 +373,6 @@ def gate(payload: dict, *, min_r2: float = 0.95, min_speedup: float = 10.0) -> l
                 f"below the {min_speedup}x gate"
             )
     tid = payload["trace_identity"]
-    if not tid["cohort_identical"]:
-        fails.append("M=16 cohort trace diverges from the per-event baseline")
     if not tid["sampled_ht0_identical"]:
         fails.append("M=16 sampled(hot_threshold=0) trace diverges from exact")
     for r in payload["events_vs_m"]:
@@ -460,7 +451,6 @@ def run() -> list[str]:
             0.0,
             f"fit_r2={fit['r2']:.4f};slope={fit['slope_per_log2n']:.3f};"
             f"depth_fit_r2={dfit['r2']:.4f};"
-            f"cohort_identical={tid['cohort_identical']};"
             f"sampled_ht0_identical={tid['sampled_ht0_identical']};"
             f"resample_mean_err={serr['sampled_resampled']['mean_rel_err']:.4f}",
         )
@@ -524,10 +514,8 @@ def main() -> None:
     )
     tid = payload["trace_identity"]
     print(
-        f"M={tid['m']} trace identity: cohort == baseline: "
-        f"{tid['cohort_identical']}; sampled(ht=0) == exact: "
-        f"{tid['sampled_ht0_identical']}; heap max "
-        f"{tid['heap_max_baseline']} -> {tid['heap_max_cohort']}"
+        f"M={tid['m']} trace identity: sampled(ht=0) == exact: "
+        f"{tid['sampled_ht0_identical']}; heap max {tid['heap_max']}"
     )
     for r in payload["events_vs_m"]:
         print(

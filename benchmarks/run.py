@@ -21,17 +21,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 # broken bench must not take down the driver's help or other benches).
 REGISTRY: list[tuple[str, str, str]] = [
     ("engine+sim(TabIII)", "benchmarks.bench_engine",
-     "vectorized round engine vs per-worker loop; M-app event simulator vs centralized baseline"),
+     "M-app event simulator vs centralized baseline"),
     ("async_vs_sync(FedBuff)", "benchmarks.bench_async",
      "sync vs fixed-K vs adaptive-K vs adaptive-K+utility time-to-target-loss under churn"),
     ("fairness(TabIII)", "benchmarks.bench_fairness",
-     "multi-app uplink fairness: weighted-fair re-pricing vs legacy start-time pricing, Jain's index at M in {4,16,64}"),
+     "multi-app uplink fairness under weighted-fair re-pricing: Jain's index at M in {4,16,64}, every app reaches its target loss"),
     ("compression", "benchmarks.bench_compression",
      "compressed wire, both directions: qsgd-int8 commits (time-to-target + <=1e-2 loss-gap gates on a tight uplink) and delta-qsgd downlink broadcasts (total bytes < 0.35x, time-to-target <= 0.90x vs uplink-only)"),
-    ("hotpath(perf)", "benchmarks.bench_hotpath",
-     "simulator hot paths: megabatched dispatch + compiled kernel fallback + incremental repricing vs the pre-optimization engine (>=3x gate, byte-identical traces)"),
     ("scale(perf)", "benchmarks.bench_scale",
-     "million-node scale layer: route_many hops vs N log-fit (R^2 gate), cohort-batched events/s + peak RSS vs M, M=16 trace-identity anchor"),
+     "million-node scale layer: route_many hops vs N log-fit (R^2 gate), cohort-batched events/s + peak RSS vs M, M=16 sampled(ht=0) trace-identity anchor"),
     ("scalability(Fig5)", "benchmarks.bench_scalability",
      "overlay join/route cost vs network size"),
     ("hops(Fig6)", "benchmarks.bench_hops",

@@ -98,7 +98,7 @@ def fair_share_rates(
 class UplinkState:
     """Incremental weighted max-min fair allocator for ONE uplink.
 
-    The legacy path rebuilt everything per flow join/complete: a group-
+    A full re-price rebuilds everything per flow join/complete: a group-
     count dict, weight/cap lists, then ``fair_share_rates``'s progressive
     relaxation — O(F) dict churn plus O(F x rounds) water-filling (worst
     case O(F^2) when caps bind one at a time).  This structure makes the
@@ -116,14 +116,15 @@ class UplinkState:
       single pass, **bit-for-bit identical** to ``fair_share_rates``:
       same sequential weight sum in flow-insertion order, same
       ``capacity * w_i / wsum`` division.  That exactness is what lets
-      the incremental event engine keep byte-identical traces
-      (bench_hotpath's gate).  The weight sum is deliberately re-summed
-      per call (O(F) float adds on a list walk — cheap) rather than
+      the incremental event engine keep byte-identical traces against
+      the water-filling oracle (tests/test_hotpath.py).  The weight sum
+      is deliberately re-summed per call (O(F) float adds on a list
+      walk — cheap) rather than
       maintained by +=/-=: float addition is not associative, and an
       incrementally drifted sum would break trace identity.
 
     Flows in one ``group`` split a single weight share and cap equally
-    (per-app fairness), exactly as the legacy engine computed it.
+    (per-app fairness), exactly as ``fair_share_rates`` is fed them.
     """
 
     __slots__ = ("capacity", "_flows", "_group_n", "_ladder")

@@ -30,17 +30,17 @@ This module splits that into an event core plus two schedulers:
   error compounds into uplink starvation (ROADMAP).  ``EventCore`` now
   carries a fluid-flow engine: each hop of a transfer is an open *flow*
   on its sender's uplink, the uplink is divided by weighted max-min fair
-  sharing (``core/congestion.fair_share_rates``), and whenever a flow
+  sharing (``core/congestion.UplinkState``), and whenever a flow
   joins or completes every in-flight flow on that uplink is **re-priced
   progress-preservingly** — bytes already delivered at the old rate stay
   delivered, only the remaining bytes reschedule at the new rate (a
   virtual-finish-time update; total delivered bytes are conserved
   exactly across any number of re-prices).  ``AsyncBufferScheduler``
-  uses the fair engine by default (``fair=False`` keeps the exact PR-3
-  start-time pricing); an uncontended (single-flow) fair trace is
-  identical to the legacy trace because one flow's fair share is the
-  whole uplink.  Per-app ``transfer_weight`` / ``rate_cap_mbps`` knobs
-  bias or bound the share, and a ``RelayAdmission`` policy adds
+  prices every transfer leg on this engine; an uncontended (single-flow)
+  leg takes its bits over each hop's capacity, summed over the hops,
+  because one flow's fair share is the whole uplink.  Per-app
+  ``transfer_weight`` / ``rate_cap_mbps`` knobs bias or bound the
+  share, and a ``RelayAdmission`` policy adds
   staleness-aware admission at shared relays: a contended relay defers
   forwarding commits whose staleness discount ``1/(1+s)^a`` has decayed
   below a threshold, freeing uplink for fresh traffic (deferred commits
@@ -58,23 +58,20 @@ This module splits that into an event core plus two schedulers:
   (utility-based straggler avoidance), with ``selector=None`` /
   ``UniformSelector`` preserving the admit-everyone behavior.
 
-- **Hot-path overhaul** (this PR): transfer pricing and event plumbing
-  were the simulator's own bottleneck at M >= 16.  Three exact-semantics
-  optimizations, all defaulting on: (1) *incremental repricing* — each
-  uplink keeps a ``core/congestion.UplinkState`` (incremental group
-  counts + a cap ladder sorted by the group-invariant ``cap/weight``
-  ratio) and schedules ONE completion event (the earliest finisher)
-  instead of one per flow, so a flow join/complete costs O(F) float
-  adds + O(log H) heap work instead of O(F log H) pushes that each left
-  a dead heap entry behind; (2) *lazy-deletion heap compaction* —
-  cancelled events are counted and the heap is rebuilt once dead
-  entries outnumber live ones, bounding heap size under churn; (3)
-  *numpy-resident route tables* — ``transfer_ms`` prices phases with
-  f32 numpy arithmetic (bit-identical to the jitted lookup it
-  replaces) and ``_path_senders`` memoizes per-(app, worker, direction)
-  sender arrays between churn events.  ``incremental=False`` restores
-  the full-water-filling engine; traces are byte-identical either way
-  (gated by benchmarks/bench_hotpath.py).
+- **Hot paths**: (1) *incremental repricing* — each uplink keeps a
+  ``core/congestion.UplinkState`` (incremental group counts + a cap
+  ladder sorted by the group-invariant ``cap/weight`` ratio) and
+  schedules ONE completion event (the earliest finisher) instead of one
+  per flow, so a flow join/complete costs O(F) float adds + O(log H)
+  heap work; the event trace is byte-identical to a full water-filling
+  re-price with one event per flow (the oracle tests/test_hotpath.py
+  keeps); (2) *lazy-deletion heap compaction* — cancelled events are
+  counted and the heap is rebuilt once dead entries outnumber live
+  ones, bounding heap size under churn; (3) *numpy-resident route
+  tables* — ``transfer_ms`` prices phases with f32 numpy arithmetic
+  (bit-identical to the jitted lookup it replaces) and
+  ``_path_senders`` memoizes per-(app, worker, direction) sender arrays
+  between churn events.
 
 Units and invariants: the clock is simulated milliseconds (``now``,
 every ``*_ms``); transfer sizes are bytes (``model_bytes``), converted
@@ -95,7 +92,7 @@ import numpy as np
 
 from repro import tracing
 
-from .congestion import CongestionEnv, UplinkState, fair_share_rates
+from .congestion import CongestionEnv, UplinkState
 
 
 @dataclass(frozen=True)
@@ -199,7 +196,7 @@ class _Flow:
 
     __slots__ = (
         "fid", "sender", "total_mbit", "delivered_mbit", "weight",
-        "rate_cap", "on_done", "ev", "rate", "t_last", "group",
+        "rate_cap", "on_done", "rate", "t_last", "group",
     )
 
     def __init__(self, fid, sender, mbit, weight, rate_cap, on_done, group):
@@ -210,7 +207,6 @@ class _Flow:
         self.weight = float(weight)
         self.rate_cap = rate_cap
         self.on_done = on_done
-        self.ev: int | None = None
         self.rate = 0.0
         self.t_last = 0.0
         self.group = group  # flows sharing a group split ONE weight share
@@ -240,7 +236,6 @@ class EventCore:
 
     def __init__(
         self, system, handles, *, model_bytes: float, base_ms: float = 5.0,
-        incremental: bool = True,
     ):
         self.system = system
         self.handles = list(handles)
@@ -254,7 +249,6 @@ class EventCore:
         self._cap_f32 = cap  # numpy-resident mirror for transfer_ms
         self.model_bytes = float(model_bytes)
         self.base_ms = float(base_ms)
-        self.incremental = bool(incremental)
         self.env = CongestionEnv(
             capacity=jnp.asarray(cap),
             theta=jnp.ones(len(nodes), jnp.float32),
@@ -274,7 +268,7 @@ class EventCore:
         self._flows_by_sender: dict[int, list[int]] = {}
         self._flow_seq = 0
         # incremental-repricing state: one allocator + at most one pending
-        # completion event per uplink (instead of one event per flow)
+        # completion event per uplink
         self._uplink_state: dict[int, UplinkState] = {}
         self._uplink_ev: dict[int, int] = {}
         # cohort batching: events sharing a cohort id keep their own
@@ -484,13 +478,12 @@ class EventCore:
         f.t_last = self.now
         self._flows[fid] = f
         self._flows_by_sender.setdefault(f.sender, []).append(fid)
-        if self.incremental:
-            st = self._uplink_state.get(f.sender)
-            if st is None:
-                st = self._uplink_state[f.sender] = UplinkState(
-                    float(self._cap_mbps[f.sender])
-                )
-            st.add(fid, f.weight, f.rate_cap, key)
+        st = self._uplink_state.get(f.sender)
+        if st is None:
+            st = self._uplink_state[f.sender] = UplinkState(
+                float(self._cap_mbps[f.sender])
+            )
+        st.add(fid, f.weight, f.rate_cap, key)
         self._reprice_uplink(f.sender)
         return fid
 
@@ -500,8 +493,6 @@ class EventCore:
         f = self._flows.pop(fid, None)
         if f is None:
             return
-        if f.ev is not None:
-            self.cancel(f.ev)
         self._drop_from_sender(f)
         self._reprice_uplink(f.sender)
         self._on_uplink_freed(f.sender, self.now)
@@ -516,81 +507,43 @@ class EventCore:
             fids.remove(f.fid)
             if not fids:
                 del self._flows_by_sender[f.sender]
-        if self.incremental:
-            self._uplink_state[f.sender].remove(f.fid)
+        self._uplink_state[f.sender].remove(f.fid)
 
     def _reprice_uplink(self, sender: int) -> None:
         """Progress-preserving re-price of every flow on one uplink:
         credit bytes delivered at the old rates since the last update,
-        recompute the weighted-fair rates, reschedule the completion(s)
-        at ``remaining / new_rate`` (a virtual-finish-time update).
-
-        Incremental mode (the default) gets the rates from the uplink's
-        ``UplinkState`` (group counts and the sorted cap ladder are
-        maintained on join/complete, not rebuilt here) and schedules ONE
-        completion event — the earliest finisher — instead of one per
-        flow: a reprice costs O(F) float work + O(log H) heap work where
-        the legacy path paid O(F log H) pushes and left F dead heap
-        entries behind.  Completion times are computed with the same
-        arithmetic in the same flow order, so event traces are
-        byte-identical across both modes (bench_hotpath's gate).
+        take the weighted-fair rates from the uplink's ``UplinkState``
+        (group counts and the sorted cap ladder are maintained on
+        join/complete, not rebuilt here), and schedule ONE completion
+        event — the earliest finisher at ``remaining / new_rate`` (a
+        virtual-finish-time update).  A reprice costs O(F) float work +
+        O(log H) heap work.
         """
-        if self.incremental:
-            prev = self._uplink_ev.pop(sender, None)
-            if prev is not None:
-                self.cancel(prev)
-            fids = self._flows_by_sender.get(sender)
-            if not fids:
-                return
-            flows = [self._flows[fid] for fid in fids]
-            now = self.now
-            for f in flows:
-                f.delivered_mbit = min(
-                    f.total_mbit, f.delivered_mbit + f.rate * (now - f.t_last) * 1e-3
-                )
-                f.t_last = now
-            rates = self._uplink_state[sender].rates()
-            best_fid, best_delay = None, None
-            for f, r in zip(flows, rates):
-                f.rate = r
-                d = 1e3 * (f.total_mbit - f.delivered_mbit) / max(r, 1e-9)
-                # strict < keeps the earliest-opened flow on ties, matching
-                # the legacy per-flow events' seq-order tie-break
-                if best_delay is None or d < best_delay:
-                    best_fid, best_delay = f.fid, d
-            self._uplink_ev[sender] = self.schedule(
-                best_delay, lambda t, fid=best_fid: self._finish_flow(fid, t)
-            )
-            return
+        prev = self._uplink_ev.pop(sender, None)
+        if prev is not None:
+            self.cancel(prev)
         fids = self._flows_by_sender.get(sender)
         if not fids:
             return
         flows = [self._flows[fid] for fid in fids]
+        now = self.now
         for f in flows:
             f.delivered_mbit = min(
-                f.total_mbit, f.delivered_mbit + f.rate * (self.now - f.t_last) * 1e-3
+                f.total_mbit, f.delivered_mbit + f.rate * (now - f.t_last) * 1e-3
             )
-            f.t_last = self.now
-        # per-group (= per-app) fairness: flows in one group split a
-        # single weight share and rate cap equally, so an app's slice of
-        # a relay is its weight, not its concurrent-flow count
-        group_n: dict = {}
-        for f in flows:
-            group_n[f.group] = group_n.get(f.group, 0) + 1
-        rates = fair_share_rates(
-            float(self._cap_mbps[sender]),
-            [f.weight / group_n[f.group] for f in flows],
-            [None if f.rate_cap is None else f.rate_cap / group_n[f.group] for f in flows],
-        )
+            f.t_last = now
+        rates = self._uplink_state[sender].rates()
+        best_fid, best_delay = None, None
         for f, r in zip(flows, rates):
             f.rate = r
-            if f.ev is not None:
-                self.cancel(f.ev)
-            remaining = f.total_mbit - f.delivered_mbit
-            f.ev = self.schedule(
-                1e3 * remaining / max(r, 1e-9),
-                lambda t, fid=f.fid: self._finish_flow(fid, t),
-            )
+            d = 1e3 * (f.total_mbit - f.delivered_mbit) / max(r, 1e-9)
+            # strict < keeps the earliest-opened flow on ties: the seq-order
+            # tie-break one event per flow would give
+            if best_delay is None or d < best_delay:
+                best_fid, best_delay = f.fid, d
+        self._uplink_ev[sender] = self.schedule(
+            best_delay, lambda t, fid=best_fid: self._finish_flow(fid, t)
+        )
 
     def _finish_flow(self, fid: int, t: float) -> None:
         f = self._flows.pop(fid)
@@ -952,27 +905,25 @@ class AsyncBufferScheduler(EventCore):
     repaired through ``core/recovery.fail_and_recover`` on the same
     clock, and re-grafted orphans stall for the repair latency.
 
-    Transfer pricing (this PR): ``fair=True`` (the default) runs every
-    hop of every download/upload as a fluid flow on its sender's uplink
-    through the ``EventCore`` fair-share engine — weighted max-min
-    sharing, re-priced progress-preservingly whenever a flow joins or
-    completes, so no app keeps a stale solo (or stale congested) rate.
-    Per-app ``app_weights`` / ``app_rate_caps`` (falling back to the
-    handles' ``transfer_weight`` / ``rate_cap_mbps``) bias or bound each
-    app's share, and ``relay_admission`` (a ``RelayAdmission``) defers
-    stale commits at contended relays.  ``fair=False`` restores the
-    PR-3 start-time-only pricing bit for bit; a single-flow (never
-    contended) trace is identical in both modes.  Per-app uplink bytes
-    are accounted per delivered commit leg; ``transport_stats()`` and the
-    per-apply ``fairness_log`` expose throughput and Jain's index.
+    Transfer pricing: every hop of every download/upload runs as a fluid
+    flow on its sender's uplink through the ``EventCore`` fair-share
+    engine — weighted max-min sharing, re-priced progress-preservingly
+    whenever a flow joins or completes, so no app keeps a stale solo (or
+    stale congested) rate.  Per-app ``app_weights`` / ``app_rate_caps``
+    (falling back to the handles' ``transfer_weight`` /
+    ``rate_cap_mbps``) bias or bound each app's share, and
+    ``relay_admission`` (a ``RelayAdmission``) defers stale commits at
+    contended relays.  Per-app uplink bytes are accounted per delivered
+    commit leg; ``transport_stats()`` and the per-apply ``fairness_log``
+    expose throughput and Jain's index.
 
     Compressed transport (docs/performance.md "compressed transport"):
     ``app_compression`` (an ``fl/compression.CompressionPolicy``, kind
     string, or per-app list; falling back to the handles'
     ``compression`` fields) prices every COMMIT leg at
-    ``policy.wire_bytes(model_bytes)`` — through the fair-share flows,
-    the legacy start-time pricing, and the sampled cold-cycle legs
-    alike — and credits the uplink ledger at the same compressed size.
+    ``policy.wire_bytes(model_bytes)`` — through the fair-share flows and
+    the sampled cold-cycle legs alike — and credits the uplink ledger at
+    the same compressed size.
     Downloads stay full-model-sized.  ``None`` / ``kind="none"``
     reproduces the uncompressed trace byte-identically
     (tests/test_compression.py).
@@ -992,11 +943,11 @@ class AsyncBufferScheduler(EventCore):
 
     Scale layer (docs/performance.md "scale layer"):
 
-    - ``cohort=True`` (default) batches per-worker cycle events into one
-      global heap entry per app cohort (``EventCore.schedule_cohort``):
-      the heap holds O(apps + uplinks) entries instead of O(workers),
-      and the dispatch order — hence the ApplyEvent/ChurnRecord trace —
-      is byte-identical to the per-event baseline (``cohort=False``).
+    - Per-worker cycle events are batched into one global heap entry per
+      app cohort (``EventCore.schedule_cohort``): the heap holds
+      O(apps + uplinks) entries instead of O(workers), and the dispatch
+      order — hence the ApplyEvent/ChurnRecord trace — is byte-identical
+      to one heap entry per event (the oracle tests/test_scale.py keeps).
     - ``congestion_mode="exact"`` (default) prices every transfer leg
       through the fluid fair-share engine.  ``"sampled"`` prices COLD
       cycles statistically: the whole download+compute+upload cycle is
@@ -1023,12 +974,9 @@ class AsyncBufferScheduler(EventCore):
         adaptive: bool = False,
         adaptive_kwargs: dict | None = None,
         selector=None,
-        fair: bool = True,
         app_weights: float | list[float] | None = None,
         app_rate_caps: float | list[float] | None = None,
         relay_admission: RelayAdmission | None = None,
-        incremental: bool = True,
-        cohort: bool = True,
         congestion_mode: str = "exact",
         hot_threshold: int = 4,
         resample_every: float | None = None,
@@ -1037,10 +985,7 @@ class AsyncBufferScheduler(EventCore):
         app_compression=None,
         placement=None,
     ):
-        super().__init__(
-            system, handles, model_bytes=model_bytes, base_ms=base_ms,
-            incremental=incremental,
-        )
+        super().__init__(system, handles, model_bytes=model_bytes, base_ms=base_ms)
         if congestion_mode not in ("exact", "sampled"):
             raise ValueError(
                 f"congestion_mode must be 'exact' or 'sampled', got {congestion_mode!r}"
@@ -1066,7 +1011,6 @@ class AsyncBufferScheduler(EventCore):
                 raise ValueError(
                     f"resample_target_error must be > 0, got {resample_target_error!r}"
                 )
-        self.cohort = bool(cohort)
         self.congestion_mode = congestion_mode
         self.hot_threshold = int(hot_threshold)
         self.resample_every = None if resample_every is None else float(resample_every)
@@ -1101,7 +1045,6 @@ class AsyncBufferScheduler(EventCore):
         self.adaptive = bool(adaptive)
         self.adaptive_kwargs = dict(adaptive_kwargs or {})
         self.selector = selector
-        self.fair = bool(fair)
         self.relay_admission = relay_admission
         self._weight = self._per_app(app_weights, "transfer_weight", 1.0)
         self._cap = self._per_app(app_rate_caps, "rate_cap_mbps", None)
@@ -1118,8 +1061,8 @@ class AsyncBufferScheduler(EventCore):
         # "compressed downlink"): a per-app CompressionPolicy shrinks the
         # COMMIT payload, and — when its downlink axis is on — the
         # BROADCAST payload too; the compressed byte counts are what
-        # every pricing path sees: fair-share flows (open_flow mbit),
-        # the legacy start-time pricing, and sampled cold-cycle legs.
+        # both pricing paths see: fair-share flows (open_flow mbit) and
+        # sampled cold-cycle legs.
         # Download legs are priced per worker (_download_mbit): a
         # delta-qsgd worker pays its version-gap chain, a rejoiner or
         # over-cap straggler the full f32 fallback.  policy None /
@@ -1286,13 +1229,9 @@ class AsyncBufferScheduler(EventCore):
             self._path_cache[(ai, w, True)] = row[:di].copy()
             self._path_cache[(ai, w, False)] = row[1 : di + 1][::-1].copy()
 
-    def _sched_worker(self, ai: int, delay_ms: float, callback: Callable,
-                      senders: np.ndarray | None = None) -> int:
-        """Schedule one per-worker cycle event — cohort-batched per app
-        when ``cohort`` is on, a plain heap entry otherwise."""
-        if self.cohort:
-            return self.schedule_cohort(ai, delay_ms, callback, senders)
-        return self.schedule(delay_ms, callback, senders)
+    def _sched_worker(self, ai: int, delay_ms: float, callback: Callable) -> int:
+        """Schedule one per-worker cycle event, cohort-batched per app."""
+        return self.schedule_cohort(ai, delay_ms, callback)
 
     # -- sampled/statistical congestion (cold-path cycles) ---------------------
 
@@ -1310,8 +1249,8 @@ class AsyncBufferScheduler(EventCore):
     def _sampled_leg_ms(self, senders: np.ndarray, mbit: float | None = None) -> float:
         """Statistical store-and-forward price of one leg: each hop at its
         *current* load (fluid flows + cold cycles + this one), frozen for
-        the cycle's whole duration.  Same f32 arithmetic as the legacy
-        ``transfer_ms`` pricing, with the cold-cycle load folded in.
+        the cycle's whole duration.  Same f32 arithmetic as
+        ``transfer_ms``, with the cold-cycle load folded in.
         ``mbit`` overrides the payload size (compressed commit legs)."""
         if len(senders) == 0:
             return 0.0
@@ -1524,15 +1463,9 @@ class AsyncBufferScheduler(EventCore):
         ):
             self._start_cycle_cold(ai, w, delay, down_mbit)
             return
-        if self.fair:
-            self._begin_leg(
-                ai, w, senders, delay, commit=False, mbit=down_mbit,
-                done=lambda t, ai=ai, w=w: self._on_downloaded(ai, w, t),
-            )
-            return
-        dur = delay + self.transfer_ms(senders, reduce="sum", mbit=down_mbit)
-        self._pending_ev[key] = self._sched_worker(
-            ai, dur, lambda t, ai=ai, w=w: self._on_downloaded(ai, w, t), senders
+        self._begin_leg(
+            ai, w, senders, delay, commit=False, mbit=down_mbit,
+            done=lambda t, ai=ai, w=w: self._on_downloaded(ai, w, t),
         )
 
     def _on_downloaded(self, ai: int, w: int, t: float) -> None:
@@ -1551,15 +1484,9 @@ class AsyncBufferScheduler(EventCore):
         if self._done[ai] or w in self._failed:
             return
         senders = self._path_senders(ai, w, up=True)
-        if self.fair:
-            self._begin_leg(
-                ai, w, senders, 0.0, commit=True,
-                done=lambda t, ai=ai, w=w: self._on_uploaded(ai, w, t),
-            )
-            return
-        dur = self.transfer_ms(senders, reduce="sum", mbit=self._commit_mbit[ai])
-        self._pending_ev[(ai, w)] = self._sched_worker(
-            ai, dur, lambda t, ai=ai, w=w: self._on_uploaded(ai, w, t), senders
+        self._begin_leg(
+            ai, w, senders, 0.0, commit=True,
+            done=lambda t, ai=ai, w=w: self._on_uploaded(ai, w, t),
         )
 
     # -- fair-share leg execution (hop-by-hop fluid flows) ---------------------
@@ -1570,8 +1497,8 @@ class AsyncBufferScheduler(EventCore):
     ) -> None:
         """Run one transfer leg (download or upload) as sequential per-hop
         flows on the fair-share engine.  The leg's store-and-forward total
-        for an uncontended path equals the legacy ``reduce="sum"`` price
-        exactly: sum over hops of ``base_ms + mbit / capacity``.  Commit
+        for an uncontended path is the sum over hops of
+        ``base_ms + mbit / capacity``.  Commit
         legs pass relay admission at every intermediate hop.  ``(ai, w)``
         stays in
         ``_pending_ev`` for the whole leg (cycle liveness/barrier checks
@@ -1701,9 +1628,9 @@ class AsyncBufferScheduler(EventCore):
         if self._done[ai] or w in self._failed:
             return
         key = (ai, w)
-        # uplink bytes are credited at commit (leg) granularity in BOTH
-        # pricing modes, so fairness comparisons across modes never
-        # measure accounting granularity at a horizon cut; flow-level
+        # uplink bytes are credited at commit (leg) granularity in both
+        # congestion modes, so comparisons across modes never measure
+        # accounting granularity at a horizon cut; flow-level
         # byte conservation across re-prices is asserted separately
         # (tests/test_fairness.py on _Flow.delivered_mbit)
         up_path = self._path_senders(ai, w, up=True)

@@ -8,10 +8,11 @@ versions through the system — a worker trains from the (possibly stale)
 global version it downloaded, and the master keeps every version that
 still has in-flight workers so their deltas can be reproduced exactly.
 
-The actual gradient work is the same jitted path the synchronous engine
+The actual gradient work is the same jitted path the synchronous round
 uses: when an apply fires, the buffered commits are grouped by model
-version and each group runs through ``engine.batched_local_train`` as
-one vmap (one XLA dispatch per version, not per worker).  Deltas then
+version and every group of the apply stacks into ONE
+``engine.fused_local_training`` dispatch (``megabatched_local_train``
+carries per-worker start params).  Deltas then
 flow through the Table-II async verbs — ``CommitDelta`` per worker
 (per-edge traffic up the tree) and one ``ApplyBuffered`` (staleness
 discount folded into the ``tree_aggregate_groups`` kernel's weight
@@ -91,14 +92,13 @@ class AsyncTrainer:
 
     def __init__(
         self, system, apps, *, staleness_alpha: float = 0.5, replicate: bool = True,
-        selector=None, megabatch: bool = True, compression=None,
+        selector=None, compression=None,
     ):
         self.system = system
         self.apps = list(apps)
         self.staleness_alpha = float(staleness_alpha)
         self.replicate = replicate
         self.selector = selector
-        self.megabatch = bool(megabatch)
         n = len(self.apps)
         if isinstance(compression, (str, CompressionPolicy)):
             compression = [compression] * n
@@ -226,22 +226,12 @@ class AsyncTrainer:
             for w, v, seq in pending:
                 groups.setdefault(v, []).append((w, seq))
             versions = sorted(groups)
-            if self.megabatch:
-                # every version group of this apply stacks into ONE compiled
-                # dispatch: megabatched_local_train carries per-worker start
-                # params, so staleness-ragged buffers stop costing one XLA
-                # program (and often one compile) per version
-                trained = engine.fused_local_training(
-                    [(app, [w for w, _ in groups[v]], self._snapshots[ai][v]) for v in versions]
-                )
-            else:  # pre-optimization path: one dispatch per version group
-                trained = [
-                    engine.local_training(
-                        app, [w for w, _ in groups[v]], params=self._snapshots[ai][v],
-                        bucketed=False,
-                    )
-                    for v in versions
-                ]
+            # every version group of this apply stacks into ONE compiled
+            # dispatch: megabatched_local_train carries per-worker start
+            # params, so staleness-ragged buffers cost one XLA program
+            trained = engine.fused_local_training(
+                [(app, [w for w, _ in groups[v]], self._snapshots[ai][v]) for v in versions]
+            )
             policy = self._compression[ai]
             losses, loss_weights = [], []
             for v, (deltas, weights, group_losses) in zip(versions, trained):
@@ -347,14 +337,10 @@ def run_async(
     adaptive: bool = False,
     adaptive_kwargs: dict | None = None,
     selector=None,
-    fair: bool = True,
     app_weights=None,
     app_rate_caps=None,
     relay_admission=None,
     compression=None,
-    megabatch: bool = True,
-    incremental: bool = True,
-    cohort: bool = True,
     congestion_mode: str = "exact",
     hot_threshold: int = 4,
     resample_every: float | None = None,
@@ -367,17 +353,11 @@ def run_async(
     every app to ``applies`` buffered updates.  Returns the scheduler
     apply events, churn log, and the trainer's loss-vs-simtime history.
 
-    ``megabatch=False`` restores the per-version-group dispatch loop and
-    ``incremental=False`` the full-water-filling repricing engine — the
-    pre-optimization hot paths kept as bench_hotpath baselines (both
-    default on; results match to fp tolerance, event traces exactly).
-
     ``adaptive=True`` turns on per-app ``AdaptiveKController``s
     (``buffer_k`` seeds K); ``selector`` plugs a
     ``fl/selection.ClientSelector`` into both the scheduler (admission,
     cycle-time feedback) and the trainer (loss/delta-norm feedback).
-    ``fair`` selects the weighted-fair transfer pricing (default; set
-    False for the legacy start-time-only pricing), ``app_weights`` /
+    Transfers are priced as weighted-fair flows; ``app_weights`` /
     ``app_rate_caps`` bias or bound per-app uplink shares, and
     ``relay_admission`` (a ``core.sim.RelayAdmission``) defers stale
     commits at contended relays.
@@ -394,9 +374,8 @@ def run_async(
     ``error_feedback`` carries per-worker EF-SGD residuals across
     commits.
 
-    Scale knobs (docs/performance.md "scale layer"): ``cohort`` batches
-    per-worker events into one heap entry per app (trace-identical,
-    default on); ``congestion_mode="sampled"`` prices cold cycles
+    Scale knobs (docs/performance.md "scale layer"):
+    ``congestion_mode="sampled"`` prices cold cycles
     statistically with ``hot_threshold`` selecting which uplinks stay
     exact, and ``resample_every`` (simulated ms) / ``resample_events``
     (dispatch count) periodically re-price in-flight cold cycles against
@@ -414,7 +393,7 @@ def run_async(
 
     trainer = AsyncTrainer(
         system, apps, staleness_alpha=staleness_alpha, selector=selector,
-        megabatch=megabatch, compression=compression,
+        compression=compression,
     )
     sched = AsyncBufferScheduler(
         system,
@@ -429,13 +408,10 @@ def run_async(
         adaptive=adaptive,
         adaptive_kwargs=adaptive_kwargs,
         selector=selector,
-        fair=fair,
         app_weights=app_weights,
         app_rate_caps=app_rate_caps,
         relay_admission=relay_admission,
         app_compression=compression,
-        incremental=incremental,
-        cohort=cohort,
         congestion_mode=congestion_mode,
         hot_threshold=hot_threshold,
         resample_every=resample_every,

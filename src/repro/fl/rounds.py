@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.core.api import TotoroSystem
+from repro.fl import engine
 from repro.fl import small_models as sm
 
 
@@ -64,32 +65,62 @@ def make_app(
     )
 
 
-def run_round(
-    system: TotoroSystem, app: FLApp, *, use_kernel: bool = True, vectorized: bool = True
-) -> dict:
-    """One Totoro+ round; returns metrics incl. modeled wall time.
+def run_round(system: TotoroSystem, app: FLApp, *, use_kernel: bool = True) -> dict:
+    """One Totoro+ round through the Table-II verbs; returns metrics incl.
+    modeled wall time.
 
-    Delegates to the vectorized round engine (``fl/engine.py``): all
-    workers' local steps run as one jitted vmap, aggregation executes the
-    tree's level schedule through the batched Pallas kernel.  Pass
-    ``vectorized=False`` for the per-worker reference loop.
+    Broadcast down the tree, every worker's local steps in one engine
+    dispatch (``engine.fused_local_training``, a batch of one job),
+    hierarchical kernel aggregation up the tree (``TotoroSystem.Aggregate``
+    executes the level schedule), master server-update + state
+    replication.
     """
-    from repro.fl import engine
-
-    return engine.run_round(system, app, use_kernel=use_kernel, vectorized=vectorized)
+    return run_round_fused(system, [app], use_kernel=use_kernel)[0]
 
 
 def run_round_fused(system: TotoroSystem, apps: list[FLApp], *, use_kernel: bool = True) -> list[dict]:
-    """One round for many apps with a single fused training dispatch.
+    """One round for MANY apps with a single fused training dispatch.
 
-    Delegates to ``fl/engine.run_round_fused``: same-config apps stack
-    into one megabatched vmap (per-worker start params, shape-bucketed
-    padding) and deltas unstack per app — per-app results match
-    ``run_round`` to fp tolerance while dispatches per round drop from
-    M to the number of distinct static configs."""
-    from repro.fl import engine
+    Every app Broadcasts, then all apps' workers train together through
+    ``engine.fused_local_training`` (same-config apps stack into one
+    megabatched vmap; deltas unstack per app), then each app Aggregates
+    and applies its server update.  Per app this is ``run_round``;
+    dispatches per round are the number of distinct static configs.
+    Returns one metrics dict per app, in ``apps`` order.
+    """
+    bstats_all, jobs = [], []
+    for app in apps:
+        bstats_all.append(system.Broadcast(app.handle.app_id, app.params))
+        workers = [w for w in sorted(app.handle.tree.members) if w in app.data]
+        jobs.append((app, workers, app.params))
+    trained = engine.fused_local_training(jobs)
 
-    return engine.run_round_fused(system, apps, use_kernel=use_kernel)
+    out = []
+    for app, bstats, (_, workers, _), (deltas, weights, losses) in zip(
+        apps, bstats_all, jobs, trained
+    ):
+        astats = system.Aggregate(
+            app.handle.app_id,
+            {w: d for w, d in zip(workers, deltas)},
+            weights={w: wt for w, wt in zip(workers, weights)},
+            use_kernel=use_kernel,
+        )
+        agg = astats["result"]
+        app.params = jax.tree.map(
+            lambda p, d: (p + d).astype(p.dtype), app.params, agg
+        )
+        app.round_num += 1
+        system.replicate_master_state(app.handle.app_id, {"round": app.round_num})
+        metrics = {
+            "round": app.round_num,
+            "loss": float(np.mean(losses)) if losses else 0.0,
+            "time_ms": bstats["time_ms"] + astats["time_ms"],
+            "traffic_bytes": bstats["bytes"] + astats["bytes"],
+            "agg_levels": astats.get("levels", []),
+        }
+        app.history.append(metrics)
+        out.append(metrics)
+    return out
 
 
 def run_async(
@@ -106,7 +137,6 @@ def run_async(
     adaptive: bool = False,
     adaptive_kwargs: dict | None = None,
     selector=None,
-    fair: bool = True,
     app_weights=None,
     app_rate_caps=None,
     relay_admission=None,
@@ -121,8 +151,7 @@ def run_async(
     ``adaptive=True`` re-sizes K per apply (``core.sim
     .AdaptiveKController``); ``selector`` plugs in utility-based client
     admission (``fl/selection``).  Transfers are priced by the
-    weighted-fair flow engine (``fair=False`` restores the legacy
-    start-time pricing); ``app_weights`` / ``app_rate_caps`` /
+    weighted-fair flow engine; ``app_weights`` / ``app_rate_caps`` /
     ``relay_admission`` expose the per-app fairness knobs.
     """
     from repro.fl import async_engine
@@ -132,7 +161,7 @@ def run_async(
         staleness_alpha=staleness_alpha, model_bytes=model_bytes,
         compute_ms=compute_ms, churn=churn, barrier=barrier,
         adaptive=adaptive, adaptive_kwargs=adaptive_kwargs, selector=selector,
-        fair=fair, app_weights=app_weights, app_rate_caps=app_rate_caps,
+        app_weights=app_weights, app_rate_caps=app_rate_caps,
         relay_admission=relay_admission,
     )
 

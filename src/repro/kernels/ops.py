@@ -121,7 +121,7 @@ def tree_aggregate_groups(grads: jax.Array, weights: jax.Array) -> jax.Array:
     The compiled fallback buckets G and C to powers of two with
     zero-weight phantom slots, so every level of every tree hits one of
     O(log G * log C) compiled programs per L instead of one per exact
-    shape (the recompile gate in bench_hotpath).
+    shape.
     """
     if _use_jnp():
         gb, cb = _bucket(grads.shape[0]), _bucket(grads.shape[1])

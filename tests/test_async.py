@@ -7,7 +7,7 @@ Covers the pluggable-scheduler refactor of ``core/sim.py``:
  - staleness weighting semantics through the kernel weight vector;
  - churn injected on the event clock repairs trees (``verify_tree``);
  - pipelined dissemination never slower than synchronous level pricing;
- - empty-batch edge cases (``pack_shards`` on no workers).
+ - empty-batch edge cases (``fused_local_training`` on no workers).
 """
 import jax
 import jax.numpy as jnp
@@ -69,7 +69,7 @@ def test_commit_apply_verbs_match_hierarchical_aggregate():
     sys_a, app_a = build_app(seed=2)
     sys_s, app_s = build_app(seed=2)
     ws = [w for w in sorted(app_a.handle.tree.members) if w in app_a.data]
-    deltas, weights, _ = engine.local_training(app_a, ws)
+    [(deltas, weights, _)] = engine.fused_local_training([(app_a, ws, None)])
     for w, d, wt in zip(ws, deltas, weights):
         stats = sys_a.CommitDelta(app_a.handle.app_id, w, d, weight=wt, staleness=0)
         assert stats["buffered"] >= 1 and stats["bytes"] >= 0.0
@@ -244,10 +244,11 @@ def test_sync_scheduler_trace_unchanged_by_refactor():
 def test_pack_shards_and_local_training_empty_workers():
     """A drained commit batch must not crash the engine (max() on [])."""
     sys_, app = build_app(seed=12)
-    x, y, mask = engine.pack_shards(app.data, [])
-    assert x.shape[0] == 0 and y.shape[0] == 0 and mask.shape[0] == 0
-    deltas, weights, losses = engine.local_training(app, [])
-    assert deltas == [] and weights == [] and losses == []
+    assert engine.fused_local_training([(app, [], None)]) == [([], [], [])]
+    # an empty job next to a real one keeps its slot and trains nothing
+    ws = [w for w in sorted(app.handle.tree.members) if w in app.data][:2]
+    out = engine.fused_local_training([(app, [], None), (app, ws, None)])
+    assert out[0] == ([], [], []) and len(out[1][0]) == len(ws)
     # and the trainer's apply is a no-op on an empty pending queue
     trainer = async_engine.AsyncTrainer(sys_, [app])
     assert trainer.apply(0, 0.0) is None

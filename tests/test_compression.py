@@ -462,10 +462,10 @@ def test_m16_policy_none_trace_byte_identical():
 
 
 def test_policy_none_identity_under_legacy_and_sampled_pricing():
-    for kw in (dict(fair=False), dict(congestion_mode="sampled", churn=False)):
-        base = _trace(4, compression=None, **kw)
-        off = _trace(4, compression="none", **kw)
-        assert base[:3] == off[:3]
+    kw = dict(congestion_mode="sampled", churn=False)
+    base = _trace(4, compression=None, **kw)
+    off = _trace(4, compression="none", **kw)
+    assert base[:3] == off[:3]
 
 
 def test_handle_compression_feeds_scheduler_and_arg_overrides():

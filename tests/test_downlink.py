@@ -24,7 +24,7 @@ Locks down the broadcast direction end to end:
   ``transport_stats`` and the fairness log.
 - **Trace identity**: ``downlink="none"`` is provably free — downlink
   knobs are inert and the M=16 churn trace is byte-identical to the
-  plain uplink-only policy, in exact, legacy and sampled pricing.
+  plain uplink-only policy, in exact and sampled pricing.
 - **EF-SGD**: with deterministic rounding a plain quantizer's commit
   stream carries a persistent bias; error feedback drives the running
   mean of dequantized commits to the true value.
@@ -419,14 +419,14 @@ def test_downlink_none_knobs_are_inert_m16_churn():
 
 
 def test_downlink_none_identity_under_legacy_and_sampled_pricing():
-    for kw in (dict(fair=False), dict(congestion_mode="sampled", churn=False)):
-        base = _trace(4, compression=CompressionPolicy(kind="qsgd-int8"), **kw)
-        off = _trace(
-            4,
-            compression=CompressionPolicy(kind="qsgd-int8", chain_cap=7),
-            **kw,
-        )
-        assert base[:3] == off[:3]
+    kw = dict(congestion_mode="sampled", churn=False)
+    base = _trace(4, compression=CompressionPolicy(kind="qsgd-int8"), **kw)
+    off = _trace(
+        4,
+        compression=CompressionPolicy(kind="qsgd-int8", chain_cap=7),
+        **kw,
+    )
+    assert base[:3] == off[:3]
 
 
 # -- EF-SGD: error feedback drives the commit-stream bias to zero --------------

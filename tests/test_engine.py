@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from oracles import reference_fused_local_training, reference_local_training
 
 from repro import data as data_mod
 from repro.core.api import TotoroSystem
@@ -34,8 +35,8 @@ def test_vectorized_matches_reference_loop():
     """vmapped masked local training == per-worker loop (ragged shards)."""
     _, app, _ = build_app(ragged=True)
     ws = [w for w in sorted(app.handle.tree.members) if w in app.data]
-    d_v, w_v, l_v = engine.local_training(app, ws, vectorized=True)
-    d_r, w_r, l_r = engine.local_training(app, ws, vectorized=False)
+    [(d_v, w_v, l_v)] = engine.fused_local_training([(app, ws, None)])
+    d_r, w_r, l_r = reference_local_training(app, ws)
     assert w_v == w_r
     np.testing.assert_allclose(l_v, l_r, rtol=1e-4, atol=1e-6)
     for a, b in zip(d_v, d_r):
@@ -49,8 +50,8 @@ def test_vectorized_matches_reference_with_fedprox():
     _, app, _ = build_app(ragged=True, seed=3)
     app.mu = 0.1
     ws = [w for w in sorted(app.handle.tree.members) if w in app.data]
-    d_v, _, _ = engine.local_training(app, ws, vectorized=True)
-    d_r, _, _ = engine.local_training(app, ws, vectorized=False)
+    [(d_v, _, _)] = engine.fused_local_training([(app, ws, None)])
+    d_r, _, _ = reference_local_training(app, ws)
     for a, b in zip(d_v, d_r):
         for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
             np.testing.assert_allclose(
@@ -58,12 +59,14 @@ def test_vectorized_matches_reference_with_fedprox():
             )
 
 
-def test_round_vectorized_and_reference_converge_identically():
+def test_round_vectorized_and_reference_converge_identically(monkeypatch):
     sys_v, app_v, (x, y) = build_app(seed=1)
     sys_r, app_r, _ = build_app(seed=1)
     for _ in range(3):
-        rounds.run_round(sys_v, app_v, vectorized=True)
-        rounds.run_round(sys_r, app_r, vectorized=False)
+        rounds.run_round(sys_v, app_v)
+    monkeypatch.setattr(engine, "fused_local_training", reference_fused_local_training)
+    for _ in range(3):
+        rounds.run_round(sys_r, app_r)
     for la, lb in zip(jax.tree.leaves(app_v.params), jax.tree.leaves(app_r.params)):
         np.testing.assert_allclose(np.asarray(la), np.asarray(lb), rtol=1e-3, atol=1e-5)
     assert rounds.evaluate(app_v, x[:300], y[:300]) > 0.7

@@ -5,9 +5,9 @@ Covers the PR-4 starvation fix end-to-end:
    processor-sharing schedule (join and complete both re-price);
  - re-pricing conserves delivered bytes exactly (no work lost or
    duplicated), and per-app uplink accounting equals commits x hops;
- - a single-flow (never contended) async trace is identical under
-   ``fair=True`` and ``fair=False`` — the legacy pricing is only wrong
-   under contention;
+ - a single-flow (never contended) async trace matches the closed
+   form: each leg takes its bits over each hop's capacity, plus the
+   per-hop base latency, summed over the hops;
  - per-app weight and rate-cap knobs shape contended throughput;
  - relay admission defers stale commits when contended, never drops
    them, and feeds the selector's deadline signal;
@@ -24,6 +24,7 @@ Covers the PR-4 starvation fix end-to-end:
 """
 import numpy as np
 import pytest
+from oracles import reference_local_training
 
 from repro import data as data_mod
 from repro.core.api import TotoroSystem
@@ -134,24 +135,32 @@ def test_flow_groups_split_one_share():
 
 
 def test_single_flow_async_trace_identical_fair_vs_legacy():
-    """Acceptance: uncontended (single-flow) pricing unchanged — one
-    worker, one app can never overlap two transfers, so the fair and
-    legacy schedulers must produce byte-identical event histories."""
-    runs = {}
-    for fair in (False, True):
-        sys_, app = build_app(seed=3, workers=1)
-        res = rounds.run_async(
-            sys_, [app], applies=4, buffer_k=1, staleness_alpha=0.5,
-            model_bytes=1e5, compute_ms=25.0, fair=fair,
+    """Acceptance: uncontended (single-flow) pricing — one worker of one
+    app never overlaps two transfers, so each leg takes, per hop, the
+    base latency plus its bits over the hop's capacity, summed over the
+    hops, and each apply lands one download + compute + upload cycle
+    after the last."""
+    model_bytes, compute_ms = 1e5, 25.0
+    sys_, app = build_app(seed=3, workers=1)
+    res = rounds.run_async(
+        sys_, [app], applies=4, buffer_k=1, staleness_alpha=0.5,
+        model_bytes=model_bytes, compute_ms=compute_ms,
+    )
+    sched = res["scheduler"]
+    (w,) = app.data
+    down = sched._path_senders(0, w, up=False)
+    up = sched._path_senders(0, w, up=True)
+    assert len(down) and len(up)  # a real multi-hop path, not the root
+
+    def leg_ms(hops):
+        return sum(
+            sched.base_ms + 1e3 * model_bytes * 8e-6 / sched._cap_mbps[h] for h in hops
         )
-        runs[fair] = res
-    assert runs[False]["events"] == runs[True]["events"]
-    assert [h["loss"] for h in runs[False]["history"]] == [
-        h["loss"] for h in runs[True]["history"]
-    ]
-    assert [h["t_ms"] for h in runs[False]["history"]] == [
-        h["t_ms"] for h in runs[True]["history"]
-    ]
+
+    cycle = leg_ms(down) + compute_ms + leg_ms(up)
+    times = [e.time_ms for e in res["events"]]
+    assert times == pytest.approx([cycle * (i + 1) for i in range(4)], rel=1e-12)
+    assert [h["t_ms"] for h in res["history"]] == times
 
 
 def test_fair_mode_deterministic_and_conserves_uplink_bytes():
@@ -480,7 +489,7 @@ def test_dirichlet_partition_low_alpha_zero_sample_repro_and_fix():
         data_mod.dirichlet_partition(y, 300, alpha=1.0, min_samples=1)
 
 
-def test_masked_padding_matches_reference_on_heavily_ragged_shards():
+def test_masked_padding_matches_reference_on_heavily_ragged_shards(monkeypatch):
     """Engine equivalence where it hurts: shard sizes 1 vs ~200 in one
     padded stack — the vectorized masked path must reproduce each
     worker's unpadded loss and delta."""
@@ -492,14 +501,22 @@ def test_masked_padding_matches_reference_on_heavily_ragged_shards():
     for w, size in zip(ws[:3], (1, 2, 5)):
         x, y = app.data[w]
         app.data[w] = (x[:size], y[:size])
-    x, y, mask = engine.pack_shards(app.data, ws)
-    assert mask.shape[0] == len(ws)
+    masks = []
+    orig = engine.megabatched_local_train
+
+    def spy(params, x, y, mask, **kw):
+        masks.append(np.asarray(mask))
+        return orig(params, x, y, mask, **kw)
+
+    monkeypatch.setattr(engine, "megabatched_local_train", spy)
+    [vec] = engine.fused_local_training([(app, ws, None)])
+    (mask,) = masks
+    assert mask.shape[0] == engine.bucket_size(len(ws))
     np.testing.assert_allclose(
-        np.asarray(mask.sum(axis=1)),
-        [len(app.data[w][1]) for w in ws],
+        mask.sum(axis=1),
+        [len(app.data[w][1]) for w in ws] + [0] * (mask.shape[0] - len(ws)),
     )
-    vec = engine.local_training(app, ws, vectorized=True)
-    ref = engine.local_training(app, ws, vectorized=False)
+    ref = reference_local_training(app, ws)
     assert vec[1] == ref[1]  # weights = shard sizes
     np.testing.assert_allclose(vec[2], ref[2], rtol=1e-4, atol=1e-6)
     for dv, dr in zip(vec[0], ref[0]):

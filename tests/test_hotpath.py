@@ -1,24 +1,29 @@
 """Hot-path overhaul: exactness + boundedness of the optimized engines.
 
-Covers ISSUE 5:
+Covers:
  - incremental repricing (UplinkState) is exact: byte-identical event
-   traces vs the legacy full-water-filling engine, bit-identical rates
-   on the uncapped fast path, allclose + identical binding sets on caps;
+   traces vs the full-water-filling oracle (tests/oracles.py), bit-
+   identical rates on the uncapped fast path, allclose on caps;
  - EventCore.cancel no longer leaks dead heap entries for the run:
    lazy-deletion compaction keeps the heap bounded under churn-heavy
    cancellation (the satellite regression);
  - numpy-resident transfer pricing == the jitted CongestionEnv lookup;
  - compiled-vs-interpret kernel parity (tree_aggregate_groups,
    buffered_aggregate, fused_update) on ragged / 1-sample shapes;
- - megabatched + bucketed training matches the exact-shape engine and
-   the per-worker reference on ragged/1-sample shards; recompile count
-   per run is O(#buckets), asserted via the jit cache-miss counter and
+ - megabatched + bucketed training matches the per-worker reference on
+   ragged/1-sample shards and per-app training; recompile count per run
+   is O(#buckets), asserted via the jit cache-miss counter and
    cross-checked against jax's own jit cache.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from oracles import (
+    WaterFillingScheduler,
+    reference_fused_local_training,
+    reference_local_training,
+)
 
 from repro import data as data_mod
 from repro.core.api import TotoroSystem
@@ -123,16 +128,16 @@ def test_uplink_state_add_remove_keeps_order_and_counts():
 
 
 def test_incremental_trace_byte_identical_with_churn():
-    """The tentpole exactness gate, in miniature: same apply events, same
-    churn log, same defer/fairness telemetry, both repricing engines."""
+    """The repricing exactness gate, in miniature: same apply events, same
+    churn log, same defer/fairness telemetry as the water-filling oracle."""
     results = []
-    for incremental in (False, True):
+    for cls in (WaterFillingScheduler, AsyncBufferScheduler):
         sys_, apps = build_multi_app(seed=3)
         churn = ChurnModel(period_ms=90.0, downtime_ms=300.0, group_size=2, seed=5)
-        sched = AsyncBufferScheduler(
+        sched = cls(
             sys_, [a.handle for a in apps], model_bytes=1.5e5,
             compute_ms=async_engine.worker_compute_fn(40.0, 6.0, seed=1),
-            buffer_k=3, churn=churn, incremental=incremental,
+            buffer_k=3, churn=churn,
         )
         events = sched.run(6)
         results.append((events, list(sched.churn_log), sched.transport_stats()))
@@ -146,16 +151,15 @@ def test_incremental_trace_identical_with_caps_weights_admission():
     from repro.core.sim import RelayAdmission
 
     results = []
-    for incremental in (False, True):
+    for cls in (WaterFillingScheduler, AsyncBufferScheduler):
         sys_, apps = build_multi_app(seed=7, m=3)
-        sched = AsyncBufferScheduler(
+        sched = cls(
             sys_, [a.handle for a in apps], model_bytes=2e5,
             compute_ms=async_engine.worker_compute_fn(40.0, 6.0, seed=2),
             buffer_k=3,
             app_weights=[2.0, 1.0, 1.0],
             app_rate_caps=[None, 40.0, 25.0],
             relay_admission=RelayAdmission(threshold=0.6, alpha=0.8),
-            incremental=incremental,
         )
         events = sched.run(5)
         results.append((events, list(sched.defer_log)))
@@ -313,9 +317,9 @@ def test_fused_update_parity_modes_and_donation(kernel_mode_guard, L, dtype):
 
 
 def test_bucketed_training_matches_exact_and_reference_ragged():
-    """Ragged shards incl. a 1-sample worker: bucketed (W, B) padding and
-    the per-worker-params megabatch both reproduce the exact-shape
-    engine and the per-worker reference loop."""
+    """Ragged shards incl. a 1-sample worker: the bucketed (W, B)
+    padding of the per-worker-params megabatch reproduces the per-worker
+    reference loop."""
     sys_, apps = build_multi_app(m=1, workers=5, seed=17)
     app = apps[0]
     ws = sorted(app.data)
@@ -324,29 +328,25 @@ def test_bucketed_training_matches_exact_and_reference_ragged():
         x, y = app.data[w]
         n = max(1, min(len(y), 1 + 3 * i))
         app.data[w] = (x[:n], y[:n])
-    d_ref, wt_ref, l_ref = engine.local_training(app, ws, vectorized=False)
-    d_exact, wt_exact, l_exact = engine.local_training(app, ws, bucketed=False)
-    d_buck, wt_buck, l_buck = engine.local_training(app, ws, bucketed=True)
+    d_ref, wt_ref, l_ref = reference_local_training(app, ws)
     [(d_mega, wt_mega, l_mega)] = engine.fused_local_training(
         [(app, ws, app.params)]
     )
-    assert wt_ref == wt_exact == wt_buck == wt_mega
-    np.testing.assert_allclose(l_buck, l_ref, rtol=1e-4, atol=1e-6)
+    assert wt_ref == wt_mega
     np.testing.assert_allclose(l_mega, l_ref, rtol=1e-4, atol=1e-6)
-    for variant in (d_exact, d_buck, d_mega):
-        for dr, dv in zip(d_ref, variant):
-            for a, b in zip(jax.tree.leaves(dr), jax.tree.leaves(dv)):
-                np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
-                )
+    for dr, dv in zip(d_ref, d_mega):
+        for a, b in zip(jax.tree.leaves(dr), jax.tree.leaves(dv)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
+            )
 
 
 def test_fused_cross_app_training_matches_per_app():
     sys_, apps = build_multi_app(m=3, workers=4, seed=19)
     jobs = [(a, sorted(a.data), a.params) for a in apps]
     fused = engine.fused_local_training(jobs)
-    for (app, ws, _), (d_f, wt_f, l_f) in zip(jobs, fused):
-        d_e, wt_e, l_e = engine.local_training(app, ws, bucketed=False)
+    for job, (d_f, wt_f, l_f) in zip(jobs, fused):
+        [(d_e, wt_e, l_e)] = engine.fused_local_training([job])
         assert wt_f == wt_e
         np.testing.assert_allclose(l_f, l_e, rtol=1e-4, atol=1e-6)
         for df, de in zip(d_f, d_e):
@@ -377,8 +377,8 @@ def test_fused_training_splits_same_name_different_shape_models():
         )
     jobs = [(a, sorted(a.data), a.params) for a in apps]
     fused = engine.fused_local_training(jobs)  # crashed before the fix
-    for (app, ws, _), (d_f, wt_f, l_f) in zip(jobs, fused):
-        d_e, wt_e, l_e = engine.local_training(app, ws, bucketed=False)
+    for job, (d_f, wt_f, l_f) in zip(jobs, fused):
+        [(d_e, wt_e, l_e)] = engine.fused_local_training([job])
         assert wt_f == wt_e
         np.testing.assert_allclose(l_f, l_e, rtol=1e-4, atol=1e-6)
         for df, de in zip(d_f, d_e):
@@ -391,8 +391,8 @@ def test_fused_training_splits_same_name_different_shape_models():
 def test_run_round_fused_matches_run_round():
     sys_a, apps_a = build_multi_app(m=2, workers=4, seed=23)
     sys_b, apps_b = build_multi_app(m=2, workers=4, seed=23)
-    fused = engine.run_round_fused(sys_a, apps_a)
-    plain = [engine.run_round(sys_b, app) for app in apps_b]
+    fused = rounds.run_round_fused(sys_a, apps_a)
+    plain = [rounds.run_round(sys_b, app) for app in apps_b]
     assert len(fused) == len(plain)
     for mf, mp, aa, ab in zip(fused, plain, apps_a, apps_b):
         assert mf["round"] == mp["round"]
@@ -427,29 +427,27 @@ def test_async_recompiles_bounded_by_buckets():
     assert cache_delta <= engine.DISPATCH.compiles
 
 
-def test_async_megabatch_matches_legacy_dispatch_loop():
-    """Trace + loss equivalence of the fused apply vs the per-version
-    dispatch loop (the pre-optimization data plane)."""
+def test_async_megabatch_matches_legacy_dispatch_loop(monkeypatch):
+    """Trace + loss equivalence of the fused apply vs the per-worker
+    reference loop (tests/oracles.py) in its place."""
     outs = []
-    for megabatch in (True, False):
+    for oracle in (False, True):
+        if oracle:
+            monkeypatch.setattr(engine, "fused_local_training", reference_fused_local_training)
         sys_, apps = build_multi_app(m=2, workers=5, seed=31)
         res = async_engine.run_async(
             sys_, apps, applies=3, buffer_k=3, staleness_alpha=0.5,
             model_bytes=1.5e5,
             compute_ms=async_engine.worker_compute_fn(40.0, 6.0, seed=5),
-            megabatch=megabatch,
         )
-        outs.append(res)
-    assert outs[0]["events"] == outs[1]["events"]
-    la = [r["loss"] for r in outs[0]["history"]]
-    lb = [r["loss"] for r in outs[1]["history"]]
+        outs.append((res, apps))
+    (res_a, apps_a), (res_b, apps_b) = outs
+    assert res_a["events"] == res_b["events"]
+    la = [r["loss"] for r in res_a["history"]]
+    lb = [r["loss"] for r in res_b["history"]]
     np.testing.assert_allclose(la, lb, rtol=1e-5, atol=1e-7)
-
-
-def test_bench_hotpath_registered():
-    from benchmarks.run import REGISTRY
-
-    names = [n for n, _, _ in REGISTRY]
-    assert "hotpath(perf)" in names
-    mods = [m for _, m, _ in REGISTRY]
-    assert "benchmarks.bench_hotpath" in mods
+    for aa, ab in zip(apps_a, apps_b):
+        for pa, pb in zip(jax.tree.leaves(aa.params), jax.tree.leaves(ab.params)):
+            np.testing.assert_allclose(
+                np.asarray(pa), np.asarray(pb), rtol=1e-4, atol=1e-6
+            )

@@ -7,9 +7,10 @@ sampled congestion invariants, and the enriched event-budget error.
 - ``neighborhood_set`` (spatial-grid index) must equal the brute-force
   full-sort result.
 - The cohort-batched scheduler in exact mode must reproduce the
-  per-event baseline trace byte-for-byte (exact ApplyEvent/ChurnRecord
-  equality) at M=16, and ``congestion_mode="sampled"`` with
-  ``hot_threshold=0`` must degenerate to the exact trace.
+  per-event oracle's trace (tests/oracles.py) byte-for-byte (exact
+  ApplyEvent/ChurnRecord equality) at M=16, and
+  ``congestion_mode="sampled"`` with ``hot_threshold=0`` must
+  degenerate to the exact trace.
 - ``EventCore.run_events`` budget exhaustion must name the clock, heap
   occupancy, per-app progress, and the ``max_events`` knob.
 """
@@ -141,10 +142,15 @@ def _timing_run(m_apps, **kw):
     return _timing_run(m_apps, **kw)
 
 
-def test_m16_cohort_trace_identical_to_per_event_baseline():
+def test_m16_cohort_trace_identical_to_per_event_baseline(monkeypatch):
+    from oracles import PerEventScheduler
+
+    from repro.core import sim
+
     kw = dict(applies=2, seed=0)
-    base = _timing_run(16, cohort=False, congestion_mode="exact", **kw)
-    coh = _timing_run(16, cohort=True, congestion_mode="exact", **kw)
+    coh = _timing_run(16, congestion_mode="exact", **kw)
+    monkeypatch.setattr(sim, "AsyncBufferScheduler", PerEventScheduler)
+    base = _timing_run(16, congestion_mode="exact", **kw)
     assert base["events"] == coh["events"]  # exact ApplyEvent equality
     assert base["churn"] == coh["churn"]  # exact ChurnRecord equality
     assert base["events_dispatched"] == coh["events_dispatched"]
@@ -154,9 +160,9 @@ def test_m16_cohort_trace_identical_to_per_event_baseline():
 
 def test_sampled_hot_threshold_zero_degenerates_to_exact():
     kw = dict(applies=2, seed=1)
-    base = _timing_run(8, cohort=True, congestion_mode="exact", **kw)
+    base = _timing_run(8, congestion_mode="exact", **kw)
     deg = _timing_run(
-        8, cohort=True, congestion_mode="sampled", hot_threshold=0, **kw
+        8, congestion_mode="sampled", hot_threshold=0, **kw
     )
     assert base["events"] == deg["events"]
     assert base["churn"] == deg["churn"]
@@ -164,8 +170,8 @@ def test_sampled_hot_threshold_zero_degenerates_to_exact():
 
 def test_sampled_mode_completes_with_fewer_events():
     kw = dict(applies=2, seed=0)
-    exact = _timing_run(8, cohort=True, congestion_mode="exact", **kw)
-    samp = _timing_run(8, cohort=True, congestion_mode="sampled", **kw)
+    exact = _timing_run(8, congestion_mode="exact", **kw)
+    samp = _timing_run(8, congestion_mode="sampled", **kw)
     assert len(samp["events"]) == len(exact["events"])  # same applies done
     assert samp["events_dispatched"] < exact["events_dispatched"]
 
@@ -188,7 +194,7 @@ def test_congestion_mode_validated():
 
 def test_run_events_budget_error_names_progress():
     with pytest.raises(RuntimeError) as ei:
-        _timing_run(4, cohort=True, congestion_mode="exact", applies=50,
+        _timing_run(4, congestion_mode="exact", applies=50,
                     seed=0, max_events=200)
     msg = str(ei.value)
     assert "event budget exhausted" in msg
@@ -251,9 +257,9 @@ def test_resample_with_hot_threshold_zero_stays_exact():
     """With hot_threshold=0 every cycle is hot (exact), no cold spans
     exist, and the resample timer must be a pure no-op on the trace."""
     kw = dict(applies=2, seed=1)
-    base = _timing_run(8, cohort=True, congestion_mode="exact", **kw)
+    base = _timing_run(8, congestion_mode="exact", **kw)
     deg = _timing_run(
-        8, cohort=True, congestion_mode="sampled", hot_threshold=0,
+        8, congestion_mode="sampled", hot_threshold=0,
         resample_every=25.0, **kw
     )
     assert base["events"] == deg["events"]
@@ -262,9 +268,9 @@ def test_resample_with_hot_threshold_zero_stays_exact():
 
 def test_resample_timer_fires_and_run_completes():
     kw = dict(applies=2, seed=0)
-    frozen = _timing_run(8, cohort=True, congestion_mode="sampled", **kw)
+    frozen = _timing_run(8, congestion_mode="sampled", **kw)
     res = _timing_run(
-        8, cohort=True, congestion_mode="sampled", resample_every=40.0, **kw
+        8, congestion_mode="sampled", resample_every=40.0, **kw
     )
     assert len(res["events"]) == len(frozen["events"])  # same applies done
     assert res["resamples"] > 0
@@ -274,7 +280,7 @@ def test_resample_timer_fires_and_run_completes():
 def test_resample_event_count_variant():
     kw = dict(applies=2, seed=0)
     res = _timing_run(
-        8, cohort=True, congestion_mode="sampled", resample_events=500, **kw
+        8, congestion_mode="sampled", resample_events=500, **kw
     )
     assert res["resamples"] > 0
 
@@ -305,9 +311,7 @@ def test_forest_bootstrap_identity_and_gate_math():
         "hops_fit": log_fit(hops_curve),
         "forest_vs_n": depth_curve,
         "depth_fit": log_fit(depth_curve, key="mean_depth"),
-        "trace_identity": {
-            "cohort_identical": True, "sampled_ht0_identical": True,
-        },
+        "trace_identity": {"sampled_ht0_identical": True},
         "events_vs_m": [],
         "applies_per_app": 2,
     }
