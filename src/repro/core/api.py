@@ -254,8 +254,11 @@ class TotoroSystem:
         fewer than ``min_k`` commits are buffered (buffer untouched).
         A buffer of ``QuantizedDelta`` on one grid, with no custom
         ``aggregate_fn``, takes the compiled path (``kernels.ops.
-        buffered_aggregate_quantized``): ``result`` is then a pytree of
-        device arrays and the combined weights are the verb's one pull.
+        buffered_aggregate_quantized``): the K grids and scales go in as
+        they are, device arrays from ``quantize_delta`` (counted
+        ``grid_resident``) or host arrays uploaded inside the operands
+        program (``grid_uploaded``), ``result`` is a pytree of device
+        arrays and the combined weights are the verb's one pull.
 
         ``k`` (the scheduler's effective buffer threshold for this
         apply), ``selector_scores`` (per-client utilities) and
@@ -305,13 +308,19 @@ class TotoroSystem:
                     )
                 # dequantize INSIDE the aggregation: per-row scales compose
                 # with the staleness discount in one kernel call; the K
-                # grids go up as one stack each and the aggregate stays on
-                # the device, already cut into the params' leaves
+                # grids stay where the commits left them (on the device
+                # from quantize_delta; a host grid goes up inside the
+                # operands program) and the aggregate stays on the
+                # device, already cut into the params' leaves
                 tracing.count("apply_fused")
+                for e in entries:
+                    tracing.count(
+                        "grid_resident" if isinstance(e.delta.q, jax.Array) else "grid_uploaded"
+                    )
                 d0 = entries[0].delta
                 leaves, combined = buffered_aggregate_quantized(
-                    tracing.implicit_push(np.stack([tracing.pull(e.delta.q) for e in entries])),
-                    tracing.implicit_push(np.stack([tracing.pull(e.delta.scale) for e in entries])),
+                    [tracing.implicit_push(e.delta.q) for e in entries],
+                    [tracing.implicit_push(e.delta.scale) for e in entries],
                     [e.weight for e in entries],
                     [e.staleness for e in entries],
                     alpha=staleness_alpha, shapes=d0.shapes,

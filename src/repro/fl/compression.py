@@ -9,10 +9,10 @@ Compressed transport (docs/performance.md "compressed transport"): a
 ``CompressionPolicy`` rides on ``AppHandle.compression`` (or the async
 scheduler's ``app_compression`` knob) and governs the *commit* direction
 — workers' delta uploads.  ``quantize_delta`` serializes an update
-pytree into a ``QuantizedDelta`` (int8 payload + per-chunk f32 scales),
-``CommitDelta`` buffers it as-is, and ``ApplyBuffered`` dequantizes
-*inside* the buffered aggregation (``kernels.ops.
-buffered_aggregate_quantized``: per-row scales compose with the
+pytree into a ``QuantizedDelta`` (int8 payload + per-chunk f32 scales,
+left on the device), ``CommitDelta`` buffers it as-is, and
+``ApplyBuffered`` dequantizes *inside* the buffered aggregation
+(``kernels.ops.buffered_aggregate_quantized``: per-row scales compose with the
 staleness weights in one kernel call, between two compiled programs,
 and the aggregate stays on the device).  The scheduler prices commit
 flows at ``CompressionPolicy.wire_bytes(model_bytes)``, so the
@@ -311,10 +311,13 @@ class QuantizedDelta:
     per-chunk f32 scales + the pytree structure needed to rebuild it.
 
     ``q`` is (R, chunk) int8 (the flattened, zero-padded delta), ``scale``
-    (R, 1) f32: host arrays, except in ``AsyncTrainer``'s delta-qsgd
-    broadcast cache, which keeps them on the device.  Dequantization is
-    ``q * scale`` row-wise; padding elements quantize to exactly 0
-    (|0/scale + u| < 1 for u in [0, 1)) and are dropped by ``unflatten``.
+    (R, 1) f32: the device arrays the quantize produced, which a commit
+    keeps until ``ApplyBuffered`` aggregates them and ``AsyncTrainer``'s
+    delta-qsgd broadcast cache until ``apply_delta_chain`` folds them in
+    (a qsgd-int8 broadcast, or a delta built by hand, may hold host
+    arrays).  Dequantization is ``q * scale`` row-wise; padding elements
+    quantize to exactly 0 (|0/scale + u| < 1 for u in [0, 1)) and are
+    dropped by ``unflatten``.
 
     ``wire_nbytes`` overrides the modeled wire size when the serialized
     format is narrower than the in-memory int8 grid (bit-packed signsgd,
@@ -350,7 +353,8 @@ class QuantizedDelta:
     def dequantize(self) -> Any:
         """Unfused reference: dequantize this delta alone (the fused
         apply-side path composes scales with staleness weights instead —
-        ``kernels.ops.buffered_aggregate_quantized``)."""
+        ``kernels.ops.buffered_aggregate_quantized``).  Device arrays
+        multiply on the device, and ``unflatten`` pulls the product once."""
         flat = self.q.astype(np.float32) * self.scale.astype(np.float32)
         return self.unflatten(flat.reshape(-1))
 
@@ -443,9 +447,11 @@ def quantize_delta(
     QSGD-quantizes the survivors.  All three ride ``QuantizedDelta`` —
     the same buffer, the same fused dequantize-in-aggregate apply path —
     with ``wire_nbytes`` carrying the packed/sparse wire model where the
-    int8 grid overstates it.  Counts ``quantize_fused`` for a call that
-    was the grid program and one kernel dispatch, else
-    ``quantize_eager``."""
+    int8 grid overstates it.  The returned q and scale are the device
+    arrays the kernel (or the pure-JAX path) produced: nothing is pulled,
+    and ``ApplyBuffered`` aggregates them where they are.  Counts
+    ``quantize_fused`` for a call that was the grid program and one
+    kernel dispatch, else ``quantize_eager``."""
     if not policy.enabled:
         raise ValueError("quantize_delta requires an enabled policy (kind != 'none')")
     with tracing.span("quantize"):
@@ -477,7 +483,7 @@ def quantize_delta(
         fused = policy.kind == "qsgd-int8" and chunk == 256
         tracing.count("quantize_fused" if fused else "quantize_eager")
         return QuantizedDelta(
-            q=tracing.pull(q), scale=tracing.pull(s), length=n, shapes=shapes,
+            q=q, scale=s, length=n, shapes=shapes,
             treedef=treedef, levels=int(policy.levels), chunk=chunk,
             wire_nbytes=wire,
         )

@@ -31,8 +31,10 @@ Span names used by the program:
 program and one kernel dispatch (``quantize_fused``) or took an eager
 branch (``quantize_eager``), ``ApplyBuffered`` aggregations that took the
 compiled quantized path (``apply_fused``) or another (``apply_eager``),
-and broadcast states built on the device (``broadcast_fused``) or on
-the host (``broadcast_eager``); they move only while tracing is on.
+the buffered commits that path took as grids already on the device
+(``grid_resident``) or had to upload (``grid_uploaded``), and broadcast
+states built on the device (``broadcast_fused``) or on the host
+(``broadcast_eager``); they move only while tracing is on.
 """
 from __future__ import annotations
 
@@ -49,6 +51,7 @@ counters = {
     "d2h_pulls": 0, "d2h_bytes": 0, "h2d_pushes": 0, "h2d_bytes": 0,
     "quantize_fused": 0, "quantize_eager": 0,
     "apply_fused": 0, "apply_eager": 0, "broadcast_fused": 0, "broadcast_eager": 0,
+    "grid_resident": 0, "grid_uploaded": 0,
 }
 
 

@@ -29,6 +29,7 @@ Locks down the compression layer end to end:
 - **End-to-end**: a trained qsgd-int8 run converges next to the
   uncompressed run, and mixed quantized/raw buffers are rejected.
 """
+import dataclasses
 import math
 
 import jax
@@ -43,6 +44,7 @@ try:  # optional dev dep: the property tests widen to random draws with it
 except ImportError:
     HAVE_HYPOTHESIS = False
 
+from repro import tracing
 from repro.core.api import TotoroSystem
 from repro.core.sim import AsyncBufferScheduler, ChurnModel
 from repro.fl import compression as comp
@@ -362,7 +364,7 @@ def test_fused_aggregate_matches_unfused_reference(kernel_mode_guard, mode):
     )
     w = np.asarray([wt / (1.0 + s) ** alpha for wt, s in zip(weights, staleness)])
     deq = np.stack([
-        (q.q.astype(np.float64) * q.scale.astype(np.float64)).ravel() for q in qds
+        (np.asarray(q.q, np.float64) * np.asarray(q.scale, np.float64)).ravel() for q in qds
     ])
     expect = (w[:, None] * deq).sum(0) / w.sum()
     np.testing.assert_allclose(np.asarray(flat), expect, rtol=1e-5, atol=1e-6)
@@ -411,6 +413,170 @@ def test_compiled_apply_verb_bit_identical_to_eager(kernel_mode_guard, K, alpha)
         got = out["result"][name]
         assert isinstance(got, jax.Array) and got.shape == shape
         np.testing.assert_array_equal(np.asarray(got), want[name])
+
+
+# -- commit payloads stay on the device ----------------------------------------
+
+
+@pytest.fixture
+def tracer():
+    tracing.disable()
+    tracing.clear()
+    yield tracing
+    tracing.disable()
+    tracing.clear()
+
+
+def _host(qd):
+    """``qd`` with q and scale pulled to the host: the commit payload as
+    ``quantize_delta`` returned it when it pulled both."""
+    return dataclasses.replace(qd, q=np.asarray(qd.q), scale=np.asarray(qd.scale))
+
+
+def _pulled_stack_apply(qds, weights, staleness, alpha):
+    """The oracle: the verb's composition when commits held host grids,
+    the K grids and scales stacked with numpy, uploaded as one stack each
+    and handed to ``buffered_aggregate_quantized``.  Returns (result
+    pytree, combined weights)."""
+    leaves, w = kops.buffered_aggregate_quantized(
+        jnp.asarray(np.stack([np.asarray(d.q) for d in qds])),
+        jnp.asarray(np.stack([np.asarray(d.scale) for d in qds])),
+        weights, staleness, alpha=alpha, shapes=qds[0].shapes,
+    )
+    return jax.tree.unflatten(qds[0].treedef, leaves), np.asarray(w).tolist()
+
+
+@pytest.mark.parametrize("K,alpha,grids", [
+    *[(K, alpha, "device") for K in (1, 3, 4, 5) for alpha in (0.0, 0.5)],
+    (5, 0.5, "mixed"),
+])
+def test_apply_verb_on_device_grids_bit_identical_to_pulled_stack(
+    kernel_mode_guard, tracer, K, alpha, grids
+):
+    """``ApplyBuffered`` aggregates the commits' device grids where they
+    are and gives the pulled-and-stacked composition's result and weights
+    bit for bit; a host grid in the buffer is uploaded and counted."""
+    kops.set_kernel_mode("jnp")
+    rng = np.random.default_rng(10 + K)
+    policy = CompressionPolicy(kind="qsgd-int8")
+    shapes = {"w": (37, 11), "b": (11,), "v": (300,)}
+    sys_, handles = _build_handles(1, workers=K, n_nodes=20, seed=K)
+    h = handles[0]
+    qds, weights, staleness, on_host = [], [], [], 0
+    for k, w in enumerate(sorted(h.tree.members)[:K]):
+        delta = {n: rng.normal(0, 1.0, s).astype(np.float32) for n, s in shapes.items()}
+        qds.append(comp.quantize_delta(delta, policy, app=0, seq=k))
+        assert isinstance(qds[-1].q, jax.Array) and isinstance(qds[-1].scale, jax.Array)
+        weights.append(float(rng.uniform(0.5, 3.0)))
+        staleness.append(k % 3)
+        payload = qds[-1]
+        if grids == "mixed" and k % 2:
+            payload, on_host = _host(payload), on_host + 1
+        sys_.CommitDelta(h.app_id, w, payload, weight=weights[-1], staleness=staleness[-1])
+    tracer.enable()
+    before = tracer.snapshot()
+    out = sys_.ApplyBuffered(h.app_id, staleness_alpha=alpha)
+    tracer.disable()
+    grew = {k: v - before[k] for k, v in tracer.snapshot().items()}
+    assert grew["grid_resident"] == K - on_host
+    assert grew["grid_uploaded"] == on_host
+    assert grew["apply_fused"] == 1 and grew["apply_eager"] == 0
+    assert grew["h2d_pushes"] == 2 * on_host
+    assert grew["d2h_pulls"] == 1  # the combined weights
+    want, want_w = _pulled_stack_apply(qds, weights, staleness, alpha)
+    assert out["weights"] == want_w
+    for name, shape in shapes.items():
+        got = out["result"][name]
+        assert isinstance(got, jax.Array) and got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want[name]))
+
+
+@pytest.mark.parametrize("kind", ["qsgd-int8", "signsgd", "topk"])
+def test_quantize_delta_keeps_q_and_scale_on_the_device(kernel_mode_guard, tracer, kind):
+    """Every commit kind returns the quantize's device arrays, pulls
+    nothing, and carries the bits and the sizes the pulled arrays had."""
+    kops.set_kernel_mode("jnp")
+    policy = CompressionPolicy(kind=kind, seed=3, topk_frac=0.05)
+    delta = _grid_delta("mnist-2nn", seed=4)
+    n = sum(np.size(l) for l in jax.tree.leaves(delta))
+    rows = math.ceil(n / 256)
+    tracer.enable()
+    before = tracer.snapshot()
+    qd = comp.quantize_delta(delta, policy, app=1, seq=7)
+    tracer.disable()
+    assert tracer.snapshot()["d2h_pulls"] == before["d2h_pulls"]
+    assert not [r for r in tracer.records if r[0] == "xfer.d2h"]
+    assert isinstance(qd.q, jax.Array) and isinstance(qd.scale, jax.Array)
+    assert qd.q.dtype == jnp.int8 and qd.q.shape == (rows, 256)
+    assert qd.scale.dtype == jnp.float32 and qd.scale.shape == (rows, 1)
+    # the same call, pulled as the commit path used to pull it
+    pulled = _host(comp.quantize_delta(delta, policy, app=1, seq=7))
+    np.testing.assert_array_equal(np.asarray(qd.q), pulled.q)
+    np.testing.assert_array_equal(np.asarray(qd.scale), pulled.scale)
+    assert qd.nbytes == pulled.nbytes
+    assert qd.wire_nbytes == pulled.wire_nbytes
+    assert qd.nbytes == policy.wire_bytes(4.0 * n)
+    assert (qd.length, qd.shapes, qd.levels, qd.chunk) == (
+        pulled.length, pulled.shapes, pulled.levels, pulled.chunk)
+    if kind == "qsgd-int8":
+        assert qd.wire_nbytes is None
+        _same(qd, _eager_quantize(delta, 256, comp.commit_key(policy, 1, 7), policy.levels),
+              delta)
+    else:
+        assert qd.wire_nbytes == policy.wire_bytes(4.0 * n)
+    if kind == "signsgd":
+        flat = np.concatenate([np.ravel(l) for l in jax.tree.leaves(delta)])
+        grid = np.zeros(rows * 256, np.float32)
+        grid[:n] = flat
+        np.testing.assert_array_equal(np.asarray(qd.q).ravel(), np.sign(grid).astype(np.int8))
+
+
+def _ef_trainer():
+    """One app of 4 workers under qsgd-int8 with EF-SGD, after two
+    applies: every worker commits twice, so the second commit folds the
+    first one's residual."""
+    from repro import data as data_mod
+    from repro.fl import async_engine, rounds
+
+    sys_ = TotoroSystem(zone_bits=2, suffix_bits=20, seed=5)
+    rng = np.random.default_rng(5)
+    nodes = [sys_.Join("n", i, site=i % 4, coord=rng.uniform(0, 50, 2)) for i in range(40)]
+    x, y = data_mod.synthetic_classification(4 * 30, 16, 4, seed=5)
+    parts = np.array_split(np.arange(len(y)), 4)
+    ws = [int(w) for w in rng.choice(nodes, size=4, replace=False)]
+    app = rounds.make_app(
+        sys_, "ef", workers=ws,
+        data_by_worker={w: (x[parts[i]], y[parts[i]]) for i, w in enumerate(ws)},
+        dim=16, num_classes=4, local_steps=2, lr=0.2, seed=5,
+    )
+    policy = CompressionPolicy(kind="qsgd-int8", levels=7, error_feedback=True)
+    tr = async_engine.AsyncTrainer(sys_, [app], compression=policy)
+    for _ in range(2):
+        for w in tr.workers(0):
+            tr.begin_download(0, w)
+        for w in tr.workers(0):
+            tr.commit(0, w, 0.0)
+        tr.apply(0, 0.0)
+    return tr
+
+
+def test_error_feedback_residual_bit_identical_with_device_payloads(monkeypatch):
+    """EF-SGD dequantizes each commit to carry its residual: with the
+    payload on the device the residuals after two commits per worker,
+    and the params, equal those of host payloads bit for bit."""
+    from repro.fl import async_engine
+
+    dev = _ef_trainer()
+    with monkeypatch.context() as m:
+        m.setattr(async_engine, "quantize_delta",
+                  lambda *a, **kw: _host(comp.quantize_delta(*a, **kw)))
+        host = _ef_trainer()
+    assert dev._ef[0] and sorted(dev._ef[0]) == sorted(host._ef[0])
+    for w in dev._ef[0]:
+        for a, b in zip(jax.tree.leaves(dev._ef[0][w]), jax.tree.leaves(host._ef[0][w])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree.leaves(dev.apps[0].params), jax.tree.leaves(host.apps[0].params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # -- scheduler fixtures --------------------------------------------------------

@@ -268,3 +268,60 @@ def test_delta_qsgd_apply_keeps_the_aggregate_step_on_the_device(tracer):
     step = ("verb.apply", "broadcast", "chain")
     pulls = [i for i, r in enumerate(recs) if r[0] == "xfer.d2h" and under(i, step)]
     assert 1 <= len(pulls) <= 2
+
+
+def test_commit_payloads_stay_on_the_device_until_the_apply(tracer):
+    """One warm apply of 4 qsgd commits with a delta-qsgd broadcast: no
+    quantize of the commit loop pulls, ``ApplyBuffered`` takes all 4
+    grids from the device, the apply pulls at most twice outside
+    ``train`` (the combined weights and the held state), and no program
+    is built."""
+    sys_, apps = _deployment()
+    tr = async_engine.AsyncTrainer(
+        sys_, apps[:1], compression=CompressionPolicy(kind="qsgd-int8", downlink="delta-qsgd")
+    )
+    workers = tr.workers(0)[:4]
+
+    def one_apply():
+        for w in workers:
+            tr.begin_download(0, w)
+        for w in workers:
+            tr.commit(0, w, 0.0)
+        return tr.apply(0, 0.0)
+
+    one_apply()  # builds every program of the path
+    builds = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            builds.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    tracer.enable()
+    before = tracer.snapshot()
+    try:
+        assert one_apply()["arrivals"] == len(workers)
+    finally:
+        tracer.disable()
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    after = tracer.snapshot()
+    assert builds == []
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew["grid_resident"] == len(workers) and grew["grid_uploaded"] == 0
+    recs = tracer.records
+
+    def under(i, names):
+        while i >= 0:
+            if recs[i][0] in names:
+                return True
+            i = recs[i][3]
+        return False
+
+    commits = [i for i, r in enumerate(recs) if r[0] == "verb.commit"]
+    assert len(commits) == len(workers)
+    quantizes = [i for i, r in enumerate(recs)
+                 if r[0] == "quantize" and not under(i, ("broadcast",))]
+    assert len(quantizes) == len(workers) and max(quantizes) < commits[-1]
+    assert not [i for i, r in enumerate(recs) if r[0] == "xfer.d2h" and under(i, ("quantize",))]
+    outside = [i for i, r in enumerate(recs) if r[0] == "xfer.d2h" and not under(i, ("train",))]
+    assert 1 <= len(outside) <= 2
